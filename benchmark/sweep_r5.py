@@ -73,10 +73,8 @@ def transformer_policy(policy, steps=20):
 
 def main():
     import bench
-    plat = bench._wait_for_backend()
-    print(f"[sweep] backend: {plat}", flush=True)
-    if plat != "tpu":
-        print("[sweep] WARNING: not on TPU — numbers are meaningless")
+    dev = bench._require_tpu()      # no chip: exit, never a CPU number
+    print(f"[sweep] device: {dev.device_kind}", flush=True)
 
     # 1. BN one-pass effect on the ResNet train rows
     for batch, dtype, steps in ((128, "bfloat16", 240), (32, "bfloat16", 240)):

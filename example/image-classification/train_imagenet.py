@@ -4,8 +4,10 @@
 
 Reference: example/image-classification/train_imagenet.py + common/fit.py.
 TPU-native: with --kv-store tpu the whole step (fwd+bwd+allreduce+update)
-is ONE pjit'd XLA program over a dp mesh (parallel.TrainStep); `local`
-runs the eager Gluon Trainer path. Data comes from an ImageRecordIter
+is ONE pjit'd XLA program over a dp mesh (parallel.TrainStep) and the job
+refuses to start without a TPU; `device` is the same compiled path on
+whatever backend jax found; `local` runs the eager Gluon Trainer path.
+One process drives every chip of the host. Data comes from an ImageRecordIter
 .rec file when --data-train is given, else a synthetic stream (for
 benchmarking and smoke tests, like benchmark_score.py's dummy data).
 """
@@ -21,7 +23,7 @@ sys.path.insert(0, os.path.join(os.path.dirname(os.path.abspath(__file__)),
 import numpy as np
 
 
-def parse_args():
+def parse_args(argv=None):
     p = argparse.ArgumentParser(description="train imagenet",
                                 formatter_class=argparse.ArgumentDefaultsHelpFormatter)
     p.add_argument("--network", default="resnet50_v1",
@@ -43,7 +45,7 @@ def parse_args():
     p.add_argument("--dp", type=int, default=0,
                    help="data-parallel mesh size (0 = all devices)")
     p.add_argument("--disp-batches", type=int, default=10)
-    return p.parse_args()
+    return p.parse_args(argv)
 
 
 def get_data(args, shape):
@@ -67,18 +69,36 @@ def get_data(args, shape):
     return Synthetic()
 
 
-def main():
-    args = parse_args()
+def main(argv=None):
+    """Run the job; returns (step_or_trainer, records) with one
+    (epoch, batch, loss, seconds-since-start) record per displayed batch —
+    what chip_smoke.py checks after driving this entry point."""
+    args = parse_args(argv)
     logging.basicConfig(level=logging.INFO)
+    t_start = time.time()
     import jax
     import jax.numpy as jnp
     import incubator_mxnet_tpu as mx
     from incubator_mxnet_tpu.gluon.model_zoo import vision
 
+    platform = jax.devices()[0].platform
+    if args.kv_store == "tpu" and platform != "tpu":
+        raise SystemExit(
+            f"--kv-store tpu needs a TPU, but jax came up on {platform!r}; "
+            "--kv-store device runs the same compiled step on whatever "
+            "backend is present")
+
     shape = tuple(int(x) for x in args.image_shape.split(","))
     net = getattr(vision, args.network)(classes=args.num_classes)
     net.initialize(mx.init.Xavier(magnitude=2.0))
     data = get_data(args, shape)
+    records = []
+
+    def show(epoch, i, loss, n, tic):
+        loss = float(loss.asnumpy() if hasattr(loss, "asnumpy") else loss)
+        records.append((epoch, i + 1, loss, time.time() - t_start))
+        logging.info("epoch %d batch %d loss %.4f  %.1f img/s",
+                     epoch, i + 1, loss, n / (time.time() - tic))
 
     if args.kv_store in ("tpu", "device"):
         # compiled SPMD path: dp mesh over all chips, ONE XLA program/step
@@ -106,14 +126,12 @@ def main():
                 loss = step(x, y)
                 n += args.batch_size
                 if (i + 1) % args.disp_batches == 0:
-                    logging.info("epoch %d batch %d loss %.4f  %.1f img/s",
-                                 epoch, i + 1, float(loss.asnumpy() if
-                                 hasattr(loss, "asnumpy") else loss),
-                                 n / (time.time() - tic))
+                    show(epoch, i, loss, n, tic)
             data.reset()
             step.sync()
             logging.info("epoch %d done: %.1f img/s", epoch,
                          n / (time.time() - tic))
+        return step, records
     else:
         from incubator_mxnet_tpu import autograd, gluon
         trainer = gluon.Trainer(net.collect_params(), "sgd",
@@ -131,12 +149,11 @@ def main():
                 trainer.step(args.batch_size)
                 n += args.batch_size
                 if (i + 1) % args.disp_batches == 0:
-                    logging.info("epoch %d batch %d loss %.4f  %.1f img/s",
-                                 epoch, i + 1, float(loss.asnumpy()),
-                                 n / (time.time() - tic))
+                    show(epoch, i, loss, n, tic)
             data.reset()
             logging.info("epoch %d done: %.1f img/s", epoch,
                          n / (time.time() - tic))
+        return trainer, records
 
 
 if __name__ == "__main__":

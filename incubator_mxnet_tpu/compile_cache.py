@@ -35,7 +35,8 @@ retraces), and aggregate `exec_cache_{hits,misses,disk_hits,evictions,
 bytes}` counters surface in `profiler.dumps()` and `render_prometheus()`.
 
 This cache is complementary to jax's own persistent *compilation* cache
-(`MXTPU_COMPILE_CACHE`, configured in `__init__._configure_jax`): that one
+(`JAX_COMPILATION_CACHE_DIR`, else `<checkout>/.jax_cache` — see
+`__init__._configure_jax`): that one
 still pays tracing + lowering + cache-key hashing per process; this one
 skips straight from abstract shapes to a loaded executable.
 """
@@ -164,7 +165,7 @@ def _fingerprint(key, opts_repr, traced, sig):
     processes. sha256 throughout — builtin hash() is per-process salted."""
     import numpy as np
     h = hashlib.sha256()
-    for part in ("mxec1", _jax_version(), _backend(), _device_kind(),
+    for part in ("mxec2", _jax_version(), _backend(), _device_kind(),
                  key, opts_repr, str(sig[0]), repr(sig[1])):
         h.update(part.encode())
         h.update(b"\x00")
@@ -236,9 +237,16 @@ def _disk_load(fp):
             raise ValueError("fingerprint mismatch")
         if hashlib.sha256(body).hexdigest() != sha:
             raise ValueError("checksum mismatch")
-        payload, in_tree, out_tree = pickle.loads(body)
+        payload, in_tree, out_tree, device_ids = pickle.loads(body)
+        import jax
         from jax.experimental import serialize_executable as _se
-        return _se.deserialize_and_load(payload, in_tree, out_tree)
+        # load onto the devices the executable was compiled for: the
+        # default is EVERY local device, which turns a one-device
+        # executable into an N-shard one on any multi-device host
+        by_id = {d.id: d for d in jax.local_devices()}
+        return _se.deserialize_and_load(
+            payload, in_tree, out_tree,
+            execution_devices=[by_id[i] for i in device_ids])
     except Exception as exc:    # noqa: BLE001 — corruption/skew degrade
         with _lock:
             _stats["disk_errors"] += 1
@@ -265,8 +273,10 @@ def _disk_store(fp, exe):
     try:
         from jax.experimental import serialize_executable as _se
         payload, in_tree, out_tree = _se.serialize(exe)
-        body = pickle.dumps((payload, in_tree, out_tree))
-    except Exception:           # noqa: BLE001 — host callbacks, old jax
+        device_ids = [d.id for d in
+                      exe.runtime_executable().local_devices()]
+        body = pickle.dumps((payload, in_tree, out_tree, device_ids))
+    except Exception:           # noqa: BLE001 — e.g. host callbacks
         with _lock:
             _stats["disk_errors"] += 1
         return False

@@ -337,11 +337,8 @@ class HostOffloader:
     @staticmethod
     def _probe_host_kind():
         import jax
-        try:
-            kinds = {m.kind for d in jax.local_devices()
-                     for m in d.addressable_memories()}
-        except Exception:                       # noqa: BLE001 — old jax
-            return None
+        kinds = {m.kind for d in jax.local_devices()
+                 for m in d.addressable_memories()}
         for kind in ("pinned_host", "unpinned_host"):
             if kind in kinds:
                 return kind

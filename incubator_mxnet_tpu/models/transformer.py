@@ -115,13 +115,15 @@ class TransformerLM:
         v = jnp.var(x.astype(jnp.float32), axis=-1, keepdims=True)
         return ((x - m) * jax.lax.rsqrt(v + 1e-5)).astype(x.dtype) * g + b
 
-    def _block(self, params, prefix, x, sp_axis, tp_axis=None):
+    def _block(self, params, prefix, x, sp_axis, tp_axis=None, mesh=None):
         """One pre-norm block. Inside shard_map, attention/MLP weights may be
         Megatron-sharded over `tp_axis` (wq/wk/wv/w_in column-parallel,
         wo/w_out row-parallel): each device computes its local slice of heads
         / hidden units and a psum over tp after each row-parallel matmul
         restores the full residual stream. Head/hidden split is read off the
-        *local* weight shapes, so the same code serves the unsharded path."""
+        *local* weight shapes, so the same code serves the unsharded path.
+        `mesh` is the multi-device mesh of a pure-jit (GSPMD) caller: the
+        flash kernel then runs per shard."""
         cfg = self.cfg
         B, T, D = x.shape
         hd = D // cfg.n_heads
@@ -142,7 +144,17 @@ class TransformerLM:
             # layout; flash_attention_bh stays for callers that already
             # hold (BH,T,D).
             from ..parallel.flash_attention import flash_attention
-            attn = flash_attention(q, kk, v, causal=True)
+            attn_fn = functools.partial(flash_attention, causal=True)
+            if mesh is not None:
+                # a Mosaic kernel cannot be partitioned automatically (jax
+                # refuses at lowering): run it per shard. Attention is
+                # independent per sequence and per head, which is exactly
+                # how GSPMD lays q/k/v out — batch over dp, heads over tp.
+                from ..parallel._compat import shard_map
+                spec = P("dp" if "dp" in mesh.axis_names else None, None,
+                         "tp" if "tp" in mesh.axis_names else None, None)
+                attn_fn = shard_map(attn_fn, mesh, (spec,) * 3, spec)
+            attn = attn_fn(q, kk, v)
         else:
             attn = attention_reference(q, kk, v, causal=True)
         attn_out = attn.reshape(B, T, d_local) @ params[prefix + "wo"]
@@ -157,10 +169,12 @@ class TransformerLM:
         y = checkpoint_name(y, "mlp_out")
         return x + y
 
-    def apply(self, params, tokens, sp_axis=None, positions=None, tp_axis=None):
+    def apply(self, params, tokens, sp_axis=None, positions=None, tp_axis=None,
+              mesh=None):
         """tokens (B, T) int32 -> logits (B, T, vocab). When called inside a
         shard_map with a sequence axis, pass sp_axis and per-shard positions;
-        pass tp_axis when attention/MLP weights are Megatron-sharded."""
+        pass tp_axis when attention/MLP weights are Megatron-sharded; pass
+        the mesh when tracing a pure-jit program over several devices."""
         cfg = self.cfg
         x = params["embed"][tokens]
         if positions is None:
@@ -168,18 +182,20 @@ class TransformerLM:
         x = x + params["pos_embed"][positions]
         if cfg.remat:
             block = jax.checkpoint(
-                lambda p, pref, y: self._block(p, pref, y, sp_axis, tp_axis),
+                lambda p, pref, y: self._block(p, pref, y, sp_axis, tp_axis,
+                                               mesh),
                 static_argnums=(1,), policy=_remat_policy(cfg.remat_policy))
         else:
-            block = lambda p, pref, y: self._block(p, pref, y, sp_axis, tp_axis)
+            block = lambda p, pref, y: self._block(p, pref, y, sp_axis,
+                                                   tp_axis, mesh)
         for i in range(cfg.n_layers):
             x = block(params, f"layer{i}_", x)
         x = self._ln(x, params["lnf_g"], params["lnf_b"])
         return (x @ params["embed"].T).astype(jnp.float32)
 
     def loss(self, params, tokens, targets, sp_axis=None, positions=None,
-             tp_axis=None):
-        logits = self.apply(params, tokens, sp_axis, positions, tp_axis)
+             tp_axis=None, mesh=None):
+        logits = self.apply(params, tokens, sp_axis, positions, tp_axis, mesh)
         logp = jax.nn.log_softmax(logits, axis=-1)
         nll = -jnp.take_along_axis(logp, targets[..., None], axis=-1)[..., 0]
         return jnp.mean(nll)
@@ -262,7 +278,8 @@ class TransformerLM:
                 fn = shard_map(local, mesh,
                                (pspec, data_spec, data_spec), P())
                 return fn(params, tokens, targets)
-            return model.loss(params, tokens, targets)
+            return model.loss(params, tokens, targets,
+                              mesh=mesh if mesh.devices.size > 1 else None)
 
         from ..parallel.train import _make_update_rule
         _, adam_rule = _make_update_rule("adam", lr, 0.0, 0.0, {})
@@ -294,15 +311,16 @@ class TransformerLM:
 
             step = multi
 
-        in_shardings = (
-            {n: NamedSharding(mesh, s) for n, s in pspec.items()},
-            {n: (NamedSharding(mesh, pspec[n]), NamedSharding(mesh, pspec[n]))
-             for n in pspec},
-            NamedSharding(mesh, data_spec),
-            NamedSharding(mesh, data_spec),
-            None,
-        )
-        jit_step = jax.jit(step, in_shardings=in_shardings,
+        param_sh = {n: NamedSharding(mesh, s) for n, s in pspec.items()}
+        opt_sh = {n: (param_sh[n], param_sh[n]) for n in pspec}
+        data_sh = NamedSharding(mesh, data_spec)
+        # outputs pinned to the input layout: left to the compiler, a
+        # replicated leaf can come back sharded and the next (donating)
+        # call then refuses it
+        jit_step = jax.jit(step,
+                           in_shardings=(param_sh, opt_sh, data_sh, data_sh,
+                                         None),
+                           out_shardings=(param_sh, opt_sh, None),
                            donate_argnums=(0, 1))
 
         def shard_params(params):
@@ -314,8 +332,11 @@ class TransformerLM:
                     for k, v in params.items()}
 
         def init_opt(params):
-            return {k: (jnp.zeros(v.shape, jnp.float32),
-                        jnp.zeros(v.shape, jnp.float32))
+            # born with the step's layout: unplaced zeros would all land
+            # on device 0 first and give the first call a signature (and a
+            # trace) of its own
+            return {k: (jnp.zeros(v.shape, jnp.float32, device=param_sh[k]),
+                        jnp.zeros(v.shape, jnp.float32, device=param_sh[k]))
                     for k, v in params.items()}
 
         return jit_step, shard_params, init_opt

@@ -1,153 +1,15 @@
-"""JAX API compatibility shims for the parallel stack.
+"""The one shard_map spelling the parallel stack uses.
 
-jax moved shard_map from `jax.experimental.shard_map` (kwarg `check_rep`) to
-`jax.shard_map` (keyword-only, kwarg `check_vma`). We feature-detect once at
-import so every caller in this package works on either API, with replication
-checking disabled (our loss reductions pmean over every mesh axis themselves).
-
-This module also backports a fix for the legacy shard_map transpose rule
-(see `_patch_shard_map_transpose` below): differentiating a shard_map whose
-body scans with a scalar carry — exactly what the composed train step's aux
-accumulation does — mispairs cotangents with in_names and dies with a
-`_SpecError` on affected jax versions.
+Replication checking is off: the loss reductions pmean over every mesh
+axis themselves, which `check_vma` cannot see through.
 """
 from __future__ import annotations
-
-import inspect
 
 import jax
 
 __all__ = ["shard_map"]
 
 
-def _make_shard_map():
-    new = getattr(jax, "shard_map", None)
-    if new is not None:
-        sig = inspect.signature(new)
-        if "check_vma" in sig.parameters:
-            def shard_map(f, mesh, in_specs, out_specs):
-                return new(f, mesh=mesh, in_specs=in_specs,
-                           out_specs=out_specs, check_vma=False)
-            return shard_map
-    from jax.experimental.shard_map import shard_map as old
-
-    sig = inspect.signature(old)
-    kw = {}
-    if "check_rep" in sig.parameters:
-        kw["check_rep"] = False
-    elif "check_vma" in sig.parameters:
-        kw["check_vma"] = False
-
-    def shard_map(f, mesh, in_specs, out_specs):
-        return old(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, **kw)
-
-    return shard_map
-
-
-shard_map = _make_shard_map()
-
-
-def _patch_shard_map_transpose():
-    """Backport the fix for the legacy shard_map transpose bookkeeping bug.
-
-    In jax 0.4.x's `_shard_map_transpose`, the transposed body partial-evals
-    the linear jaxpr on the undefined primals and runs `backward_pass` over
-    `jaxpr_unknown`, whose invars are `[inner residuals..., undefined
-    primals...]`. The resulting cotangent list is then zipped against
-    `in_names`, which is indexed by the *original* invars. Whenever the
-    inner partial-eval mints fresh residuals (any scan body does), the two
-    lists have different lengths and meanings: cotangents get paired with
-    the wrong names, and a scalar inner residual paired with a sharded name
-    raises `_SpecError` from `_check_names`. Newer jax rewrote the rule;
-    here we re-seat the cotangents at their original invar positions before
-    the name zip. Patching is skipped wholesale when the module layout is
-    not the one this backport understands.
-    """
-    try:
-        from jax.experimental import shard_map as sm
-        # Only the legacy experimental module has this rule; probe every
-        # internal we touch so a partially-matching future version is left
-        # alone rather than half-patched.
-        needed = (sm._shard_map_transpose, sm._shard_aval, sm._unshard_aval,
-                  sm._unmentioned2, sm.shard_map_p, sm.ad, sm.pe, sm.core,
-                  sm.lu, sm.dtypes, sm.prod, sm.partition_list,
-                  sm.tree_flatten, sm.tree_unflatten,
-                  sm.flatten_fun_nokwargs)
-        del needed
-        if sm.ad.primitive_transposes.get(sm.shard_map_p) \
-                is not sm._shard_map_transpose:
-            return False  # someone else already swapped the rule; leave it
-    except (ImportError, AttributeError):
-        return False
-
-    ad, pe, core, lu = sm.ad, sm.pe, sm.core, sm.lu
-
-    def fixed_transpose(out_cts, *args, jaxpr, mesh, in_names, out_names,
-                        check_rep, rewrite, auto):
-        mb_div = lambda x, y: x / y if y != 1 else x
-        out_cts = [
-            ad.Zero(sm._shard_aval(mesh, ns, x.aval)) if type(x) is ad.Zero
-            else x if rewrite or sm.dtypes.dtype(x) == sm.dtypes.float0
-            else mb_div(x, sm.prod(map(mesh.shape.get,
-                                       sm._unmentioned2(mesh, ns, auto))))
-            for ns, x in zip(out_names, out_cts)]
-        args = [x if type(x) is not ad.UndefinedPrimal else
-                ad.UndefinedPrimal(sm._shard_aval(mesh, ns, x.aval))
-                for ns, x in zip(in_names, args)]
-        all_args, in_tree = sm.tree_flatten((out_cts, args))
-
-        @lu.wrap_init
-        def fun_trans(out_cts, args):
-            undef = [ad.is_undefined_primal(a) for a in args]
-            res, undefs = sm.partition_list(undef, args)
-            jaxpr_known, jaxpr_unknown, _, _ = pe.partial_eval_jaxpr_nounits(
-                pe.close_jaxpr(jaxpr), undef, False)
-            res_reshaped = core.jaxpr_as_fun(jaxpr_known)(*res)
-            cts = ad.backward_pass(
-                jaxpr_unknown.jaxpr, False, (), (*res_reshaped, *undefs),
-                out_cts)
-            # cotangents for jaxpr_unknown's invars = [inner residuals,
-            # undefined primals]; only the tail corresponds to original
-            # invars — re-seat it before pairing with in_names.
-            cts = cts[len(res_reshaped):]
-            cts_it = iter(cts)
-            out = []
-            for ns, a in zip(in_names, args):
-                if not ad.is_undefined_primal(a):
-                    out.append(ad.Zero(
-                        sm._unshard_aval(mesh, ns, core.get_aval(a))))
-                    continue
-                x = next(cts_it)
-                out.append(
-                    ad.Zero(sm._unshard_aval(mesh, ns, x.aval))
-                    if type(x) is ad.Zero else x if rewrite
-                    else jax.lax.psum(
-                        x, tuple(sm._unmentioned2(mesh, ns, auto))))
-            return out
-
-        fun_trans, nz_arg_cts = ad.nonzero_outputs(fun_trans)
-        fun_trans_flat, out_tree = sm.flatten_fun_nokwargs(fun_trans, in_tree)
-
-        new_in_names = \
-            [n for n, x in zip(out_names, out_cts)
-             if type(x) is not ad.Zero] + \
-            [n for n, x in zip(in_names, args)
-             if type(x) is not ad.UndefinedPrimal]
-
-        def new_out_names_thunk():
-            return tuple(names for names, nz in zip(in_names, nz_arg_cts())
-                         if nz)
-
-        out_flat = sm.shard_map_p.bind(
-            fun_trans_flat, *all_args, mesh=mesh,
-            in_names=tuple(new_in_names),
-            out_names_thunk=new_out_names_thunk, check_rep=check_rep,
-            rewrite=rewrite, auto=auto)
-        return sm.tree_unflatten(out_tree(), out_flat)
-
-    sm._shard_map_transpose = fixed_transpose
-    ad.primitive_transposes[sm.shard_map_p] = fixed_transpose
-    return True
-
-
-_TRANSPOSE_PATCHED = _patch_shard_map_transpose()
+def shard_map(f, mesh, in_specs, out_specs):
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)

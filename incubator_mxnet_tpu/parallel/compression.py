@@ -18,6 +18,8 @@ contributor, carried forward by the residual.
 """
 from __future__ import annotations
 
+import functools
+
 import jax
 import jax.numpy as jnp
 from jax import lax
@@ -168,20 +170,27 @@ def quantized_psum(x, axis_name, threshold, residual):
     return jnp.sum(deq, axis=0).reshape(x.shape), new_res
 
 
+@functools.lru_cache(maxsize=32)
+def _allreduce_fn(mesh, axis, threshold):
+    """One jitted program per (mesh, axis, threshold): an un-jitted
+    shard_map over a fresh closure retraces and recompiles on every
+    call."""
+    from jax.sharding import PartitionSpec as P
+
+    def inner(xx, rr):
+        return quantized_psum(xx, axis, threshold, rr)
+
+    return jax.jit(shard_map(inner, mesh, in_specs=(P(), P()),
+                             out_specs=(P(), P())))
+
+
 def quantized_allreduce(x, mesh, threshold, residual=None, axis=None):
     """Whole-array entry: replicated x (and residual) -> (sum over the
     axis members' quantized contributions, new residual). With a
     replicated input every member contributes the same value — the
     multi-process kvstore instead passes per-process values via its
     collective mesh (kvstore._axis0_packed_sum)."""
-    from jax.sharding import PartitionSpec as P
-
     if residual is None:
         residual = jnp.zeros_like(x)
-    axis = axis or mesh.axis_names[0]
-
-    def inner(xx, rr):
-        return quantized_psum(xx, axis, threshold, rr)
-
-    return shard_map(inner, mesh, in_specs=(P(), P()),
-                     out_specs=(P(), P()))(x, residual)
+    fn = _allreduce_fn(mesh, axis or mesh.axis_names[0], float(threshold))
+    return fn(x, residual)

@@ -51,15 +51,7 @@ _ACC = jnp.float32
 
 
 def _compiler_params(pltpu):
-    # the params class has been renamed across jax releases
-    # (CompilerParams <-> TPUCompilerParams); accept either and degrade
-    # to backend defaults when neither fits
-    cls = getattr(pltpu, "CompilerParams", None) or \
-        getattr(pltpu, "TPUCompilerParams", None)
-    try:
-        return cls(dimension_semantics=("arbitrary",))
-    except TypeError:
-        return None
+    return pltpu.CompilerParams(dimension_semantics=("arbitrary",))
 
 
 def _interpret():

@@ -28,11 +28,14 @@ untileable shapes and the no-pallas path.
 from __future__ import annotations
 
 import functools
+import logging
+import threading
 
 import jax
 import jax.numpy as jnp
 
-__all__ = ["flash_attention", "flash_attention_bh", "pallas_available"]
+__all__ = ["flash_attention", "flash_attention_bh", "pallas_available",
+           "dispatch_stats"]
 
 _NEG_INF = -1e30
 
@@ -135,15 +138,8 @@ def _fa_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_sc, l_sc, acc_sc, *,
 
 def _compiler_params():
     from jax.experimental.pallas import tpu as pltpu
-    # the params class has been renamed across jax releases
-    # (CompilerParams <-> TPUCompilerParams); accept either and degrade
-    # to backend defaults when neither fits
-    cls = getattr(pltpu, "CompilerParams", None) or \
-        getattr(pltpu, "TPUCompilerParams", None)
-    try:
-        return cls(dimension_semantics=("parallel", "parallel", "arbitrary"))
-    except TypeError:
-        return None
+    return pltpu.CompilerParams(
+        dimension_semantics=("parallel", "parallel", "arbitrary"))
 
 
 def _interpret():
@@ -384,11 +380,52 @@ def _fa_backward(q, k, v, do, lse, out, dlse, causal, sm_scale, block_q,
     return dq, dk, dv
 
 
-def _pick_block(t, preferred):
-    for b in (preferred, 512, 256, 128, 64, 32, 16, 8):
-        if b <= t and t % b == 0:
+def _pick_block(t, preferred=1024):
+    """Kernel block for an axis of length `t`, under the TPU tiling rule:
+    the last two dims of every block are multiples of (8, 128) or span
+    the array. The k block is the LANE dim of the pre-transposed key, so
+    a partial block is a multiple of 128; an axis that fits in
+    `preferred` is taken whole (rows in multiples of 8). None = no legal
+    block, the caller takes the XLA path."""
+    if t % 8:
+        return None
+    if t <= preferred:
+        return t
+    for b in (preferred, 512, 256, 128):
+        if t % b == 0:
             return b
     return None
+
+
+# Which implementation each traced call got, by reason. Attention drops
+# to the O(T^2) XLA reference when no block fits; that must be a choice
+# somebody can see, not a silent one (chip_smoke.py asserts on it).
+_dispatch = {"pallas": 0, "reference": 0}
+_dispatch_lock = threading.Lock()
+
+
+def dispatch_stats():
+    """{"pallas": n, "reference": n}: traced flash_attention/flash_hop
+    calls served by the Pallas kernels vs dropped to attention_reference."""
+    with _dispatch_lock:
+        return dict(_dispatch)
+
+
+def _blocks_for(tq, tk, d):
+    """(block_q, block_k) when the Pallas kernels take this shape, else
+    None — counted and logged either way."""
+    bq, bk = _pick_block(tq), _pick_block(tk)
+    ok = pallas_available() and bq is not None and bk is not None \
+        and d % 8 == 0
+    with _dispatch_lock:
+        _dispatch["pallas" if ok else "reference"] += 1
+    if not ok:
+        logging.warning(
+            "flash_attention: Tq=%d Tk=%d D=%d has no legal kernel block "
+            "(blocks %s/%s); using the O(T^2) attention_reference",
+            tq, tk, d, bq, bk)
+        return None
+    return bq, bk
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
@@ -404,12 +441,12 @@ def _flash_fwd_impl(q, k, v, causal, sm_scale, want_lse=False):
     # v5e-tuned r4: (1024, 1024) — 33.8 TF/s fwd at T=2048 (vs 30.5 at
     # the r3 (512,1024) tune) and 53.4 at T=8192 (vs 46.6); the r3 sweep
     # predates the backward/block interplay (docs/perf_notes.md)
-    bq = _pick_block(Tq, 1024)
-    bk = _pick_block(Tk, 1024)
-    if not pallas_available() or bq is None or bk is None or D % 8:
+    blocks = _blocks_for(Tq, Tk, D)
+    if blocks is None:
         out = attention_reference(q, k, v, causal=causal,
                                   sm_scale=sm_scale)
         return (out, None) if want_lse else out
+    bq, bk = blocks
     out, lse = _fa_forward(_to_bh(q), _to_bh(k), _to_bh(v), causal,
                            sm_scale, bq, bk, _interpret())
     out = _un_bh(out, B, H, Tq, D)
@@ -436,8 +473,8 @@ def _flash_vjp_bwd(causal, sm_scale, res, g):
     if lse is not None:
         # v5e block sweep (docs/perf_notes.md round 4): (1024,1024) runs
         # the backward pair at 34.3 TF/s vs 28.9 at the old (512,512)
-        bq = _pick_block(Tq, 1024)
-        bk = _pick_block(Tk, 1024)
+        bq = _pick_block(Tq)
+        bk = _pick_block(Tk)
         do_bh = _to_bh(g)
         dq, dk, dv = _fa_backward(_to_bh(q), _to_bh(k), _to_bh(v), do_bh,
                                   lse, _to_bh(out),
@@ -514,8 +551,8 @@ def flash_hop(q, k, v, causal, sm_scale):
 
 def _flash_hop_fwd_impl(q, k, v, causal, sm_scale):
     B, T, H, D = q.shape
-    bq = _pick_block(T, 1024)
-    bk = _pick_block(k.shape[1], 1024)
+    bq = _pick_block(T)
+    bk = _pick_block(k.shape[1])
     out, lse = _fa_forward(_to_bh(q), _to_bh(k), _to_bh(v), causal,
                            sm_scale, bq, bk, _interpret())
     lse_bht = lse.reshape(B, H, T)
@@ -533,8 +570,8 @@ def _flash_hop_vjp_bwd(causal, sm_scale, res, cts):
     q, k, v, out, lse = res
     B, Tq, H, D = q.shape
     Tk = k.shape[1]
-    bq = _pick_block(Tq, 1024)
-    bk = _pick_block(Tk, 1024)
+    bq = _pick_block(Tq)
+    bk = _pick_block(Tk)
     lse_kern = jnp.where(jnp.isfinite(lse), lse, 1e30).reshape(
         B * H, Tq, 1).astype(jnp.float32)
     dlse = g_lse.reshape(B * H, Tq, 1).astype(jnp.float32)

@@ -31,10 +31,13 @@ def functionalize(net, example_inputs, training=True):
                  for x in example_inputs]
     # resolve deferred shapes with one abstract pass
     import jax
+    from .. import tune
     # the state scope swallows traced stat writes (BatchNorm running stats)
-    # so abstract tracers never land in Parameters
+    # so abstract tracers never land in Parameters; nothing traced here
+    # ever runs, so the tuner must not race kernels for it
     with _TraceScope(), autograd.pause(train_mode=training), \
-            _rnd._TraceKeyScope(jax.random.PRNGKey(0)), _StateWriteScope():
+            _rnd._TraceKeyScope(jax.random.PRNGKey(0)), _StateWriteScope(), \
+            tune.xla_only("functionalize's shape-only pass"):
         jax.eval_shape(
             lambda *xs: _abstract(net, xs),
             *[jax.ShapeDtypeStruct(x._data.shape, x._data.dtype)

@@ -57,14 +57,10 @@ def _interpret():
 
 def _compiler_params():
     from jax.experimental.pallas import tpu as pltpu
-    cls = getattr(pltpu, "CompilerParams", None) or \
-        getattr(pltpu, "TPUCompilerParams", None)
-    try:
-        # batch axis is parallel; the page axis accumulates running
-        # softmax statistics, so it must stay "arbitrary" (sequential)
-        return cls(dimension_semantics=("parallel", "arbitrary"))
-    except TypeError:
-        return None
+    # batch axis is parallel; the page axis accumulates running
+    # softmax statistics, so it must stay "arbitrary" (sequential)
+    return pltpu.CompilerParams(
+        dimension_semantics=("parallel", "arbitrary"))
 
 
 def _scale(sm_scale, d):
@@ -110,6 +106,33 @@ def paged_attention_reference(q, k_pages, v_pages, page_table, seq_lens,
 # Pallas kernel
 # ---------------------------------------------------------------------------
 
+def _page_update(q, k, v, seq_len, j, page_size, m_prev, l_prev, acc_prev):
+    """Fold one (page_size, H, D) page into one query's running softmax.
+
+    Everything stays in the page's own (rows, H, D) layout — heads on
+    sublanes, head_dim on lanes — and runs on the VPU: a decode query is
+    one row per head, far too thin for the MXU, and Mosaic takes neither
+    a dot batched over a non-leading axis nor the transposes that would
+    make H lead. Scores and the per-head statistics m/l are carried
+    broadcast along the lane axis, shaped like the page and the
+    accumulator, so no step needs a relayout. q (H, D) f32 pre-scaled; k, v (page_size, H, D); returns
+    the new (m, l, acc), each (H, D) f32."""
+    from jax import lax
+    k = k.astype(jnp.float32)
+    v = v.astype(jnp.float32)
+    # s[p, h, :] = sum_d q[h, d] * k[p, h, d], the same value on every lane
+    s = jnp.broadcast_to(jnp.sum(q[None] * k, axis=-1, keepdims=True),
+                         k.shape)
+    pos = j * page_size + lax.broadcasted_iota(jnp.int32, s.shape, 0)
+    s = jnp.where(pos < seq_len, s, _NEG_INF)
+    m_new = jnp.maximum(m_prev, jnp.max(s, axis=0))
+    p = jnp.exp(s - m_new[None])
+    alpha = jnp.exp(m_prev - m_new)
+    l_new = l_prev * alpha + jnp.sum(p, axis=0)
+    acc_new = acc_prev * alpha + jnp.sum(p * v, axis=0)
+    return m_new, l_new, acc_new
+
+
 def _pa_kernel(pt_ref, sl_ref, q_ref, k_ref, v_ref, o_ref,
                m_sc, l_sc, acc_sc, *, page_size, sm_scale):
     """One (sequence b, page j) grid step. The page axis is innermost
@@ -120,8 +143,7 @@ def _pa_kernel(pt_ref, sl_ref, q_ref, k_ref, v_ref, o_ref,
 
     Refs: q (1, H, D) | k, v (1, page_size, H, D) — the ONE pool page
     pt_ref[b, j] selected by the scalar-prefetched index map — | o
-    (1, H, D); scratch m, l (H, 128), acc (H, D), all f32."""
-    from jax import lax
+    (1, H, D); scratch m, l, acc (H, D), all f32."""
     from jax.experimental import pallas as pl
 
     b = pl.program_id(0)
@@ -131,40 +153,24 @@ def _pa_kernel(pt_ref, sl_ref, q_ref, k_ref, v_ref, o_ref,
 
     @pl.when(j == 0)
     def _init():
-        m_sc[:] = jnp.full_like(m_sc, _NEG_INF)
-        l_sc[:] = jnp.zeros_like(l_sc)
-        acc_sc[:] = jnp.zeros_like(acc_sc)
+        m_sc[...] = jnp.full_like(m_sc, _NEG_INF)
+        l_sc[...] = jnp.zeros_like(l_sc)
+        acc_sc[...] = jnp.zeros_like(acc_sc)
 
     # a page past the sequence's tail contributes nothing: skip it (and
     # its statistics update) entirely — this is where raggedness wins
     @pl.when(j * page_size < seq_len)
     def _step():
-        prec = _prec(q_ref.dtype)
-        q = q_ref[0] * jnp.asarray(sm_scale, q_ref.dtype)   # (H, D)
-        k = k_ref[0]                                        # (ps, H, D)
-        v = v_ref[0]
-        # s[h, p] = sum_d q[h, d] * k[p, h, d]
-        s = lax.dot_general(q, k, (((1,), (2,)), ((0,), (1,))),
-                            precision=prec,
-                            preferred_element_type=jnp.float32)
-        pos = j * page_size + lax.broadcasted_iota(jnp.int32, s.shape, 1)
-        s = jnp.where(pos < seq_len, s, _NEG_INF)
-        m_prev = m_sc[:, 0]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1))
-        p = jnp.exp(s - m_new[:, None])
-        alpha = jnp.exp(m_prev - m_new)
-        l_sc[:, 0] = l_sc[:, 0] * alpha + jnp.sum(p, axis=-1)
-        # acc[h, d] = acc * alpha + sum_p p[h, p] * v[p, h, d]
-        pv = lax.dot_general(p.astype(v.dtype), v,
-                             (((1,), (0,)), ((0,), (1,))),
-                             precision=prec,
-                             preferred_element_type=jnp.float32)
-        m_sc[:, 0] = m_new
-        acc_sc[:] = acc_sc[:] * alpha[:, None] + pv
+        # scaled in the INPUT dtype, like the reference
+        q = (q_ref[0] * jnp.asarray(sm_scale, q_ref.dtype)) \
+            .astype(jnp.float32)
+        m_sc[...], l_sc[...], acc_sc[...] = _page_update(
+            q, k_ref[0], v_ref[0], seq_len, j, page_size,
+            m_sc[...], l_sc[...], acc_sc[...])
 
     @pl.when(j == n_j - 1)
     def _finish():
-        o_ref[0] = (acc_sc[:] / l_sc[:, 0][:, None]).astype(o_ref.dtype)
+        o_ref[0] = (acc_sc[...] / l_sc[...]).astype(o_ref.dtype)
 
 
 def paged_attention_pallas(q, k_pages, v_pages, page_table, seq_lens,
@@ -197,11 +203,7 @@ def paged_attention_pallas(q, k_pages, v_pages, page_table, seq_lens,
                          lambda b, j, pt, sl: (pt[b, j], 0, 0, 0)),
         ],
         out_specs=pl.BlockSpec((1, H, D), lambda b, j, pt, sl: (b, 0, 0)),
-        scratch_shapes=[
-            pltpu.VMEM((H, 128), jnp.float32),
-            pltpu.VMEM((H, 128), jnp.float32),
-            pltpu.VMEM((H, D), jnp.float32),
-        ],
+        scratch_shapes=[pltpu.VMEM((H, D), jnp.float32)] * 3,
     )
     call = pl.pallas_call(
         kernel,
@@ -264,55 +266,42 @@ def _pa_mq_kernel(pt_ref, sl_ref, q_ref, k_ref, v_ref, o_ref,
     double-buffered page walk as _pa_kernel, but the flash recurrence
     carries a G axis: each of the sequence's G query tokens keeps its
     own (max, sumexp, acc) and its own length mask, all fed by the ONE
-    page this step DMA'd.
+    page this step DMA'd. G is small and static (k+1 speculation
+    positions), so the queries are a python loop over _page_update and
+    each length is one scalar SMEM read.
 
     Refs: q (1, G, H, D) | k, v (1, page_size, H, D) | o (1, G, H, D);
-    scratch m, l (H, G, 128), acc (H, G, D), all f32."""
-    from jax import lax
+    scratch m, l, acc (G, H, D), all f32."""
     from jax.experimental import pallas as pl
 
     b = pl.program_id(0)
     j = pl.program_id(1)
     n_j = pl.num_programs(1)
-    sl = jnp.maximum(sl_ref[b], 1)                       # (G,)
+    n_g = q_ref.shape[1]
+    sl = [jnp.maximum(sl_ref[b, g], 1) for g in range(n_g)]
+    longest = functools.reduce(jnp.maximum, sl)
 
     @pl.when(j == 0)
     def _init():
-        m_sc[:] = jnp.full_like(m_sc, _NEG_INF)
-        l_sc[:] = jnp.zeros_like(l_sc)
-        acc_sc[:] = jnp.zeros_like(acc_sc)
+        m_sc[...] = jnp.full_like(m_sc, _NEG_INF)
+        l_sc[...] = jnp.zeros_like(l_sc)
+        acc_sc[...] = jnp.zeros_like(acc_sc)
 
     # skip pages past the LONGEST query's tail; shorter queries inside
-    # the page are handled by the per-query mask below
-    @pl.when(j * page_size < jnp.max(sl))
+    # the page are handled by the per-query mask
+    @pl.when(j * page_size < longest)
     def _step():
-        prec = _prec(q_ref.dtype)
-        q = q_ref[0] * jnp.asarray(sm_scale, q_ref.dtype)   # (G, H, D)
-        k = k_ref[0]                                        # (ps, H, D)
+        k = k_ref[0]
         v = v_ref[0]
-        # s[h, g, p] = sum_d q[g, h, d] * k[p, h, d]
-        s = lax.dot_general(q, k, (((2,), (2,)), ((1,), (1,))),
-                            precision=prec,
-                            preferred_element_type=jnp.float32)
-        pos = j * page_size + lax.broadcasted_iota(jnp.int32, s.shape, 2)
-        s = jnp.where(pos < sl[None, :, None], s, _NEG_INF)
-        m_prev = m_sc[:, :, 0]                              # (H, G)
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1))
-        p = jnp.exp(s - m_new[:, :, None])
-        alpha = jnp.exp(m_prev - m_new)
-        l_sc[:, :, 0] = l_sc[:, :, 0] * alpha + jnp.sum(p, axis=-1)
-        # pv[h, g, d] = sum_p p[h, g, p] * v[p, h, d]
-        pv = lax.dot_general(p.astype(v.dtype), v,
-                             (((2,), (0,)), ((0,), (1,))),
-                             precision=prec,
-                             preferred_element_type=jnp.float32)
-        m_sc[:, :, 0] = m_new
-        acc_sc[:] = acc_sc[:] * alpha[:, :, None] + pv
+        for g in range(n_g):
+            q = (q_ref[0, g] * jnp.asarray(sm_scale, q_ref.dtype)) \
+                .astype(jnp.float32)
+            m_sc[g], l_sc[g], acc_sc[g] = _page_update(
+                q, k, v, sl[g], j, page_size, m_sc[g], l_sc[g], acc_sc[g])
 
     @pl.when(j == n_j - 1)
     def _finish():
-        o = acc_sc[:] / l_sc[:, :, 0][:, :, None]           # (H, G, D)
-        o_ref[0] = o.transpose(1, 0, 2).astype(o_ref.dtype)
+        o_ref[0] = (acc_sc[...] / l_sc[...]).astype(o_ref.dtype)
 
 
 def paged_attention_mq_pallas(q, k_pages, v_pages, page_table, seq_lens,
@@ -345,11 +334,7 @@ def paged_attention_mq_pallas(q, k_pages, v_pages, page_table, seq_lens,
         ],
         out_specs=pl.BlockSpec((1, G, H, D),
                                lambda b, j, pt, sl: (b, 0, 0, 0)),
-        scratch_shapes=[
-            pltpu.VMEM((H, G, 128), jnp.float32),
-            pltpu.VMEM((H, G, 128), jnp.float32),
-            pltpu.VMEM((H, G, D), jnp.float32),
-        ],
+        scratch_shapes=[pltpu.VMEM((G, H, D), jnp.float32)] * 3,
     )
     call = pl.pallas_call(
         kernel,

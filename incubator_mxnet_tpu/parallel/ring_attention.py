@@ -78,11 +78,10 @@ def _flash_hop(q, k, v, scale, causal):
 
 
 def _flash_ok(q):
-    from .flash_attention import _pick_block, pallas_available
+    from .flash_attention import _blocks_for
 
     B, t, H, D = q.shape
-    return (pallas_available() and _pick_block(t, 1024) is not None
-            and D % 8 == 0)
+    return _blocks_for(t, t, D) is not None
 
 
 def _merge(o_acc, lse_acc, o_b, lse_b):

@@ -11,11 +11,13 @@ kvstore priority=-key).
 """
 from __future__ import annotations
 
+import contextlib
 from collections import OrderedDict
 
 import jax
 import jax.numpy as jnp
 
+from .. import tune as _tune
 from ..base import MXNetError
 from ..ops import optimizer_ops as _oo
 from .functional import functionalize
@@ -231,13 +233,22 @@ class TrainStep:
         self.preplaced_hits = 0
         non_diff = {p.name for p in self._param_list if p.grad_req == "null"}
 
+        # Under a multi-device mesh this is a GSPMD program, and a Mosaic
+        # kernel cannot be partitioned automatically: the tuner's Pallas
+        # candidates are withheld while the net is traced (tune.xla_only).
+        spans = (f"TrainStep traces one program over {mesh.devices.size} "
+                 f"devices" if mesh is not None and mesh.devices.size > 1
+                 else None)
+
         def step_fn(params, opt_state, rng, step_i, *batch):
             inputs, label = batch[:-1], batch[-1]
 
             def loss_of(diff_params):
                 full = dict(params)
                 full.update(diff_params)
-                outs, writes = apply_fn(full, rng, *inputs)
+                with (_tune.xla_only(spans) if spans
+                      else contextlib.nullcontext()):
+                    outs, writes = apply_fn(full, rng, *inputs)
                 out = outs[0]
                 return loss_fn(out, label), (writes, out)
 
@@ -460,10 +471,9 @@ class TrainStep:
         """Run `n` optimizer steps on ONE batch inside a single XLA program
         (lax.scan over the step, params/opt-state carried on device).
 
-        The whole loop is one dispatch: no host round-trip per step, which
-        is what makes steady-state throughput on a remote/tunneled device
-        match on-chip compute (the reference gets the same effect from
-        engine op-bulking, graph_executor.cc:1288 InitOpSegs). Per-step RNG
+        The whole loop is one dispatch: no host round-trip per step (the
+        reference gets the same effect from engine op-bulking,
+        graph_executor.cc:1288 InitOpSegs). Per-step RNG
         is fold_in(step_index). Returns the per-step losses as an NDArray.
         """
         import jax

@@ -60,7 +60,8 @@ __all__ = ["set_config", "set_state", "start", "stop", "dump", "dumps",
            "attribution_reset", "phase_stats", "phase_step_end",
            "last_step_phases", "span_records", "next_span_id", "trace_id",
            "clock_sync_event", "cost_event", "cost_stats",
-           "cost_from_executable", "device_peak_flops", "mfu_stats"]
+           "cost_from_executable", "DEVICE_PEAKS", "device_peaks",
+           "device_peak_flops", "mfu_stats"]
 
 _lock = _mxsan.lock("profiler.py", "_lock")
 _state = {
@@ -342,15 +343,9 @@ def track_jit(key, fn):
     compile_event: a call that grows the executable's internal cache (new
     shape/dtype signature -> XLA retrace+compile) is a miss charged with
     the call's wall time; a steady-state call is a hit.
-
-    Falls back to first-call-is-the-miss accounting when the jit internals
-    don't expose a cache size (older jax, non-jit callables).
     """
-    probe = getattr(fn, "_cache_size", None)
-    # first-call detection must be atomic: concurrent first calls would
-    # otherwise both read called=False and both record a miss (the CC01
-    # unlocked read-modify-write pattern mxlint polices)
-    state = {"called": False, "captured": False}
+    probe = fn._cache_size
+    state = {"captured": False}
     state_lock = _mxsan.lock("profiler.py", "state_lock")
 
     def _maybe_capture(args, kwargs):
@@ -360,11 +355,8 @@ def track_jit(key, fn):
         from . import shardlint as _sl
         if not _sl.enabled():
             return
-        tracer = getattr(fn, "trace", None)
-        if tracer is None:
-            return
         try:
-            _sl.record_jit(key, traced=tracer(*args, **kwargs))
+            _sl.record_jit(key, traced=fn.trace(*args, **kwargs))
         except Exception:       # noqa: BLE001 — capture must never break a call
             pass
 
@@ -375,28 +367,11 @@ def track_jit(key, fn):
                 state["captured"] = True
             if first_capture:
                 _maybe_capture(args, kwargs)
-        before = None
-        if probe is not None:
-            try:
-                before = probe()
-            except Exception:       # noqa: BLE001
-                before = None
+        before = probe()
         t0 = time.perf_counter()
         out = fn(*args, **kwargs)
         dt_ms = (time.perf_counter() - t0) * 1e3
-        after = None
-        if probe is not None:
-            try:
-                after = probe()
-            except Exception:       # noqa: BLE001
-                after = None
-        if before is None or after is None:
-            with state_lock:
-                first = not state["called"]
-                state["called"] = True
-            compile_event(key, cache_hit=not first,
-                          compile_ms=dt_ms if first else 0.0)
-        elif after > before:
+        if probe() > before:
             compile_event(key, cache_hit=False, compile_ms=dt_ms)
         else:
             compile_event(key, cache_hit=True)
@@ -703,9 +678,12 @@ def clock_sync_event(peer, offset_us, rtt_us):
 # guarded by _clock next to the compile table it annotates
 _costs = {}
 
-_PEAK_TFLOPS = {
-    "TPU v4": 275, "TPU v5 lite": 197, "TPU v5e": 197, "TPU v5": 459,
-    "TPU v5p": 459, "TPU v6e": 918, "TPU v6": 918, "TPU v7": 4614,
+# Published per-chip peaks keyed by jax's exact `device_kind` (Google Cloud
+# documentation, "TPU v5e"). THE table: bench.py reads it too. A kind that
+# is not listed is an error — never a default, never a prefix match.
+DEVICE_PEAKS = {
+    "TPU v5 lite": {"bf16_flops": 197e12, "int8_ops": 393e12,
+                    "hbm_bytes_per_sec": 819e9, "hbm_bytes": 16e9},
 }
 
 
@@ -780,19 +758,26 @@ def cost_stats():
     return snap
 
 
+def device_peaks():
+    """The DEVICE_PEAKS row of device 0; an unlisted kind raises."""
+    import jax
+    from .base import MXNetError
+    kind = jax.devices()[0].device_kind
+    if kind not in DEVICE_PEAKS:
+        raise MXNetError(
+            f"no published peaks for device kind {kind!r}; add its row to "
+            f"profiler.DEVICE_PEAKS (known: {sorted(DEVICE_PEAKS)})")
+    return DEVICE_PEAKS[kind]
+
+
 def device_peak_flops():
-    """Best-effort peak FLOP/s of device 0 (bf16 matmul peak for known
-    TPU generations). None on CPU/unknown kinds — MFU is then null
-    rather than a made-up number."""
-    try:
-        import jax
-        kind = jax.devices()[0].device_kind
-    except Exception:       # noqa: BLE001
+    """bf16 matmul peak FLOP/s of device 0. None on the CPU backend (no
+    trustworthy peak — MFU is then null rather than a made-up number);
+    an accelerator kind without a DEVICE_PEAKS row raises."""
+    import jax
+    if jax.default_backend() == "cpu":
         return None
-    for k, v in sorted(_PEAK_TFLOPS.items(), key=lambda kv: -len(kv[0])):
-        if kind.lower().startswith(k.lower()):
-            return v * 1e12
-    return None
+    return device_peaks()["bf16_flops"]
 
 
 def mfu_stats():
@@ -1391,7 +1376,8 @@ def dumps(reset=False, format="table", sort_by="total", ascending=False):
         lines += ["", f"{'Kernel autotuner':<34}{'Value':>12}",
                   "-" * 46]
         for k in ("searches", "hits", "disk_hits", "disk_errors",
-                  "fallbacks", "winners"):
+                  "fallbacks", "withheld", "cand_errors",
+                  "cand_mismatches", "cand_lost", "winners"):
             lines.append(f"{'tune_' + k:<34}{tune_snap[k]:>12}")
     if fault_snap is not None:
         lines += ["", f"{'Fault tolerance':<34}{'Value':>12}",
@@ -1601,6 +1587,14 @@ def render_prometheus():
              "corrupt/stale/unwritable autotuner winner files"),
             ("fallbacks", "counter",
              "tuned_call dispatches that fell back to the XLA path"),
+            ("withheld", "counter",
+             "tuned_call dispatches inside a tune.xla_only() scope"),
+            ("cand_errors", "counter",
+             "autotuner candidates (or builders) that raised"),
+            ("cand_mismatches", "counter",
+             "autotuner candidates that diverged from the XLA reference"),
+            ("cand_lost", "counter",
+             "autotuner candidates that ran, matched, and were slower"),
             ("winners", "gauge", "tuned winners resident in memory"),
         )
         for stat, mtype, help_text in _TUNE_FAMILIES:

@@ -37,6 +37,7 @@ MXLINT_LOCK_ORDER: see tools/mxlint/lock_order.py ("tune.py").
 """
 from __future__ import annotations
 
+import contextlib
 import hashlib
 import json
 import logging
@@ -47,7 +48,7 @@ import time
 from collections import OrderedDict
 
 __all__ = ["register_kernel", "tuned_call", "winner_for", "winners",
-           "stats", "clear", "KernelSpec"]
+           "stats", "clear", "KernelSpec", "xla_only"]
 
 _MAGIC = b"MXTN1\n"   # on-disk: MAGIC + fp + "\n" + sha256(body) + "\n" + body
 _SUFFIX = ".mxtn"
@@ -62,6 +63,12 @@ _stats = {
     "disk_hits": 0,      # winners re-loaded from the persistent store
     "disk_errors": 0,    # corrupt/stale/unwritable winner files
     "fallbacks": 0,      # tuner off / unregistered kernel / winner vanished
+    "withheld": 0,       # dispatches inside an xla_only() scope
+    # why candidates did not win, counted apart: a kernel the compiler
+    # refuses must never look like one that merely lost the race
+    "cand_errors": 0,    # candidate (or its builder) raised
+    "cand_mismatches": 0,  # ran, but diverged from the XLA reference
+    "cand_lost": 0,      # ran and matched, slower than the winner
 }
 
 
@@ -88,6 +95,29 @@ class KernelSpec:
         self.builder = builder
         self.version = version
         self.bench = bench
+
+
+_scope = threading.local()
+_withheld_why = set()       # reasons already logged (guarded by _lock)
+
+
+@contextlib.contextmanager
+def xla_only(reason):
+    """Scope (per thread) in which every tuned_call takes its XLA
+    candidate without racing, counted under ``withheld``.
+
+    For traces whose program spans several devices: a Mosaic kernel
+    cannot be partitioned automatically (jax refuses at lowering, "wrap
+    the call in a shard_map"), and tuned_call does not know how its
+    operands are sharded, so it cannot wrap the winner itself. The race
+    would also run on ONE device at the GLOBAL shape and say nothing
+    about the per-shard program."""
+    prev = getattr(_scope, "xla_only", None)
+    _scope.xla_only = reason
+    try:
+        yield
+    finally:
+        _scope.xla_only = prev
 
 
 def register_kernel(name, builder, *, version=1, bench=None):
@@ -190,6 +220,8 @@ def _disk_load(fp, spec):
             raise ValueError("stale search-space version")
         if not isinstance(rec.get("winner"), str):
             raise ValueError("no winner recorded")
+        if not isinstance(rec.get("rejected"), dict):
+            raise ValueError("record predates rejection reasons")
         return rec
     except Exception as exc:    # noqa: BLE001 — corruption degrades
         with _lock:
@@ -242,24 +274,21 @@ def _is_traced(x):
 
 def _concretize(args):
     """Concrete stand-ins for a call signature: tracers are replaced by
-    deterministic random arrays of the same shape/dtype (winners are
-    keyed on shape/dtype, so synthetic data is exactly representative);
-    concrete leaves pass through."""
+    deterministic random arrays of the same shape/dtype, generated on
+    the device (winners are keyed on shape/dtype, so synthetic data is
+    exactly representative); concrete leaves pass through."""
+    import jax
     import jax.numpy as jnp
-    import numpy as np
-    rng = np.random.RandomState(0)
+    key = jax.random.PRNGKey(0)
     out = []
-    for a in args:
+    for i, a in enumerate(args):
         if a is None or not _is_traced(a):
             out.append(a)
-            continue
-        shape, dtype = tuple(a.shape), a.dtype
-        if jnp.issubdtype(dtype, jnp.floating):
-            out.append(jnp.asarray(rng.standard_normal(shape), dtype))
-        elif jnp.issubdtype(dtype, jnp.integer):
-            out.append(jnp.zeros(shape, dtype))
+        elif jnp.issubdtype(a.dtype, jnp.floating):
+            out.append(jax.random.normal(jax.random.fold_in(key, i),
+                                         tuple(a.shape), a.dtype))
         else:
-            out.append(jnp.zeros(shape, dtype))
+            out.append(jnp.zeros(tuple(a.shape), a.dtype))
     return tuple(out)
 
 
@@ -299,15 +328,21 @@ def _default_bench(fn, *args, **kwargs):
     return fn(*args, **kwargs)
 
 
+def _reason(exc):
+    """`Type: first line` of an exception — what a winner record keeps of
+    a candidate the compiler or the runtime refused."""
+    lines = str(exc).strip().splitlines()
+    return f"{type(exc).__name__}: {lines[0] if lines else ''}"[:300]
+
+
 def _search(spec, fallback, args, kwargs, fp, call_key):
     """Time every candidate against the XLA fallback on concrete inputs
-    and publish the winner (memory + disk). Candidates that raise or
-    diverge numerically are disqualified."""
+    and publish the winner (memory + disk). A candidate that raises or
+    diverges numerically is disqualified, and the record says which and
+    why: ``rejected`` maps its name to ``error: <Type: first line>`` or
+    ``mismatch: ...``."""
+    import jax
     from .compile_cache import _backend, _device_kind, _jax_version
-    try:
-        cands = spec.builder(args, kwargs) or {}
-    except Exception:   # noqa: BLE001 — a broken builder means XLA wins
-        cands = {}
     rec = {
         "kernel": spec.name,
         "key": call_key,
@@ -317,31 +352,54 @@ def _search(spec, fallback, args, kwargs, fp, call_key):
         "jax_version": _jax_version(),
         "winner": "xla",
         "timings_us": {},
-        "rejected": [],
+        "rejected": {},
     }
+    errors = mismatches = 0
+    try:
+        cands = spec.builder(args, kwargs) or {}
+    except Exception as exc:    # noqa: BLE001 — a broken builder: XLA wins
+        logging.warning("tune: builder for %s raised (%s); XLA wins",
+                        spec.name, _reason(exc))
+        rec["rejected"]["<builder>"] = "error: " + _reason(exc)
+        errors += 1
+        cands = {}
     if cands:
         bench = spec.bench or _default_bench
         samples = _samples()
-        cargs = _concretize(args)
-        t_ref, ref = _time_one(bench, fallback, cargs, kwargs, samples)
-        rec["timings_us"]["xla"] = round(t_ref, 3)
-        best_t = t_ref
-        for name, fn in cands.items():
-            try:
-                t, out = _time_one(bench, fn, cargs, kwargs, samples)
+        # tuned_call sits inside traced op bodies: without stepping out to
+        # the eval trace the "concrete" timing runs would be staged into
+        # the enclosing trace and nothing would execute. (Not
+        # ensure_compile_time_eval: that constant-folds inside the
+        # candidates' own traces too, which Pallas kernels do not survive.)
+        with jax.core.eval_context():
+            cargs = _concretize(args)
+            t_ref, ref = _time_one(bench, fallback, cargs, kwargs, samples)
+            rec["timings_us"]["xla"] = round(t_ref, 3)
+            best_t = t_ref
+            for name, fn in cands.items():
+                try:
+                    t, out = _time_one(bench, fn, cargs, kwargs, samples)
+                except Exception as exc:    # noqa: BLE001 — disqualify
+                    logging.warning("tune: candidate %s:%s raised (%s)",
+                                    spec.name, name, _reason(exc))
+                    rec["rejected"][name] = "error: " + _reason(exc)
+                    errors += 1
+                    continue
                 if not _tree_close(out, ref):
-                    raise ValueError("numerical mismatch vs xla reference")
-            except Exception as exc:    # noqa: BLE001 — disqualify
-                logging.info("tune: candidate %s:%s disqualified (%s)",
-                             spec.name, name, exc)
-                rec["rejected"].append(name)
-                continue
-            rec["timings_us"][name] = round(t, 3)
-            if t < best_t:
-                best_t = t
-                rec["winner"] = name
+                    rec["rejected"][name] = \
+                        "mismatch: diverges from the xla reference"
+                    mismatches += 1
+                    continue
+                rec["timings_us"][name] = round(t, 3)
+                if t < best_t:
+                    best_t = t
+                    rec["winner"] = name
+    lost = sum(n not in ("xla", rec["winner"]) for n in rec["timings_us"])
     with _lock:
         _stats["searches"] += 1
+        _stats["cand_errors"] += errors
+        _stats["cand_mismatches"] += mismatches
+        _stats["cand_lost"] += lost
         _winners[fp] = rec
     _disk_store(fp, rec)
     return rec
@@ -380,6 +438,16 @@ def tuned_call(kernel, fallback, *args, **kwargs):
         with _lock:
             _stats["fallbacks"] += 1
         return fallback(*args, **kwargs)
+    reason = getattr(_scope, "xla_only", None)
+    if reason is not None:
+        with _lock:
+            _stats["withheld"] += 1
+            first = reason not in _withheld_why
+            _withheld_why.add(reason)
+        if first:
+            logging.warning("tune: Pallas candidates withheld (%s); "
+                            "tuned_call sites take XLA", reason)
+        return fallback(*args, **kwargs)
     call_key = _call_key(args, kwargs)
     # shardlint graph capture: metadata only — args may be tracers here,
     # so nothing value-dependent is recorded
@@ -393,14 +461,10 @@ def tuned_call(kernel, fallback, *args, **kwargs):
     name = rec["winner"]
     if name == "xla":
         return fallback(*args, **kwargs)
-    try:
-        cands = spec.builder(args, kwargs) or {}
-        fn = cands.get(name)
-    except Exception:   # noqa: BLE001
-        fn = None
+    fn = (spec.builder(args, kwargs) or {}).get(name)
     if fn is None:
         # persisted winner no longer offered (env gate flipped, candidate
-        # set changed without a version bump): degrade to XLA
+        # set changed without a version bump): degrade to XLA, counted
         with _lock:
             _stats["fallbacks"] += 1
         return fallback(*args, **kwargs)

@@ -196,8 +196,9 @@ ENV_VARS = collections.OrderedDict([
      "serialized here and deserialized by later processes, so a fleet "
      "replica cold-starts without recompiling. Empty (default) disables "
      "the disk tier; the in-memory LRU is always on. Distinct from "
-     "MXTPU_COMPILE_CACHE (jax's own compilation cache, which still "
-     "pays tracing+lowering per process).")),
+     "jax's own compilation cache (JAX_COMPILATION_CACHE_DIR, else "
+     "<checkout>/.jax_cache), which still pays tracing+lowering per "
+     "process.")),
     ("MXNET_EXEC_CACHE_SIZE", EnvSpec(1024, "int",
      "Entry capacity of the process-wide in-memory executable LRU shared "
      "by all compile_cache.cached_jit call sites; replaces serve's "
@@ -252,8 +253,6 @@ ENV_VARS = collections.OrderedDict([
     ("MXTPU_FP32_MATMUL", EnvSpec("strict", "str",
      "fp32 matmul precision: 'strict' (MXNet semantics, fp32 "
      "accumulate), 'fast' (bf16_3x), or 'fastest' (plain bf16).")),
-    ("MXTPU_COMPILE_CACHE", EnvSpec("~/.cache/mxtpu_xla", "str",
-     "XLA persistent compilation-cache directory; '0' disables.")),
     ("MXTPU_TEST_PLATFORM", EnvSpec("cpu", "str",
      "Test-suite only: jax platform the suite pins itself to.")),
     ("MXTPU_TEST_SEED", EnvSpec(0, "int",

@@ -257,7 +257,8 @@ def test_concurrent_two_process_write_last_writer_wins(cache_dir):
     assert raw[6:70].decode() == name[:-len(".mxec")]
     body = raw[136:]
     assert hashlib.sha256(body).hexdigest() == raw[71:135].decode()
-    payload, in_tree, out_tree = pickle.loads(body)
+    payload, in_tree, out_tree, device_ids = pickle.loads(body)
+    assert device_ids == [0]
     assert payload
     # ...that a third, fresh process deserializes instead of recompiling
     third = subprocess.run(

@@ -57,12 +57,25 @@ def test_train_mnist_learns():
 
 
 def test_train_imagenet_compiled_path():
+    # `device` = the --kv-store tpu step on whatever backend is present
     out = _run("example/image-classification/train_imagenet.py",
                "--network", "resnet18_v1", "--batch-size", "16",
                "--num-batches", "3", "--image-shape", "3,32,32",
-               "--num-classes", "10", "--kv-store", "tpu",
+               "--num-classes", "10", "--kv-store", "device",
                "--dtype", "float32", "--disp-batches", "1")
     assert "epoch 0 done" in out
+
+
+def test_train_imagenet_kv_store_tpu_refuses_the_cpu():
+    r = subprocess.run(
+        [sys.executable,
+         os.path.join(REPO, "example/image-classification/train_imagenet.py"),
+         "--kv-store", "tpu"],
+        capture_output=True, text=True, timeout=300, cwd=REPO,
+        env={**os.environ, "JAX_PLATFORMS": "cpu"})
+    assert r.returncode != 0
+    assert "needs a TPU" in r.stderr and "'cpu'" in r.stderr
+    assert "epoch" not in r.stdout + r.stderr
 
 
 def test_train_imagenet_trainer_path():

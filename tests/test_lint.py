@@ -273,6 +273,10 @@ def test_getenv_helpers_semantics(monkeypatch):
     for name, spec in util.ENV_VARS.items():
         assert name.startswith(("MXNET_", "MXTPU_"))
         assert spec.kind in ("int", "bool", "str") and spec.doc
+    # the knob count only goes down (ROADMAP D3); jax's compilation cache
+    # is placed by JAX_COMPILATION_CACHE_DIR, not a knob of ours
+    assert len(util.ENV_VARS) <= 84
+    assert "MXTPU_COMPILE_CACHE" not in util.ENV_VARS
 
 
 def test_env_registry_matches_ast_extraction():

@@ -276,34 +276,6 @@ def test_compile_warn_threshold(caplog):
             os.environ["MXNET_COMPILE_WARN_THRESHOLD"] = old
 
 
-def test_track_jit_first_call_latch_atomic_across_threads():
-    """The first-call fallback path (no jit cache-size probe) is a
-    read-modify-write on shared state: without the latch lock, N threads
-    racing the first call would all read called=False and every one of
-    them would book a phantom miss."""
-    fn = profiler.track_jit("test:threaded_latch", lambda a: a + 1)
-    n_threads = 8
-    start = threading.Barrier(n_threads)
-    errs = []
-
-    def call():
-        try:
-            start.wait()
-            fn(np.ones((2,), np.float32))
-        except Exception as e:              # noqa: BLE001
-            errs.append(e)
-
-    threads = [threading.Thread(target=call) for _ in range(n_threads)]
-    for t in threads:
-        t.start()
-    for t in threads:
-        t.join()
-    assert not errs
-    row = profiler.compile_stats()["test:threaded_latch"]
-    assert row["misses"] == 1
-    assert row["hits"] == n_threads - 1
-
-
 def test_track_jit_detects_shape_retrace():
     import jax
 

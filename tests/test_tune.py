@@ -115,8 +115,76 @@ def test_candidate_must_win_the_race_never_unconditional(tune_dir):
     np.testing.assert_allclose(np.asarray(out), 2.0)
     assert tune.winner_for("t_wrong", x) == "xla"
     rec = next(iter(tune.winners().values()))
-    assert rec["rejected"] == ["fast_but_wrong"]
+    assert list(rec["rejected"]) == ["fast_but_wrong"]
+    assert rec["rejected"]["fast_but_wrong"].startswith("mismatch:")
     assert ran["cand"] > 0      # it WAS timed/validated, then rejected
+    s = tune.stats()
+    assert (s["cand_mismatches"], s["cand_errors"], s["cand_lost"]) == \
+        (1, 0, 0)
+
+
+def test_raising_candidate_is_recorded_with_its_reason(tune_dir):
+    """A candidate the compiler/runtime refuses is not a candidate that
+    lost: the record keeps the exception's type and first line, and the
+    three ways of not winning are counted apart."""
+    def refused(x):
+        raise NotImplementedError("Mosaic says no\nsecond line")
+
+    def slow(x):
+        time.sleep(0.005)
+        return x + x
+
+    tune.register_kernel(
+        "t_raise", lambda a, k: {"refused": refused, "slow": slow})
+    x = jnp.ones((8,))
+    out = tune.tuned_call("t_raise", lambda x: x + x, x)
+    np.testing.assert_allclose(np.asarray(out), 2.0)
+    (rec,) = tune.winners().values()
+    assert rec["winner"] == "xla"
+    assert rec["rejected"] == {
+        "refused": "error: NotImplementedError: Mosaic says no"}
+    assert "slow" in rec["timings_us"]
+    s = tune.stats()
+    assert (s["cand_errors"], s["cand_mismatches"], s["cand_lost"]) == \
+        (1, 0, 1)
+
+
+def test_xla_only_scope_withholds_the_race_and_counts_it(tune_dir):
+    """Programs traced over several devices (and shape-only passes) take
+    the XLA candidate without a search — visibly, under `withheld`."""
+    ran = {"n": 0}
+
+    def cand(x):
+        ran["n"] += 1
+        return x + x
+
+    tune.register_kernel("t_held", lambda a, k: {"pallas": cand})
+    x = jnp.ones((8,))
+    with tune.xla_only("test: spans devices"):
+        out = tune.tuned_call("t_held", lambda x: x + x, x)
+    np.testing.assert_allclose(np.asarray(out), 2.0)
+    s = tune.stats()
+    assert (s["withheld"], s["searches"], ran["n"]) == (1, 0, 0)
+    tune.tuned_call("t_held", lambda x: x + x, x)     # scope closed: races
+    assert tune.stats()["searches"] == 1 and ran["n"] > 0
+
+
+def test_search_inside_a_jit_trace_really_runs(tune_dir):
+    """tuned_call sites live inside traced op bodies. The search must
+    execute there (compile-time eval), not be staged into the enclosing
+    trace — where every candidate used to die on a tracer conversion and
+    XLA won by default."""
+    tune.register_kernel("t_traced", lambda a, k: {"twice": lambda x: x * 2})
+
+    @jax.jit
+    def f(x):
+        return tune.tuned_call("t_traced", lambda x: x + x, x)
+
+    np.testing.assert_allclose(np.asarray(f(jnp.ones((8,)))), 2.0)
+    (rec,) = tune.winners().values()
+    assert rec["rejected"] == {}
+    assert set(rec["timings_us"]) == {"xla", "twice"}
+    assert tune.stats()["cand_errors"] == 0
 
 
 def test_winner_dispatches_and_vanished_winner_degrades(tune_dir):

@@ -99,7 +99,8 @@ def main():
         s = tune.stats()
         print("counters     :",
               {k: s[k] for k in ("searches", "hits", "disk_hits",
-                                 "disk_errors", "fallbacks")})
+                                 "disk_errors", "fallbacks", "cand_errors",
+                                 "cand_mismatches", "cand_lost")})
         recs = tune.winners()
         if not recs:
             print("winners      : (none recorded)")
@@ -117,6 +118,8 @@ def main():
                     best = "" if best is None else f" {best}us"
                     print(f"  {rec['kernel']:<16} -> {rec['winner']}{best}"
                           f"  [{rec['key']}]")
+                    for name, why in rec["rejected"].items():
+                        print(f"    rejected {name}: {why}")
     except Exception as e:
         print("tune probe FAILED:", e)
 

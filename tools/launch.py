@@ -11,14 +11,20 @@ peer in the jax distributed runtime. The launcher starts N worker processes
     JAX_NUM_PROCESSES        n
     JAX_PROCESS_ID           0..n-1
 
-plus the framework's own MXTPU_* mirrors, then waits. Inside the program,
+plus the framework's own MXTPU_* mirrors, then waits. ONE PROCESS PER HOST:
+a host's chips are driven by one process over a mesh (a chip belongs to one
+process at a time), and `--launcher local` starts its workers with identical
+device visibility — on a multi-chip TPU host every worker would claim every
+chip — so on accelerator hosts use it with -n 1 per host (or on the CPU).
+Inside the program,
 `incubator_mxnet_tpu.kvstore.create("tpu")` picks rank/size from the jax
 runtime, so reference-style `launch.py -n 4 python train.py --kv-store tpu`
 keeps its shape.
 
 Usage:
-    python tools/launch.py -n 4 python train_mnist.py --kv-store tpu
     python tools/launch.py -n 8 -H hostfile --launcher ssh python train.py
+    JAX_PLATFORMS=cpu python tools/launch.py -n 4 python train_mnist.py \
+        --kv-store dist_sync
 """
 from __future__ import annotations
 

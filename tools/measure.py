@@ -98,7 +98,9 @@ def main(argv=None):
     print(json.dumps({"metric": f"kvstore_{args.kv_store}_bandwidth",
                       "network": args.network, "value": round(gbps, 3),
                       "unit": "GB/s", "ms_per_round": round(per_round * 1e3, 2),
-                      "devices": n_dev}))
+                      "devices": n_dev,
+                      "platform": jax.devices()[0].platform,
+                      "device_kind": jax.devices()[0].device_kind}))
     return 0
 
 
