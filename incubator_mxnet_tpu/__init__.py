@@ -51,6 +51,12 @@ def _configure_jax():
                           os.path.join(checkout, ".jax_cache"))
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.3)
     jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
+    # Names are metadata (jax.named_scope, a kernel's name in op_name), and
+    # jax strips metadata from the cache key unless told otherwise: an
+    # executable cached before a scope was added would be served without
+    # it, and a device trace would name nothing. With this the key also
+    # holds source locations, so an edit that moves a traced line misses.
+    jax.config.update("jax_compilation_cache_include_metadata_in_key", True)
 
 
 _configure_jax()

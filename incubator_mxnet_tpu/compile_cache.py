@@ -172,7 +172,9 @@ def _fingerprint(key, opts_repr, traced, sig):
     closed = traced.jaxpr
     # the jaxpr text elides closure-captured constant *values*; hash them
     # separately or a changed baked-in table would collide (TS04's hazard)
-    h.update(str(closed).encode())
+    # with the name stacks: named scopes reach the executable's metadata
+    # (what a device trace is read by) and the plain text prints none
+    h.update(closed.pretty_print(name_stack=True).encode())
     for c in getattr(closed, "consts", ()):
         try:
             a = np.asarray(c)

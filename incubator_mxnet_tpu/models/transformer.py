@@ -124,6 +124,21 @@ class TransformerLM:
         *local* weight shapes, so the same code serves the unsharded path.
         `mesh` is the multi-device mesh of a pure-jit (GSPMD) caller: the
         flash kernel then runs per shard."""
+        with jax.named_scope("attn"):
+            x = x + self._attn(params, prefix, x, sp_axis, tp_axis, mesh)
+        with jax.named_scope("mlp"):
+            h = self._ln(x, params[prefix + "ln2_g"],
+                         params[prefix + "ln2_b"])
+            y = jax.nn.gelu(h @ params[prefix + "w_in"]) \
+                @ params[prefix + "w_out"]
+            if tp_axis is not None:
+                y = jax.lax.psum(y, tp_axis)
+            y = checkpoint_name(y, "mlp_out")
+            return x + y
+
+    def _attn(self, params, prefix, x, sp_axis, tp_axis, mesh):
+        """The attention half of a block: ln1, projections, attention, the
+        output projection (psum over tp where sharded). Returns attn_out."""
         cfg = self.cfg
         B, T, D = x.shape
         hd = D // cfg.n_heads
@@ -160,14 +175,7 @@ class TransformerLM:
         attn_out = attn.reshape(B, T, d_local) @ params[prefix + "wo"]
         if tp_axis is not None:
             attn_out = jax.lax.psum(attn_out, tp_axis)
-        attn_out = checkpoint_name(attn_out, "attn_out")
-        x = x + attn_out
-        h = self._ln(x, params[prefix + "ln2_g"], params[prefix + "ln2_b"])
-        y = jax.nn.gelu(h @ params[prefix + "w_in"]) @ params[prefix + "w_out"]
-        if tp_axis is not None:
-            y = jax.lax.psum(y, tp_axis)
-        y = checkpoint_name(y, "mlp_out")
-        return x + y
+        return checkpoint_name(attn_out, "attn_out")
 
     def apply(self, params, tokens, sp_axis=None, positions=None, tp_axis=None,
               mesh=None):
@@ -176,10 +184,11 @@ class TransformerLM:
         pass tp_axis when attention/MLP weights are Megatron-sharded; pass
         the mesh when tracing a pure-jit program over several devices."""
         cfg = self.cfg
-        x = params["embed"][tokens]
-        if positions is None:
-            positions = jnp.arange(tokens.shape[1])
-        x = x + params["pos_embed"][positions]
+        with jax.named_scope("embed"):
+            x = params["embed"][tokens]
+            if positions is None:
+                positions = jnp.arange(tokens.shape[1])
+            x = x + params["pos_embed"][positions]
         if cfg.remat:
             block = jax.checkpoint(
                 lambda p, pref, y: self._block(p, pref, y, sp_axis, tp_axis,
@@ -189,16 +198,25 @@ class TransformerLM:
             block = lambda p, pref, y: self._block(p, pref, y, sp_axis,
                                                    tp_axis, mesh)
         for i in range(cfg.n_layers):
-            x = block(params, f"layer{i}_", x)
-        x = self._ln(x, params["lnf_g"], params["lnf_b"])
-        return (x @ params["embed"].T).astype(jnp.float32)
+            with jax.named_scope(f"layer{i}"):
+                x = block(params, f"layer{i}_", x)
+        with jax.named_scope("final_ln"):
+            x = self._ln(x, params["lnf_g"], params["lnf_b"])
+        with jax.named_scope("logits"):
+            return (x @ params["embed"].T).astype(jnp.float32)
 
     def loss(self, params, tokens, targets, sp_axis=None, positions=None,
              tp_axis=None, mesh=None):
-        logits = self.apply(params, tokens, sp_axis, positions, tp_axis, mesh)
-        logp = jax.nn.log_softmax(logits, axis=-1)
-        nll = -jnp.take_along_axis(logp, targets[..., None], axis=-1)[..., 0]
-        return jnp.mean(nll)
+        # `forward`, `loss` and (in the train step) `optimizer` are the top
+        # words a device trace is read by (PERF.md section 3)
+        with jax.named_scope("forward"):
+            logits = self.apply(params, tokens, sp_axis, positions, tp_axis,
+                                mesh)
+        with jax.named_scope("loss"):
+            logp = jax.nn.log_softmax(logits, axis=-1)
+            nll = -jnp.take_along_axis(logp, targets[..., None],
+                                       axis=-1)[..., 0]
+            return jnp.mean(nll)
 
     # -- sharded training ---------------------------------------------------
     def param_sharding(self, mesh, tp_axis="tp"):
@@ -287,13 +305,14 @@ class TransformerLM:
         def step(params, opt_state, tokens, targets, step_i):
             loss, grads = jax.value_and_grad(loss_fn)(params, tokens, targets)
             new_params, new_opt = {}, {}
-            t = step_i + 1
-            for k, g in grads.items():
-                # fp32 master weights around the shared adam rule
-                w32, new_opt[k] = adam_rule(params[k].astype(jnp.float32),
-                                            g.astype(jnp.float32),
-                                            opt_state[k], t)
-                new_params[k] = w32.astype(params[k].dtype)
+            with jax.named_scope("optimizer"):
+                t = step_i + 1
+                for k, g in grads.items():
+                    # fp32 master weights around the shared adam rule
+                    w32, new_opt[k] = adam_rule(
+                        params[k].astype(jnp.float32), g.astype(jnp.float32),
+                        opt_state[k], t)
+                    new_params[k] = w32.astype(params[k].dtype)
             return new_params, new_opt, loss
 
         if n_steps:

@@ -231,6 +231,11 @@ def apply_op(op: OpDef, *args, out=None, **params):
             fn = lambda rng, *xs, _p=params: op.fn(*xs, rng=rng, **_p)
         else:
             fn = lambda *xs, _p=params: op.fn(*xs, **_p)
+        if traced:
+            # the op's registry name on every instruction it lowers to (and,
+            # through jax's own jvp/transpose wrappers, on its backward):
+            # acts while the outer program is traced, costs nothing per step
+            fn = jax.named_scope(op.name)(fn)
     else:
         fn = op.jitted(**params)
 

@@ -194,6 +194,7 @@ def _patch_nhwc(x, go, w_hwio, bn):
         ],
         compiler_params=params,
         interpret=_interpret(),
+        name="conv3x3_bwd_patch",
     )(x, go, wd)
     # dw rows are [(kh,kw,i)]; back to (3,3,I,O)
     return dx, dw.reshape(3, 3, ci, co)
@@ -237,6 +238,7 @@ def _bwd_nhwc(x, go, w_hwio, bn):
         ],
         compiler_params=params,
         interpret=_interpret(),
+        name="conv3x3_bwd_taps",
     )(x, go, w_hwio)
     return dx, dw
 

@@ -191,6 +191,7 @@ def _fa_forward(q, k, v, causal, sm_scale, block_q, block_k, interpret):
         ],
         compiler_params=params,
         interpret=interpret,
+        name="flash_fwd",
     )(q, kt, v)
 
 
@@ -351,6 +352,7 @@ def _fa_backward(q, k, v, do, lse, out, dlse, causal, sm_scale, block_q,
                         pltpu.VMEM((block_q, 128), jnp.float32)],
         compiler_params=params,
         interpret=interpret,
+        name="flash_bwd_dq",
     )(q, k, v, do, lse, out, dlse)
 
     dk, dv = pl.pallas_call(
@@ -376,6 +378,7 @@ def _fa_backward(q, k, v, do, lse, out, dlse, causal, sm_scale, block_q,
                         pltpu.VMEM((block_k, d), jnp.float32)],
         compiler_params=params,
         interpret=interpret,
+        name="flash_bwd_dkv",
     )(k, v, q, do, lse, delta)
     return dq, dk, dv
 

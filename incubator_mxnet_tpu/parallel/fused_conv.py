@@ -126,6 +126,7 @@ def _epi_rows(z2, s2, b2, bm, relu):
         out_specs=pl.BlockSpec((bm, c), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((m, c), z2.dtype),
         interpret=_interpret(),
+        name="bn_act" if relu else "bn_apply",
     )(z2, s2, b2)
 
 
@@ -143,6 +144,7 @@ def _epi_res_rows(z2, s2, b2, r2, bm, relu):
         out_specs=pl.BlockSpec((bm, c), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((m, c), z2.dtype),
         interpret=_interpret(),
+        name="bn_add_act",
     )(z2, s2, b2, r2)
 
 
@@ -333,6 +335,7 @@ def _conv_bn_relu_nhwc(x, w_hwio, s2, b2, *, bn, k, plo_h, plo_w, variant):
         scratch_shapes=scratch,
         compiler_params=params,
         interpret=_interpret(),
+        name=f"conv_bn_relu_{variant}",
     )(x, wmat, s2, b2)
 
 

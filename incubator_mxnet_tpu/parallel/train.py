@@ -247,10 +247,12 @@ class TrainStep:
                 full = dict(params)
                 full.update(diff_params)
                 with (_tune.xla_only(spans) if spans
-                      else contextlib.nullcontext()):
+                      else contextlib.nullcontext(),
+                      jax.named_scope("forward")):
                     outs, writes = apply_fn(full, rng, *inputs)
                 out = outs[0]
-                return loss_fn(out, label), (writes, out)
+                with jax.named_scope("loss"):
+                    return loss_fn(out, label), (writes, out)
 
             diff_params = {k: v for k, v in params.items() if k not in non_diff}
             (loss, (writes, out)), grads = jax.value_and_grad(
@@ -258,14 +260,15 @@ class TrainStep:
 
             new_params = dict(params)
             new_opt = dict(opt_state)
-            t = step_i + 1
-            for k, g in grads.items():
-                w = params[k]
-                new_params[k], new_opt[k] = update(w, g.astype(w.dtype),
-                                                   opt_state[k], t)
-            # fold state writes (BN running stats) into the param tree
-            for k, v in writes.items():
-                new_params[k] = v.astype(params[k].dtype)
+            with jax.named_scope("optimizer"):
+                t = step_i + 1
+                for k, g in grads.items():
+                    w = params[k]
+                    new_params[k], new_opt[k] = update(
+                        w, g.astype(w.dtype), opt_state[k], t)
+                # fold state writes (BN running stats) into the param tree
+                for k, v in writes.items():
+                    new_params[k] = v.astype(params[k].dtype)
             return new_params, new_opt, loss
 
         self._step_fn = step_fn

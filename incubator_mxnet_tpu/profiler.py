@@ -16,6 +16,25 @@ directory is configured. Dispatch is async under XLA — `profile_sync=True`
 real compute times, mirroring the reference's GPU stream-sync profiling
 mode (profiler.h kSimple vs kAccurate).
 
+What the device trace names. With `set_config(tensorboard_dir=...)` and
+`start()`, TensorBoard's trace viewer and op profile show each compiled
+operation under the names the program was traced with (its `op_name`):
+inside a jitted program every registered op carries its registry name
+(`Convolution`, `BatchNorm`, `FusedBNAddReLU`, `Pooling`, `FullyConnected`,
+...), `parallel.TrainStep` puts the whole step under `forward`, `loss` and
+`optimizer`, and `TransformerLM`'s step adds `embed`, `layer<i>/attn`,
+`layer<i>/mlp`, `final_ln` and `logits` under `forward`. jax writes the
+pass itself: `jvp(forward)/..` is the forward pass, `transpose(jvp(forward))
+/..` the backward pass, and `../checkpoint/rematted_computation/..` the
+forward pass recomputed inside it. The flash-attention kernels appear as
+`flash_fwd`, `flash_bwd_dq` and `flash_bwd_dkv`, the fused convolution
+kernels as `conv_bn_relu_<variant>`, `bn_act`, `bn_add_act`, `bn_apply`,
+`conv3x3_bwd_patch` and `conv3x3_bwd_taps`. A fusion is listed under the
+scope of the instruction XLA made its root, so a convolution fused with a
+BatchNorm epilogue is one `Convolution` entry. Eager dispatch enters no
+scope. `perfbench/op_scopes.py` reduces the same names to shares of device
+time.
+
 Three telemetry layers beyond the reference:
 
 - **Memory profiler** (`profile_memory=True`): NDArray construction and the
