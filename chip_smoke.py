@@ -374,6 +374,13 @@ def leg_lm(cfg, rehearse, result_path):
           flush=True)
     assert took["pallas"] > 0 and took["reference"] == 0, \
         "flash attention dropped to attention_reference"
+    run, square = took["causal_subblocks_run"], took["causal_subblocks_all"]
+    print(f"[smoke:lm] causal score sub-blocks computed: {run} of {square}",
+          flush=True)
+    # from 256 positions on a grid block on the diagonal has sub-blocks to
+    # skip; below that the whole square is one masked pass
+    assert 0 < run <= square and (run < square or cfg["seq"] < 256), \
+        "the causal walk inside the kernels did not engage"
     if n > 1:
         check_spread("lm", {"tokens": tokens,
                             "layer0_wq": params["layer0_wq"],

@@ -15,6 +15,12 @@ when the shape doesn't tile (tiny heads / ragged lengths). Off-TPU the
 kernel runs in Pallas interpret mode, so the same code path is tested on
 the CPU mesh.
 
+Causal calls skip the masked half twice: the grid skips the blocks above
+the diagonal, and a block ON the diagonal is walked in strips of 256 rows
+that stop at the diagonal, with the mask on each strip's one 256 x 256
+triangle (_causal_plan). At T <= 1,024 a head is one grid block, so the
+walk is all the skipping there is.
+
 Backward: REAL flash backward kernels (custom_vjp) — the forward also
 emits the per-row log-sum-exp; `_fa_bwd_dq_kernel` streams k/v blocks
 accumulating dq, `_fa_bwd_dkv_kernel` streams q blocks accumulating
@@ -27,6 +33,7 @@ untileable shapes and the no-pallas path.
 """
 from __future__ import annotations
 
+import collections
 import functools
 import logging
 import threading
@@ -61,26 +68,187 @@ def pallas_available():
         return False
 
 
-def _causal_mask(s, q_off, k_off, transposed=False):
-    """Mask `s` to the causal (q_row >= k_row) region. s is
-    (block_q, block_k), or (block_k, block_q) when transposed."""
+# The kernels' bodies are written in lax, not jnp: a jnp function is a jit of
+# its own, and tracing one costs five times what binding the primitive does.
+# A train step traces and lowers three kernels a layer in every process, and
+# a ref load is the dearest thing to lower, so each kernel loads its blocks
+# once and cuts strips out of the values (PERF.md section 6, PR 26).
+
+def _keep(shape, q_off, k_off, transposed=False):
+    """Causal (q_row >= k_row) mask of a score block whose first row and
+    column sit at q_off / k_off. shape is (q rows, k rows), or (k rows,
+    q rows) when transposed."""
     from jax import lax
-    shape = s.shape
     a = lax.broadcasted_iota(jnp.int32, shape, 0)
     b = lax.broadcasted_iota(jnp.int32, shape, 1)
     if transposed:                       # rows are k, cols are q
-        keep = (q_off + b) >= (k_off + a)
-    else:                                # rows are q, cols are k
-        keep = (q_off + a) >= (k_off + b)
-    return jnp.where(keep, s, _NEG_INF)
+        return (q_off + b) >= (k_off + a)
+    return (q_off + a) >= (k_off + b)    # rows are q, cols are k
 
 
-def _fa_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_sc, l_sc, acc_sc, *,
-               block_q, block_k, causal, sm_scale):
+def _cut(x, rows=None, cols=None):
+    """x[rows[0]:rows[1], cols[0]:cols[1]] of a 2-d value, None for all.
+    Row bounds are multiples of 8 and column bounds of 128 wherever a
+    kernel cuts, so a cut moves no data."""
+    from jax import lax
+    for axis, cut in ((0, rows), (1, cols)):
+        if cut is not None and cut != (0, x.shape[axis]):
+            x = lax.slice_in_dim(x, cut[0], cut[1], axis=axis)
+    return x
+
+
+def _stack(parts, axis=0):
+    """The strips' results side by side again, in the order given."""
+    from jax import lax
+    return parts[0] if len(parts) == 1 else lax.concatenate(parts, axis)
+
+
+def _masked(s, keep, lead=False):
+    """s with -inf where `keep` is False. A `keep` narrower than s covers
+    its trailing columns only (its leading ones with `lead`): the one
+    sub-block of a strip that lies on the diagonal."""
+    from jax import lax
+    n, w = keep.shape[1], s.shape[1]
+    on = _cut(s, cols=(0, n) if lead else (w - n, w))
+    on = lax.select(keep, on, lax.full_like(on, _NEG_INF))
+    if n == w:
+        return on
+    rest = _cut(s, cols=(n, w) if lead else (0, w - n))
+    return _stack([on, rest] if lead else [rest, on], axis=1)
+
+
+def _over(col, like):
+    """A column (r, 1) spread over the lanes of `like` (r, w)."""
+    from jax import lax
+    return lax.broadcast_in_dim(col, like.shape, (0, 1))
+
+
+def _under(col, like):
+    """A column (w, 1) turned into a row of lanes and spread down the
+    sublanes of `like` (r, w)."""
+    from jax import lax
+    row = lax.expand_dims(lax.squeeze(col, (1,)), (0,))
+    return lax.broadcast_in_dim(row, like.shape, (0, 1))
+
+
+def _dot(a, b, contract, prec):
+    """f32 a . b contracting a's dim contract[0] with b's contract[1].
+    bf16 operands keep full MXU rate with f32 accumulation; precision
+    comes from _prec (DEFAULT for bf16 — Mosaic requires it — HIGHEST for
+    f32 inputs)."""
+    from jax import lax
+    return lax.dot_general(a, b, (((contract[0],), (contract[1],)), ((), ())),
+                           precision=prec,
+                           preferred_element_type=jnp.float32)
+
+
+# Edge of the score sub-blocks a grid block ON the diagonal is walked in, in
+# all three kernels; a multiple of 128 (it cuts the LANE dim of the
+# pre-transposed key). On the chip 128 runs the three kernels 6%, 3% and 7%
+# faster than 256 at (BH, T, D) = (512, 1024, 64), and costs twice the strips
+# to trace and lower in every process: at 24 layers that is 7 s of set-up for
+# 1.2% of a GPT-2 medium step (PERF.md section 6, PR 26).
+_SUB = 256
+
+
+def _sub_block(block_q, block_k):
+    """Edge of the sub-blocks a grid block is made of, two a side at the
+    least: _SUB, 128 for the lengths that are not multiples of _SUB, or
+    None."""
+    for c in (_SUB, 128):
+        if block_q % c == 0 and block_k % c == 0 \
+                and min(block_q, block_k) >= 2 * c:
+            return c
+    return None
+
+
+# What a causal call's kernels do, from what they can see (the two lengths
+# and the block sizes): `sub`, the sub-block edge on the diagonal (None: one
+# masked pass, as before PR 26); whether any grid block lies wholly `below`
+# the diagonal (one unmasked pass) or `straddle`s it off the block's corner
+# (Tq != Tk with unequal blocks: one masked pass); and the score sub-blocks
+# computed, `run`, of `all` in the (Tq, Tk) square, in units of `sub` (of
+# grid blocks where there is none).
+_Plan = collections.namedtuple("_Plan", "sub below straddle run all")
+
+
+def _walk_rows(block_q, block_k, sub):
+    """[(rows, cols, on)]: the strips of an aligned diagonal grid block by
+    q sub-block: q rows `rows` see k rows `cols`, from 0 up to the
+    diagonal, the last sub-block of them through the triangle where `on`
+    (a q sub-block past the last k sub-block sees them all, unmasked)."""
+    n_k = block_k // sub
+    return [((i * sub, (i + 1) * sub), (0, min(i + 1, n_k) * sub), i < n_k)
+            for i in range(block_q // sub)]
+
+
+def _causal_plan(tq, tk, block_q, block_k):
+    """The _Plan of a causal call over (tq, tk) in (block_q, block_k) grid
+    blocks."""
+    sub = _sub_block(block_q, block_k)
+    per_block = (block_q // sub) * (block_k // sub) if sub else 1
+    on_diag = sum(cols[1] // sub for _, cols, _ in
+                  _walk_rows(block_q, block_k, sub)) if sub else 1
+    below = straddle = diag = 0
+    for q0 in range(0, tq, block_q):
+        for k0 in range(0, tk, block_k):
+            if k0 > q0 + block_q - 1:
+                continue                 # above: the grid test skips it
+            if k0 + block_k - 1 <= q0:
+                below += 1
+            elif k0 == q0:
+                diag += 1
+            else:
+                straddle += 1
+    return _Plan(sub, below > 0, straddle > 0,
+                 (below + straddle) * per_block + diag * on_diag,
+                 (tq // block_q) * (tk // block_k) * per_block)
+
+
+def _causal_branches(plan, q_off, k_off, block_q, block_k, full, walk):
+    """Run the one of `full(masked)` / `walk()` that the grid block at
+    (q_off, k_off) takes; a block wholly above the diagonal runs none."""
+    from jax.experimental import pallas as pl
+    below = k_off + block_k - 1 <= q_off
+    diag = q_off == k_off
+    if plan.below:
+        pl.when(below)(lambda: full(False))
+    pl.when(diag)(walk if plan.sub else (lambda: full(True)))
+    if plan.straddle:
+        reach = k_off <= q_off + block_q - 1
+        pl.when(reach & ~below & ~diag)(lambda: full(True))
+
+
+def _fwd_fold(carry, s, v, prec):
+    """One online-softmax step: scores s (r, w) folded into carry = (max,
+    sumexp, acc), columns (r, 1) and (r, d), with v (w, d). Without a
+    carry s holds its rows' every score: the plain statistics, nothing to
+    rescale."""
+    from jax import lax
+    m = lax.expand_dims(lax.reduce_max(s, (1,)), (1,))
+    if carry:
+        m_prev, l_prev, acc_prev = carry
+        m = lax.max(m_prev, m)
+    p = lax.exp(lax.sub(s, _over(m, s)))
+    l = lax.expand_dims(lax.reduce_sum(p, (1,)), (1,))
+    acc = _dot(lax.convert_element_type(p, v.dtype), v, (1, 0), prec)
+    if carry:
+        alpha = lax.exp(lax.sub(m_prev, m))
+        l = lax.add(lax.mul(l_prev, alpha), l)
+        acc = lax.add(lax.mul(acc_prev, _over(alpha, acc)), acc)
+    return m, l, acc
+
+
+def _fa_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *scratch, block_q,
+               block_k, plan, sm_scale):
     """One (batch*head, q_block, kv_block) grid step. The kv axis is the
     innermost ('arbitrary') grid dimension, so Pallas double-buffers the
     K/V block DMAs while this step computes; running (max, sumexp, acc)
-    stats live in VMEM scratch that persists across kv steps.
+    stats live in VMEM scratch that persists across kv steps. `plan` is
+    None for a non-causal call (every block one unmasked pass), else the
+    call's _causal_plan. Without scratch the grid step is a head's only
+    one and is walked in strips that each hold their rows' every score:
+    the statistics go straight to the output.
 
     Refs: q (1, block_q, d) | kt (1, d, block_k) | v (1, block_k, d)
     | o (1, block_q, d); scratch m,l (block_q, 128) acc (block_q, d)."""
@@ -89,51 +257,66 @@ def _fa_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_sc, l_sc, acc_sc, *,
 
     j = pl.program_id(2)
     n_k = pl.num_programs(2)
-    iq = pl.program_id(1)
-    q_offset = iq * block_q
+    q_off = pl.program_id(1) * block_q
+    k_off = j * block_k
+    prec = _prec(q_ref.dtype)
+    if scratch:
+        m_sc, l_sc, acc_sc = scratch
 
-    @pl.when(j == 0)
-    def _init():
-        m_sc[:] = jnp.full_like(m_sc, _NEG_INF)
-        l_sc[:] = jnp.zeros_like(l_sc)
-        acc_sc[:] = jnp.zeros_like(acc_sc)
+        @pl.when(j == 0)
+        def _init():
+            m_sc[:] = jnp.full_like(m_sc, _NEG_INF)
+            l_sc[:] = jnp.zeros_like(l_sc)
+            acc_sc[:] = jnp.zeros_like(acc_sc)
 
-    # causal: a kv block strictly above the diagonal contributes nothing
-    run = (j * block_k <= q_offset + block_q - 1) if causal else (j < n_k)
+    def emit(m, l, acc):
+        some = lax.select(l == 0.0, lax.full_like(l, 1.0), l)
+        # fully-masked rows: zeros out, and a +inf-ish log-sum-exp so that
+        # exp(s - lse) underflows to 0 in the backward kernels
+        o_ref[0] = (acc / _over(some, acc)).astype(o_ref.dtype)
+        lse_ref[0] = lax.select(l == 0.0, lax.full_like(l, 1e30),
+                                m + lax.log(some))
 
-    @pl.when(run)
-    def _step():
-        # bf16 operands keep full MXU rate with f32 accumulation via
-        # preferred_element_type; precision comes from _prec (DEFAULT for
-        # bf16 — Mosaic requires it — HIGHEST for f32 inputs)
-        prec = _prec(q_ref.dtype)
+    def fold(strips):
+        """Fold k columns `cols`, masked by `keep`, into the running stats
+        of q rows `rows`, for each (rows, cols, keep) of `strips`, which
+        together hold every q row once: every strip's scores first, so
+        that no strip's first matmul queues behind another's second on
+        its MXU."""
         q = q_ref[0] * jnp.asarray(sm_scale, q_ref.dtype)
-        kt = k_ref[0]                      # (d, block_k), pre-transposed
-        v = v_ref[0]                       # (block_k, d)
-        s = lax.dot_general(q, kt, (((1,), (0,)), ((), ())),
-                            precision=prec,
-                            preferred_element_type=jnp.float32)
-        if causal:
-            s = _causal_mask(s, q_offset, j * block_k)
-        m_prev = m_sc[:, 0]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1))
-        p = jnp.exp(s - m_new[:, None])
-        alpha = jnp.exp(m_prev - m_new)
-        l_sc[:, 0] = l_sc[:, 0] * alpha + jnp.sum(p, axis=-1)
-        acc_sc[:] = acc_sc[:] * alpha[:, None] + lax.dot(
-            p.astype(v.dtype), v, precision=prec,
-            preferred_element_type=jnp.float32)
-        m_sc[:, 0] = m_new
+        kt, v = k_ref[0], v_ref[0]
+        old = (m_sc[:, :1], l_sc[:, :1], acc_sc[:]) if scratch else None
+        scores = [_dot(_cut(q, rows), _cut(kt, cols=cols), (1, 0), prec)
+                  for rows, cols, _ in strips]
+        new = [_fwd_fold(old and [_cut(x, rows) for x in old],
+                         s if keep is None else _masked(s, keep),
+                         _cut(v, cols), prec)
+               for (rows, cols, keep), s in zip(strips, scores)]
+        m, l, acc = (_stack(list(x)) for x in zip(*new))
+        if scratch:
+            m_sc[:, :1], l_sc[:, :1], acc_sc[:] = m, l, acc
+        else:
+            emit(m, l, acc)
 
-    @pl.when(j == n_k - 1)
-    def _finish():
-        l = l_sc[:, 0]
-        l = jnp.where(l == 0.0, 1.0, l)   # fully-masked rows -> zeros
-        o_ref[0] = (acc_sc[:] / l[:, None]).astype(o_ref.dtype)
-        # row log-sum-exp for the backward kernels; fully-masked rows get
-        # +inf-ish so exp(s - lse) underflows to 0 there
-        lse_ref[0] = jnp.where(l_sc[:, 0] == 0.0, 1e30,
-                               m_sc[:, 0] + jnp.log(l))[:, None]
+    def full(masked):
+        fold([((0, block_q), (0, block_k),
+               _keep((block_q, block_k), q_off, k_off) if masked else None)])
+
+    def walk():
+        c = plan.sub
+        tri = _keep((c, c), 0, 0)
+        fold([(rows, cols, tri if on else None)
+              for rows, cols, on in _walk_rows(block_q, block_k, c)])
+
+    if plan is None:
+        full(False)
+    else:
+        _causal_branches(plan, q_off, k_off, block_q, block_k, full, walk)
+
+    if scratch:
+        @pl.when(j == n_k - 1)
+        def _finish():
+            emit(m_sc[:, :1], l_sc[:, :1], acc_sc[:])
 
 
 def _compiler_params():
@@ -165,8 +348,16 @@ def _fa_forward(q, k, v, causal, sm_scale, block_q, block_k, interpret):
     tk = k.shape[1]
     kt = k.transpose(0, 2, 1)   # (BH, D, Tk) for the kernel's matmul
     grid = (bh, tq // block_q, tk // block_k)
+    plan = _causal_plan(tq, tk, block_q, block_k) if causal else None
+    if causal:
+        with _dispatch_lock:
+            _dispatch["causal_subblocks_run"] += plan.run
+            _dispatch["causal_subblocks_all"] += plan.all
     kern = functools.partial(_fa_kernel, block_q=block_q, block_k=block_k,
-                             causal=causal, sm_scale=sm_scale)
+                             plan=plan, sm_scale=sm_scale)
+    # a head that is one grid block walked in strips carries no running
+    # statistics from one kv step to the next: no scratch
+    alone = causal and plan.sub and grid[1:] == (1, 1)
     params = _compiler_params()
     return pl.pallas_call(
         kern,
@@ -184,7 +375,7 @@ def _fa_forward(q, k, v, causal, sm_scale, block_q, block_k, interpret):
         ],
         out_shape=[jax.ShapeDtypeStruct((bh, tq, d), q.dtype),
                    jax.ShapeDtypeStruct((bh, tq, 1), jnp.float32)],
-        scratch_shapes=[
+        scratch_shapes=[] if alone else [
             pltpu.VMEM((block_q, 128), jnp.float32),   # running max
             pltpu.VMEM((block_q, 128), jnp.float32),   # running sumexp
             pltpu.VMEM((block_q, d), jnp.float32),     # output accumulator
@@ -197,7 +388,7 @@ def _fa_forward(q, k, v, causal, sm_scale, block_q, block_k, interpret):
 
 def _fa_bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, out_ref,
                       dlse_ref, dq_ref, delta_ref, acc_sc, delta_sc, *,
-                      block_q, block_k, causal, sm_scale):
+                      block_q, block_k, plan, sm_scale):
     """dq for one q block, streaming k/v blocks (innermost grid dim):
       delta = rowsum(dO * O) - dlse   (computed HERE at j==0 — fused, so
                                  no separate XLA pass re-reads dO and O;
@@ -209,13 +400,16 @@ def _fa_bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, out_ref,
       p  = exp(s*scale - lse);  dp = dO V^T
       ds = p * (dp - delta);    dq = scale * sum_k ds K
     Matmuls keep input-dtype operands with f32 accumulation. delta is
-    also emitted as an output for the dk/dv kernel to consume."""
+    also emitted as an output for the dk/dv kernel to consume. `plan` as
+    in _fa_kernel."""
     from jax import lax
     from jax.experimental import pallas as pl
 
     j = pl.program_id(2)
     n_k = pl.num_programs(2)
     q_off = pl.program_id(1) * block_q
+    k_off = j * block_k
+    prec = _prec(q_ref.dtype)
 
     @pl.when(j == 0)
     def _init():
@@ -226,33 +420,44 @@ def _fa_bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, out_ref,
         delta_sc[:] = jnp.broadcast_to(d, delta_sc.shape)
         delta_ref[0] = d
 
-    run = (j * block_k <= q_off + block_q - 1) if causal else (j < n_k)
-
-    @pl.when(run)
-    def _step():
-        q = q_ref[0]
-        k = k_ref[0]
-        v = v_ref[0]
-        do = do_ref[0]
+    def add(strips):
+        """Add to the dq of q rows `rows` what k rows `cols`, masked by
+        `keep`, give, for each (rows, cols, keep) of `strips`, which
+        together hold every q row once: every strip's two score matmuls
+        first, so that none queues behind another strip's last."""
         # scale q in the INPUT dtype before the dot, exactly like the
         # forward — a post-dot f32 scale would recompute a subtly
         # different s than the one that produced the saved lse
-        prec = _prec(q_ref.dtype)
-        qs = q * jnp.asarray(sm_scale, q.dtype)
-        s = lax.dot_general(qs, k, (((1,), (1,)), ((), ())),
-                            precision=prec,
-                            preferred_element_type=jnp.float32)
-        if causal:
-            s = _causal_mask(s, q_off, j * block_k)
-        p = jnp.exp(s - lse_ref[0])
-        dp = lax.dot_general(do, v, (((1,), (1,)), ((), ())),
-                             precision=prec,
-                            preferred_element_type=jnp.float32)
-        ds = p * (dp - delta_sc[:, :1])
-        acc_sc[:] += lax.dot_general(ds.astype(k.dtype), k,
-                                     (((1,), (0,)), ((), ())),
-                                     precision=prec,
-                            preferred_element_type=jnp.float32)
+        qs = q_ref[0] * jnp.asarray(sm_scale, q_ref.dtype)
+        k, v, do = k_ref[0], v_ref[0], do_ref[0]
+        lse, delta = lse_ref[0], delta_sc[:, :1]
+        first = [(_dot(_cut(qs, rows), _cut(k, cols), (1, 1), prec),
+                  _dot(_cut(do, rows), _cut(v, cols), (1, 1), prec))
+                 for rows, cols, _ in strips]
+        parts = []
+        for (rows, cols, keep), (s, dp) in zip(strips, first):
+            if keep is not None:
+                s = _masked(s, keep)
+            ds = lax.mul(lax.exp(lax.sub(s, _over(_cut(lse, rows), s))),
+                         lax.sub(dp, _over(_cut(delta, rows), dp)))
+            parts.append(_dot(lax.convert_element_type(ds, k.dtype),
+                              _cut(k, cols), (1, 0), prec))
+        acc_sc[:] += _stack(parts)
+
+    def full(masked):
+        add([((0, block_q), (0, block_k),
+              _keep((block_q, block_k), q_off, k_off) if masked else None)])
+
+    def walk():
+        c = plan.sub
+        tri = _keep((c, c), 0, 0)
+        add([(rows, cols, tri if on else None)
+             for rows, cols, on in _walk_rows(block_q, block_k, c)])
+
+    if plan is None:
+        full(False)
+    else:
+        _causal_branches(plan, q_off, k_off, block_q, block_k, full, walk)
 
     @pl.when(j == n_k - 1)
     def _finish():
@@ -261,10 +466,12 @@ def _fa_bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, out_ref,
 
 def _fa_bwd_dkv_kernel(k_ref, v_ref, q_ref, do_ref, lse_ref, delta_ref,
                        dk_ref, dv_ref, dk_sc, dv_sc, *, block_q, block_k,
-                       causal, sm_scale):
+                       plan, sm_scale):
     """dk/dv for one k block, streaming q blocks (innermost grid dim):
       p^T  = exp(s^T*scale - lse);     dv = sum_q p^T dO
-      ds^T = p^T * (dp^T - delta);     dk = scale * sum_q ds^T Q"""
+      ds^T = p^T * (dp^T - delta);     dk = scale * sum_q ds^T Q
+    `plan` as in _fa_kernel; the walk goes by k sub-block here, over the
+    q sub-blocks at and below the diagonal."""
     from jax import lax
     from jax.experimental import pallas as pl
 
@@ -272,40 +479,56 @@ def _fa_bwd_dkv_kernel(k_ref, v_ref, q_ref, do_ref, lse_ref, delta_ref,
     n_q = pl.num_programs(2)
     k_off = pl.program_id(1) * block_k
     q_off = i * block_q
+    prec = _prec(q_ref.dtype)
 
     @pl.when(i == 0)
     def _init():
         dk_sc[:] = jnp.zeros_like(dk_sc)
         dv_sc[:] = jnp.zeros_like(dv_sc)
 
-    run = (q_off + block_q - 1 >= k_off) if causal else (i < n_q)
+    def add(strips):
+        """Add to the dk, dv of k rows `cols` what q rows `rows`, masked by
+        `keep`, give, for each (cols, rows, keep) of `strips`, in
+        transposed scores (k rows, q rows); the score matmuls first, as in
+        the dq kernel. Strips that leave k rows out leave their dk, dv as
+        they are."""
+        q, do, k, v = q_ref[0], do_ref[0], k_ref[0], v_ref[0]
+        qs = q * jnp.asarray(sm_scale, q.dtype)      # as the forward
+        lse, delta = lse_ref[0], delta_ref[0]
+        first = [(_dot(_cut(k, cols), _cut(qs, rows), (1, 1), prec),
+                  _dot(_cut(v, cols), _cut(do, rows), (1, 1), prec))
+                 for cols, rows, _ in strips]
+        dks, dvs = [], []
+        for (cols, rows, keep), (st, dpt) in zip(strips, first):
+            if keep is not None:
+                st = _masked(st, keep, lead=True)
+            pt = lax.exp(lax.sub(st, _under(_cut(lse, rows), st)))
+            dvs.append(_dot(lax.convert_element_type(pt, do.dtype),
+                            _cut(do, rows), (1, 0), prec))
+            dst = lax.mul(pt, lax.sub(dpt, _under(_cut(delta, rows), dpt)))
+            dks.append(_dot(lax.convert_element_type(dst, q.dtype),
+                            _cut(q, rows), (1, 0), prec))
+        done = strips[-1][0][1]       # the strips' k rows run from 0 on
+        dk_sc[:done, :] += _stack(dks)
+        dv_sc[:done, :] += _stack(dvs)
 
-    @pl.when(run)
-    def _step():
-        k = k_ref[0]
-        v = v_ref[0]
-        q = q_ref[0]
-        do = do_ref[0]
-        prec = _prec(q_ref.dtype)
-        qs = q * jnp.asarray(sm_scale, q.dtype)   # match the forward
-        st = lax.dot_general(k, qs, (((1,), (1,)), ((), ())),
-                             precision=prec,
-                             preferred_element_type=jnp.float32)
-        if causal:
-            st = _causal_mask(st, q_off, k_off, transposed=True)
-        pt = jnp.exp(st - lse_ref[0][:, 0][None, :])
-        dv_sc[:] += lax.dot_general(pt.astype(do.dtype), do,
-                                    (((1,), (0,)), ((), ())),
-                                    precision=prec,
-                            preferred_element_type=jnp.float32)
-        dpt = lax.dot_general(v, do, (((1,), (1,)), ((), ())),
-                              precision=prec,
-                            preferred_element_type=jnp.float32)
-        dst = pt * (dpt - delta_ref[0][:, 0][None, :])
-        dk_sc[:] += lax.dot_general(dst.astype(q.dtype), q,
-                                    (((1,), (0,)), ((), ())),
-                                    precision=prec,
-                            preferred_element_type=jnp.float32)
+    def full(masked):
+        add([((0, block_k), (0, block_q),
+              _keep((block_k, block_q), q_off, k_off, transposed=True)
+              if masked else None)])
+
+    def walk():
+        # k sub-block j against the q rows from its own on down: the
+        # triangle leads the strip
+        c = plan.sub
+        tri = _keep((c, c), 0, 0, transposed=True)
+        add([((j * c, j * c + c), (j * c, block_q), tri)
+             for j in range(min(block_k, block_q) // c)])
+
+    if plan is None:
+        full(False)
+    else:
+        _causal_branches(plan, q_off, k_off, block_q, block_k, full, walk)
 
     @pl.when(i == n_q - 1)
     def _finish():
@@ -327,11 +550,11 @@ def _fa_backward(q, k, v, do, lse, out, dlse, causal, sm_scale, block_q,
     bh, tq, d = q.shape
     tk = k.shape[1]
     params = _compiler_params()
+    plan = _causal_plan(tq, tk, block_q, block_k) if causal else None
 
     dq, delta = pl.pallas_call(
         functools.partial(_fa_bwd_dq_kernel, block_q=block_q,
-                          block_k=block_k, causal=causal,
-                          sm_scale=sm_scale),
+                          block_k=block_k, plan=plan, sm_scale=sm_scale),
         grid=(bh, tq // block_q, tk // block_k),
         in_specs=[
             pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0)),
@@ -357,8 +580,7 @@ def _fa_backward(q, k, v, do, lse, out, dlse, causal, sm_scale, block_q,
 
     dk, dv = pl.pallas_call(
         functools.partial(_fa_bwd_dkv_kernel, block_q=block_q,
-                          block_k=block_k, causal=causal,
-                          sm_scale=sm_scale),
+                          block_k=block_k, plan=plan, sm_scale=sm_scale),
         grid=(bh, tk // block_k, tq // block_q),
         in_specs=[
             pl.BlockSpec((1, block_k, d), lambda b, j, i: (b, j, 0)),
@@ -389,7 +611,13 @@ def _pick_block(t, preferred=1024):
     the array. The k block is the LANE dim of the pre-transposed key, so
     a partial block is a multiple of 128; an axis that fits in
     `preferred` is taken whole (rows in multiples of 8). None = no legal
-    block, the caller takes the XLA path."""
+    block, the caller takes the XLA path.
+
+    1024 was tuned on the chip at T = 2,048 and 8,192, where the grid skips
+    the blocks above the diagonal. At T <= 1,024 a head is ONE grid block
+    and the grid skips nothing: a causal call's skip happens inside the
+    block there (_causal_plan), and smaller grid blocks are no substitute
+    (docs/perf_notes.md round 4: 1.4x dearer per unit of work)."""
     if t % 8:
         return None
     if t <= preferred:
@@ -403,13 +631,18 @@ def _pick_block(t, preferred=1024):
 # Which implementation each traced call got, by reason. Attention drops
 # to the O(T^2) XLA reference when no block fits; that must be a choice
 # somebody can see, not a silent one (chip_smoke.py asserts on it).
-_dispatch = {"pallas": 0, "reference": 0}
+_dispatch = {"pallas": 0, "reference": 0,
+             "causal_subblocks_run": 0, "causal_subblocks_all": 0}
 _dispatch_lock = threading.Lock()
 
 
 def dispatch_stats():
     """{"pallas": n, "reference": n}: traced flash_attention/flash_hop
-    calls served by the Pallas kernels vs dropped to attention_reference."""
+    calls served by the Pallas kernels vs dropped to attention_reference;
+    "causal_subblocks_run" of "causal_subblocks_all": over the traced
+    CAUSAL forward kernel calls, the score sub-blocks one head computes
+    and those in its (Tq, Tk) square (_causal_plan; the backward pair
+    walks the same ones). All counted at trace time, nothing per step."""
     with _dispatch_lock:
         return dict(_dispatch)
 
@@ -441,9 +674,10 @@ def _flash_fwd_impl(q, k, v, causal, sm_scale, want_lse=False):
 
     B, Tq, H, D = q.shape
     Tk = k.shape[1]
-    # v5e-tuned r4: (1024, 1024) — 33.8 TF/s fwd at T=2048 (vs 30.5 at
-    # the r3 (512,1024) tune) and 53.4 at T=8192 (vs 46.6); the r3 sweep
-    # predates the backward/block interplay (docs/perf_notes.md)
+    # v5e-tuned r4 at T=2048 and T=8192 only: (1024, 1024) — 33.8 TF/s
+    # fwd at T=2048 (vs 30.5 at the r3 (512,1024) tune) and 53.4 at
+    # T=8192 (vs 46.6); the r3 sweep predates the backward/block interplay
+    # (docs/perf_notes.md). T <= 1024 is one block a head: _pick_block
     blocks = _blocks_for(Tq, Tk, D)
     if blocks is None:
         out = attention_reference(q, k, v, causal=causal,
@@ -474,8 +708,9 @@ def _flash_vjp_bwd(causal, sm_scale, res, g):
     B, Tq, H, D = q.shape
     Tk = k.shape[1]
     if lse is not None:
-        # v5e block sweep (docs/perf_notes.md round 4): (1024,1024) runs
-        # the backward pair at 34.3 TF/s vs 28.9 at the old (512,512)
+        # v5e block sweep at T=2048 (docs/perf_notes.md round 4):
+        # (1024,1024) runs the backward pair at 34.3 TF/s vs 28.9 at the
+        # old (512,512); below T=2048 see _pick_block
         bq = _pick_block(Tq)
         bk = _pick_block(Tk)
         do_bh = _to_bh(g)

@@ -306,3 +306,91 @@ def test_flash_attention_bh_layout():
     for a, b in zip(g1, g2):
         np.testing.assert_allclose(np.asarray(to_bh(a)), np.asarray(b),
                                    rtol=1e-3, atol=1e-4)
+
+
+# -- the causal walk inside a grid block (PR 26) ------------------------------
+# (Tq, Tk, causal, entry, sub-blocks run, in the square) at the sub-block
+# edge the module chose, 256: a square of n x n sub-blocks runs n (n + 1) / 2.
+_WALK_CASES = {
+    "cell_T1024": (1024, 1024, True, "attention", 10, 16),
+    "one_block_T512": (512, 512, True, "attention", 3, 4),
+    # 2 x 2 grid blocks: one below the diagonal (16 whole), two on it (10
+    # each), one above (skipped by the grid)
+    "grid_T2048": (2048, 2048, True, "attention", 36, 64),
+    "noncausal_T1024": (1024, 1024, False, "attention", 0, 0),
+    # top-left aligned: q row r sees k rows <= r, the right half of k never
+    "cross_Tq512_Tk1024": (512, 1024, True, "attention", 3, 8),
+    # blocks (1024, 512): the k block at 512 straddles q block 0 off its
+    # corner and takes one masked pass (8 sub-blocks); both k blocks below
+    # q block 1 run whole; the two on the diagonal run 7 of 8 each
+    "straddle_Tq2048_Tk1536": (2048, 1536, True, "attention", 38, 48),
+    # 384 = 1.5 x 256: three sub-blocks of 128 a side; a block with fewer
+    # than two a side takes one masked pass
+    "edge128_T384": (384, 384, True, "attention", 6, 9),
+    "no_walk_T128": (128, 128, True, "attention", 1, 1),
+    "hop_T1024": (1024, 1024, True, "hop", 10, 16),
+}
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("case", list(_WALK_CASES))
+def test_causal_walk_matches_reference(case, dtype):
+    """Forward and all three gradients against the dense reference over the
+    shapes the sub-block walk tells apart, and the counts that say which
+    sub-blocks ran. `hop` is flash_hop with a cotangent on its lse too."""
+    import importlib
+    fa = importlib.import_module(
+        "incubator_mxnet_tpu.parallel.flash_attention")
+    tq, tk, causal, entry, run, square = _WALK_CASES[case]
+    assert fa._SUB == 256, "recount _WALK_CASES"
+    rng = np.random.RandomState(7)
+    B, H, D = 1, 2, 64
+    q, k, v = (jnp.asarray(rng.randn(B, t, H, D).astype(np.float32) * 0.5
+                           ).astype(dtype) for t in (tq, tk, tk))
+    sm = 1.0 / np.sqrt(D)
+    f32 = lambda x: x.astype(jnp.float32)
+
+    if entry == "hop":
+        def flash(q_, k_, v_):
+            out, lse = fa.flash_hop(q_, k_, v_, causal, sm)
+            return jnp.sum(f32(out) ** 2) + 0.7 * jnp.sum(jnp.sin(lse))
+
+        def dense(q_, k_, v_):
+            s = jnp.einsum("bqhd,bkhd->bhqk", f32(q_), f32(k_)) * sm
+            s = jnp.where(jnp.tril(jnp.ones((tq, tk), bool)), s, -jnp.inf)
+            out = jnp.einsum("bhqk,bkhd->bqhd", jax.nn.softmax(s, -1),
+                             f32(v_))
+            return jnp.sum(out ** 2) + 0.7 * jnp.sum(jnp.sin(
+                jax.scipy.special.logsumexp(s, axis=-1)))
+    else:
+        def flash(q_, k_, v_):
+            return jnp.sum(f32(fa.flash_attention(
+                q_, k_, v_, causal=causal)) ** 2)
+
+        def dense(q_, k_, v_):
+            return jnp.sum(f32(attention_reference(
+                q_, k_, v_, causal=causal)) ** 2)
+
+    before = fa.dispatch_stats()
+    got = jax.value_and_grad(flash, argnums=(0, 1, 2))(q, k, v)
+    after = fa.dispatch_stats()
+    want = jax.value_and_grad(dense, argnums=(0, 1, 2))(q, k, v)
+    assert (after["causal_subblocks_run"] - before["causal_subblocks_run"],
+            after["causal_subblocks_all"] - before["causal_subblocks_all"]
+            ) == (run, square)
+    tol = 2e-4 if dtype == "float32" else 4e-2
+    for name, a, b in zip(("loss", "dq", "dk", "dv"),
+                          (got[0],) + got[1], (want[0],) + want[1]):
+        a, b = np.asarray(a, np.float32), np.asarray(b, np.float32)
+        err = np.abs(a - b).max() / max(1e-3, np.abs(b).max())
+        assert err < tol, (name, err)
+
+
+def test_causal_walk_output_matches_reference_elementwise():
+    """The cell's shape, output rows compared one by one (the loss above
+    sums them): every q sub-block's carried max / sum / accumulator."""
+    q, k, v = _qkv(B=1, T=1024, H=2, D=64, seed=11)
+    np.testing.assert_allclose(
+        np.asarray(flash_attention(q, k, v, causal=True)),
+        np.asarray(attention_reference(q, k, v, causal=True)),
+        rtol=1e-4, atol=1e-5)
