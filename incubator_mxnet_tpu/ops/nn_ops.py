@@ -265,6 +265,19 @@ def _bn_batch_stats(data, red):
     E[x^2]-E[x]^2 catastrophic cancellation for badly-centered
     activations (|mean| >> std) — unconditionally, unlike a
     moving_mean shift, which is garbage at cold start.
+
+    The shift is a CONSTANT of the reduction, under `stop_gradient`:
+    mean and var do not depend on it (d mean/dc = 1 - n/n, d var/dc =
+    -2 s1/n + 2 dmean), so its cotangent is the rounding residue of
+    an exact zero, which autodiff would still pad back into the batch
+    and add to the activation's gradient. On a dp mesh (batch sharded,
+    statistics of the global batch) the slice lives on one chip. What
+    crosses chips per layer: forward f32[C] for the shift, then
+    (s1, s2) as one all-reduce; backward the layer's two f32[C]
+    reductions as one. The cotangent would add an f32[C] and a
+    (1, C, H, W) map to every backward layer, and the map's
+    all-reduce keeps XLA from fusing that backward pass into the
+    convolutions round it.
     """
     n = 1
     for i in red:
@@ -275,7 +288,7 @@ def _bn_batch_stats(data, red):
         # keep the old NaN-stats-no-crash contract for this edge
         return (jnp.mean(data.astype(jnp.float32), axis=red),
                 jnp.var(data.astype(jnp.float32), axis=red))
-    first = lax.slice_in_dim(data, 0, 1, axis=red[0])
+    first = lax.stop_gradient(lax.slice_in_dim(data, 0, 1, axis=red[0]))
     c = jnp.mean(first.astype(jnp.float32), axis=red, keepdims=True)
     shifted = data.astype(jnp.float32) - c
     s1 = jnp.sum(shifted, axis=red, dtype=jnp.float32)

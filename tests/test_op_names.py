@@ -222,17 +222,21 @@ def test_a_cache_filled_without_names_then_a_program_with_them(
 # may hold the TPU's library).
 
 @pytest.fixture(scope="module")
-def one_chip():
+def v5e():
     import os
     from jax.experimental import topologies
-    from jax.sharding import SingleDeviceSharding
     os.environ.setdefault("TPU_LOG_DIR", "disabled")
     try:
-        topo = topologies.get_topology_desc(platform="tpu",
+        return topologies.get_topology_desc(platform="tpu",
                                             topology_name="v5e:2x2")
     except Exception as e:      # noqa: BLE001 — whatever libtpu raises here
         pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
-    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(scope="module")
+def one_chip(v5e):
+    from jax.sharding import SingleDeviceSharding
+    return SingleDeviceSharding(v5e.devices[0])
 
 
 @pytest.mark.parametrize("kernels", [("flash_fwd",),
@@ -271,3 +275,114 @@ def test_flash_kernels_keep_their_names_in_a_tpu_program(one_chip, kernels):
         assert re.search(rf'op_name="jit\(\w+\)/forward/attn/{name}/'
                          r'pallas_call"', line), line[-400:]
         assert "bf16[512,1024,64]" in line.split("custom-call(")[1]
+
+
+# -- BatchNorm's all-reduces on a dp mesh -------------------------------------
+# Under GSPMD a BatchNorm over a batch sharded on `dp` takes the statistics of
+# the global batch, and every reduction over the batch becomes an all-reduce.
+# What a layer needs: forward the shift's f32[C] and (s1, s2) as one, backward
+# the two reductions of its gradient as one. The shift's own cotangent, an
+# exact zero, used to add an f32[C] and a (1, C, H, W) map to each backward
+# layer (`_bn_batch_stats`).
+
+_BN_C = 8
+_BN_LAYERS = {"BatchNorm": 1, "FusedBNAddReLU": 2, "FusedConvBNReLU": 1}
+
+
+class _ThreeOps(gluon.HybridBlock):
+    """conv, BatchNorm, conv, FusedBNAddReLU, conv, FusedBNAddReLU with a
+    residual, FusedConvBNReLU: each registered op that takes batch
+    statistics, in training mode."""
+
+    def __init__(self, **kwargs):
+        super().__init__(**kwargs)
+        with self.name_scope():
+            self.convs = gluon.nn.HybridSequential(prefix="")
+            for _ in range(3):
+                self.convs.add(gluon.nn.Conv2D(_BN_C, 3, padding=1,
+                                               use_bias=False))
+            self.weight = self.params.get("weight",
+                                          shape=(_BN_C, _BN_C, 3, 3))
+            for i in range(4):
+                for name, init, diff in (("gamma", "ones", True),
+                                         ("beta", "zeros", True),
+                                         ("mean", "zeros", False),
+                                         ("var", "ones", False)):
+                    setattr(self, f"{name}{i}", self.params.get(
+                        f"{name}{i}", shape=(_BN_C,), init=init,
+                        differentiable=diff))
+
+    def hybrid_forward(self, F, x, weight, **p):
+        def bn(i):
+            return [p[f"{n}{i}"] for n in ("gamma", "beta", "mean", "var")]
+        kw = {"fix_gamma": False, "training": mx.autograd.is_training()}
+        y = F.BatchNorm(self.convs[0](x), *bn(0), **kw)[0]
+        z = F.FusedBNAddReLU(self.convs[1](y), *bn(1), **kw)[0]
+        z = F.FusedBNAddReLU(self.convs[2](z), *bn(2), y, **kw)[0]
+        return F.FusedConvBNReLU(z, weight, *bn(3), kernel=(3, 3),
+                                 pad=(1, 1), num_filter=_BN_C, **kw)[0]
+
+
+@pytest.fixture(scope="module")
+def three_ops_step():
+    from incubator_mxnet_tpu.parallel import TrainStep, make_mesh
+    net = _ThreeOps()
+    net.initialize()
+    return TrainStep(
+        net, lambda out, label: jnp.mean(out.astype(jnp.float32) * label),
+        optimizer="sgd",
+        optimizer_params={"learning_rate": 0.1, "momentum": 0.9},
+        mesh=make_mesh({"dp": 4}, jax.devices()[:4]),
+        example_inputs=[mx.nd.ones((8, 3, 8, 8))])
+
+
+@pytest.fixture(scope="module", params=["cpu", "v5e:2x2"])
+def bn_all_reduces(request, three_ops_step):
+    """(scope, backward?, elements) of every all-reduce under a BatchNorm
+    scope in the step compiled for four devices: four of the suite's CPU
+    devices, or the four described chips of a v5e:2x2."""
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+    devices = jax.devices()[:4] if request.param == "cpu" \
+        else request.getfixturevalue("v5e").devices[:4]
+    mesh = Mesh(np.array(devices), ("dp",))
+    rep, dat = NamedSharding(mesh, P()), NamedSharding(mesh, P("dp"))
+    step = three_ops_step
+
+    def aval(v, sharding):
+        return jax.ShapeDtypeStruct(v.shape, v.dtype, sharding=sharding)
+    text = jax.jit(step._step_fn).lower(
+        {k: aval(v, rep) for k, v in step.params.items()},
+        {k: tuple(aval(s, rep) for s in st)
+         for k, st in step.opt_state.items()},
+        aval(jax.random.PRNGKey(0), rep), 0,
+        jax.ShapeDtypeStruct((8, 3, 8, 8), jnp.float32, sharding=dat),
+        jax.ShapeDtypeStruct((8, _BN_C, 8, 8), jnp.float32, sharding=dat),
+    ).compile().as_text()
+    found = []
+    for line in text.splitlines():
+        m = re.match(r"\s*(?:ROOT )?%?[\w.\-]+ = (.*?) all-reduce(?:-start)?\(",
+                     line)
+        if not m:
+            continue
+        name = re.search(r'op_name="([^"]*)"', line).group(1)
+        scope = [part for part in name.split("/") if part in _BN_LAYERS]
+        # the step's one gradient all-reduce is named after any of its
+        # members, gamma's and beta's among them: known by a conv's weight
+        if scope and not re.search(r"\[8,[38],3,3\]", m.group(1)):
+            found.append((scope[0], "transpose(" in name, sum(
+                int(np.prod([int(d) for d in dims.split(",") if d]))
+                for dims in re.findall(r"\w+\[([\d,]*)\]", m.group(1)))))
+    return found
+
+
+@pytest.mark.parametrize("scope", sorted(_BN_LAYERS))
+def test_batchnorm_on_a_dp_mesh_sends_only_its_statistics(bn_all_reduces,
+                                                          scope):
+    """No all-reduce of a BatchNorm layer carries more than two f32[C]
+    vectors; a layer's forward pass has at most two and its backward pass
+    one (none where XLA lets the first layer's ride the gradient's)."""
+    mine = [(back, n) for s, back, n in bn_all_reduces if s == scope]
+    assert mine and max(n for _, n in mine) <= 2 * _BN_C, mine
+    layers = _BN_LAYERS[scope]
+    assert layers <= sum(not back for back, _ in mine) <= 2 * layers, mine
+    assert sum(back for back, _ in mine) <= layers, mine
