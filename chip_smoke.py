@@ -49,8 +49,7 @@ FULL = {
                   buckets=(1, 4), heads=16, head_dim=128, vocab=50304,
                   page_size=16, slots=16, num_pages=2048,
                   pages_per_seq=40, prompt_buckets=(32, 128, 512),
-                  prompt_lens=(5, 40, 200, 500), new_tokens=32,
-                  verify_g=5),
+                  prompt_lens=(5, 40, 200, 500), new_tokens=32),
 }
 # --rehearse: the same code at sizes a CPU finishes in about a minute
 REHEARSAL = {
@@ -62,7 +61,7 @@ REHEARSAL = {
                   buckets=(1, 4), heads=2, head_dim=8, vocab=64,
                   page_size=4, slots=4, num_pages=64, pages_per_seq=12,
                   prompt_buckets=(4, 16), prompt_lens=(3, 7, 12),
-                  new_tokens=8, verify_g=3),
+                  new_tokens=8),
 }
 
 
@@ -156,10 +155,9 @@ def begin(leg, rehearse):
         f"built and loaded (jpeg decode: {bool(lib.has_jpeg)})"),
         flush=True)
     if not rehearse:
-        # the five copies of the rule `interpret = backend != "tpu"`
+        # the copies of the rule `interpret = backend != "tpu"`
         import importlib
-        for name in ("parallel.flash_attention", "parallel.paged_attention",
-                     "parallel.conv_backward"):
+        for name in ("parallel.flash_attention", "parallel.fused_conv"):
             mod = importlib.import_module("incubator_mxnet_tpu." + name)
             assert mod._interpret() is False, f"{name} would interpret"
         assert jax.default_backend() == "tpu"   # compression.py, rtc.py
@@ -424,44 +422,6 @@ def _burst(fn, items):
     return out
 
 
-def _check_paged_kernels(cfg):
-    """Both paged-attention kernels, compiled by Mosaic, agree with their
-    XLA references on ragged random inputs at the served geometry."""
-    import importlib
-    import jax.numpy as jnp
-    import numpy as np
-    pa = importlib.import_module(
-        "incubator_mxnet_tpu.parallel.paged_attention")
-    rng = np.random.RandomState(1)
-    b, h, d = cfg["slots"], cfg["heads"], cfg["head_dim"]
-    ps, pages, per_seq = cfg["page_size"], cfg["num_pages"], \
-        cfg["pages_per_seq"]
-    g = cfg["verify_g"]
-    kp, vp = (jnp.asarray(rng.standard_normal((pages, ps, h, d)),
-                          jnp.float32) for _ in range(2))
-    table = jnp.asarray(rng.permutation(pages)[:b * per_seq]
-                        .reshape(b, per_seq), jnp.int32)
-    lens = rng.randint(1, ps * per_seq - g, b)
-    lens[0], lens[-1] = 1, ps * per_seq - g     # both ends of the range
-    q = jnp.asarray(rng.standard_normal((b, h, d)), jnp.float32)
-    np.testing.assert_allclose(
-        np.asarray(pa.paged_attention_pallas(
-            q, kp, vp, table, jnp.asarray(lens, jnp.int32))),
-        np.asarray(pa.paged_attention_reference(
-            q, kp, vp, table, jnp.asarray(lens, jnp.int32))),
-        rtol=1e-4, atol=1e-4, err_msg="paged_attention_pallas")
-    qg = jnp.asarray(rng.standard_normal((b, g, h, d)), jnp.float32)
-    lens_g = jnp.asarray(lens[:, None] + np.arange(g)[None], jnp.int32)
-    np.testing.assert_allclose(
-        np.asarray(pa.paged_attention_mq_pallas(qg, kp, vp, table, lens_g)),
-        np.asarray(pa.paged_attention_mq_reference(qg, kp, vp, table,
-                                                   lens_g)),
-        rtol=1e-4, atol=1e-4, err_msg="paged_attention_mq_pallas")
-    print(f"[smoke:serve] paged_attention and paged_attention_mq kernels "
-          f"agree with their references (B{b} H{h} D{d} page {ps}, "
-          f"lens {lens.min()}..{lens.max()}, G{g})", flush=True)
-
-
 def leg_serve(cfg, rehearse, result_path):
     device, cache0 = begin("serve", rehearse)
     import numpy as np
@@ -470,7 +430,6 @@ def leg_serve(cfg, rehearse, result_path):
     from incubator_mxnet_tpu.serve import (DecodePredictor, DecodeScheduler,
                                            ModelServer, Predictor)
     t0 = time.time()
-    _check_paged_kernels(cfg)
 
     # -- /predict: exported ResNet against the Gluon net it came from ----
     mx.random.seed(0)

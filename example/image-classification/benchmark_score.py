@@ -5,7 +5,7 @@ BASELINE.md's inference rows).
 
 Scans batch sizes per network; each measurement runs its loop on-device
 (lax.scan with carry feedback) so per-dispatch host time doesn't pollute
-the number — same discipline as bench.py.
+the number.
 """
 import argparse
 import os

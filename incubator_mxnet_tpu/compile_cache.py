@@ -443,7 +443,7 @@ class _CachedJit:
         table. Gated on the attribution flag like every automatic
         observability hook — otherwise every op a process ever compiles
         leaks into dumps() (callers who want cost unconditionally use
-        profiler.cost_from_executable directly, the bench.py path).
+        profiler.cost_from_executable directly).
         Never raises — cost extraction is advisory."""
         try:
             from . import profiler as _prof

@@ -57,21 +57,19 @@ class TransformerConfig:
     max_len: int = 2048
     dtype: str = "bfloat16"
     remat: bool = True          # jax.checkpoint each block (HBM for FLOPs)
-    # Selective rematerialization policy (r4 profile: recompute is 199ms
-    # = 18% of the flagship step, the largest untried lever). None =
-    # recompute everything (baseline). "dots" / "dots_no_batch" are
-    # XLA's stock save-matmul-outputs policies; "save_attn" /
-    # "save_attn_mlp" save the named per-block outputs (attn_out, mlp_out
-    # — 1.6 GB each per 12x1024/T2048/b32 model at bf16) and recompute
-    # the rest. Measured results belong in docs/perf_notes.md.
+    # Selective rematerialization policy. None = recompute everything
+    # (the recomputed forward's share of a step is `remat_time_share`,
+    # PERF.md section 5). "dots" / "dots_no_batch" are XLA's stock
+    # save-matmul-outputs policies; "save_attn" / "save_attn_mlp" save
+    # the named per-block outputs (attn_out, mlp_out) and recompute the
+    # rest.
     remat_policy: str | None = None
     # Pallas blocked flash attention for the non-sp path (O(T) memory,
     # parallel/flash_attention.py); the sp path always uses ring
-    # attention. DEFAULT ON since round 4: steady-state train at T=2048
-    # b32 measures 56.3k tok/s vs 39.9k with the dense path (the round-3
-    # "flash loses end-to-end" number was a first-dispatch warmup
-    # artifact — docs/perf_notes.md). Untileable shapes fall back to
-    # attention_reference inside flash_attention().
+    # attention. On by default: it beats the dense path end to end at
+    # T = 2,048 and is the only path that compiles at long context
+    # (docs/perf_notes.md; speeds in PERF.md section 5). Untileable
+    # shapes fall back to attention_reference inside flash_attention().
     flash_attention: bool = True
 
 
@@ -152,12 +150,12 @@ class TransformerLM:
         if sp_axis is not None:
             attn = ring_attention(q, kk, v, sp_axis, causal=True)
         elif self.cfg.flash_attention:
-            # measured r4: emitting (BH,T,hd) straight from projection
-            # einsums to skip the _to_bh copies is 4.4% SLOWER end to end
-            # (56.5k vs 59.1k tok/s) — XLA's bhtk-output einsum costs
-            # more than the transposes it saves. Keep the standard
-            # layout; flash_attention_bh stays for callers that already
-            # hold (BH,T,D).
+            # emitting (BH,T,hd) straight from the projection einsums to
+            # skip the _to_bh copies was slower end to end: XLA's
+            # bhtk-output einsum costs more than the transposes it saves
+            # (their share of a step: PERF.md section 5). Keep the
+            # standard layout; flash_attention_bh stays for callers that
+            # already hold (BH,T,D).
             from ..parallel.flash_attention import flash_attention
             attn_fn = functools.partial(flash_attention, causal=True)
             if mesh is not None:

@@ -11,6 +11,8 @@ hand-fused cuDNN kernels.
 """
 from __future__ import annotations
 
+import functools
+
 import numpy as _np
 
 from ..base import MXNetError, dtype_np
@@ -125,24 +127,13 @@ def _conv_xla(data, weight, kernel, stride, dilate, pad, num_group):
         else None)
 
 
-def _conv3x3_xla(data, weight):
-    """The plain-XLA candidate the tuned 3x3 table races against."""
-    return _conv_xla(data, weight, (3, 3), (1, 1), (1, 1), (1, 1), 1)
-
-
 def _conv_core(data, weight, kernel, stride, dilate, pad, num_group):
     """Convolution dispatch shared by the Convolution op and the fused
-    conv+BN+ReLU paths: stem space-to-depth rewrite, then the tuned 3x3
-    table (parallel/conv_backward's fused-backward kernel raced against
-    XLA's native vjp — selection by measurement, never by heuristic),
-    then plain XLA."""
+    conv+BN+ReLU paths: the stem space-to-depth rewrite where it is
+    exact, else plain XLA."""
     kernel = tuple(int(x) for x in kernel)
     if _stem_eligible(data, kernel, stride, dilate, pad, num_group):
         return _stem_s2d_conv(data, weight, kernel[0])
-    if (kernel == (3, 3) and stride == (1, 1) and dilate == (1, 1)
-            and pad == (1, 1) and num_group == 1 and data.ndim == 4):
-        from ..parallel import conv_backward  # noqa: F401 — registers conv3x3
-        return tune.tuned_call("conv3x3", _conv3x3_xla, data, weight)
     return _conv_xla(data, weight, kernel, stride, dilate, pad, num_group)
 
 
@@ -309,29 +300,38 @@ def _bn_scale_bias(gamma, beta, mean, var, eps, fix_gamma):
     return scale, bias
 
 
-def _bn_apply_xla(data, scale, bias):
-    """Plain-XLA candidate for the tuned BN apply epilogue."""
-    from ..parallel.fused_conv import bn_act_reference
-    return bn_act_reference(data, scale, bias, relu=False)
+def bn_act_reference(data, scale, bias, residual=None, *, relu=True, ax=1):
+    """The BN apply chain every BatchNorm op ends in, and the XLA candidate
+    of the tuned epilogue families (parallel/fused_conv.py, whose kernels'
+    backward is this function's vjp): one multiply-add, rounded to the
+    data dtype BEFORE the optional residual add and ReLU, as the Gluon
+    blocks compose them layer by layer."""
+    shape = [1] * data.ndim
+    shape[ax] = data.shape[ax]
+    out = (data * jnp.reshape(scale, shape)
+           + jnp.reshape(bias, shape)).astype(data.dtype)
+    if residual is not None:
+        out = out + residual
+    return jnp.maximum(out, 0) if relu else out
 
 
-def _bn_act_xla(data, scale, bias):
-    """Plain-XLA candidate for the tuned BN+ReLU epilogue."""
-    from ..parallel.fused_conv import bn_act_reference
-    return bn_act_reference(data, scale, bias, relu=True)
+# tuned_call hands its keyword arguments to every candidate, so the
+# family without ReLU binds the flag here
+_bn_apply_xla = functools.partial(bn_act_reference, relu=False)
 
 
-def _bn_add_act_xla(data, scale, bias, residual):
-    """Plain-XLA candidate for the tuned BN+residual-add+ReLU epilogue."""
-    from ..parallel.fused_conv import bn_act_reference
-    return bn_act_reference(data, scale, bias, residual, relu=True)
-
-
-def _conv_bn_relu_xla(data, weight, scale, bias, *, k, pad_lo, pad_hi):
-    """Plain-XLA candidate for the tuned fused conv+BN+ReLU forward."""
-    from ..parallel.fused_conv import conv_bn_relu_reference
-    return conv_bn_relu_reference(data, weight, scale, bias, k, pad_lo,
-                                  pad_hi)
+def conv_bn_relu_reference(data, weight, scale, bias, k, pad_lo, pad_hi):
+    """XLA candidate of the tuned fused forward: a k x k stride-1 NCHW
+    conv with (possibly asymmetric) padding, the Convolution op's trailing
+    astype, then bn_act_reference."""
+    del k       # the weight carries it; the Pallas candidates need it static
+    z = lax.conv_general_dilated(
+        data, weight, window_strides=(1, 1),
+        padding=list(zip(pad_lo, pad_hi)),
+        dimension_numbers=_conv_dnums(2),
+        preferred_element_type=jnp.float32 if data.dtype == jnp.float32
+        else None).astype(data.dtype)
+    return bn_act_reference(z, scale, bias)
 
 
 def _bn_apply(data, scale, bias, ax):
@@ -339,10 +339,7 @@ def _bn_apply(data, scale, bias, ax):
     if ax == 1 and data.ndim == 4:
         from ..parallel import fused_conv  # noqa: F401 — registers epilogues
         return tune.tuned_call("bn_apply", _bn_apply_xla, data, scale, bias)
-    shape = [1] * data.ndim
-    shape[ax] = data.shape[ax]
-    return (data * jnp.reshape(scale, shape)
-            + jnp.reshape(bias, shape)).astype(data.dtype)
+    return bn_act_reference(data, scale, bias, relu=False, ax=ax)
 
 
 @register(name="BatchNorm", aliases=("batch_norm", "BatchNorm_v1"), train_aware=True)
@@ -389,15 +386,13 @@ def fused_bn_add_relu(data, gamma, beta, moving_mean, moving_var,
     if ax == 1 and data.ndim == 4:
         from ..parallel import fused_conv  # noqa: F401 — registers epilogues
         if residual is None:
-            out = tune.tuned_call("bn_act", _bn_act_xla, data, scale, bias)
+            out = tune.tuned_call("bn_act", bn_act_reference, data, scale,
+                                  bias)
         else:
-            out = tune.tuned_call("bn_add_act", _bn_add_act_xla, data,
+            out = tune.tuned_call("bn_add_act", bn_act_reference, data,
                                   scale, bias, residual)
     else:
-        out = _bn_apply(data, scale, bias, ax)
-        if residual is not None:
-            out = out + residual
-        out = jnp.maximum(out, 0)
+        out = bn_act_reference(data, scale, bias, residual, ax=ax)
     return (out, mean, var)
 
 
@@ -411,20 +406,20 @@ def _conv_bn_relu_infer(data, weight, scale, bias, kernel, stride, dilate,
     if residual is None and _stem_eligible(data, kernel, stride, dilate,
                                            pad, num_group):
         x2, w2, m, lo, hi = _stem_s2d_parts(data, weight, k)
-        return tune.tuned_call("conv_bn_relu", _conv_bn_relu_xla, x2, w2,
-                               scale, bias, k=m, pad_lo=(lo, lo),
+        return tune.tuned_call("conv_bn_relu", conv_bn_relu_reference, x2,
+                               w2, scale, bias, k=m, pad_lo=(lo, lo),
                                pad_hi=(hi, hi))
     if (residual is None and len(kernel) == 2 and kernel == (k, k)
             and k % 2 == 1 and stride == (1, 1) and dilate == (1, 1)
             and pad == (k // 2,) * 2 and num_group == 1 and data.ndim == 4):
-        return tune.tuned_call("conv_bn_relu", _conv_bn_relu_xla, data,
-                               weight, scale, bias, k=k,
+        return tune.tuned_call("conv_bn_relu", conv_bn_relu_reference,
+                               data, weight, scale, bias, k=k,
                                pad_lo=(k // 2,) * 2, pad_hi=(k // 2,) * 2)
     z = _conv_core(data, weight, kernel, stride, dilate, pad,
                    num_group).astype(data.dtype)
     if residual is None:
-        return tune.tuned_call("bn_act", _bn_act_xla, z, scale, bias)
-    return tune.tuned_call("bn_add_act", _bn_add_act_xla, z, scale, bias,
+        return tune.tuned_call("bn_act", bn_act_reference, z, scale, bias)
+    return tune.tuned_call("bn_add_act", bn_act_reference, z, scale, bias,
                            residual)
 
 
@@ -462,9 +457,9 @@ def fused_conv_bn_relu(data, weight, gamma, beta, moving_mean, moving_var,
     var = var.astype(moving_var.dtype)
     scale, bias = _bn_scale_bias(gamma, beta, mean, var, eps, fix_gamma)
     if residual is None:
-        out = tune.tuned_call("bn_act", _bn_act_xla, z, scale, bias)
+        out = tune.tuned_call("bn_act", bn_act_reference, z, scale, bias)
     else:
-        out = tune.tuned_call("bn_add_act", _bn_add_act_xla, z, scale, bias,
+        out = tune.tuned_call("bn_add_act", bn_act_reference, z, scale, bias,
                               residual)
     return (out, mean, var)
 

@@ -18,9 +18,7 @@ from .functional import functionalize
 from .train import TrainStep, shard_batch
 from .ring_attention import ring_attention, ring_attention_sharded
 from .flash_attention import flash_attention, flash_attention_bh
-from .paged_attention import (paged_attention,
-                              paged_attention_multiquery,
-                              paged_attention_reference)
+from .paged_attention import paged_attention, paged_attention_multiquery
 from .pipeline import pipeline_apply, pipeline_sharded
 from .moe import moe_apply, moe_sharded, init_moe_params
 from .partition import match_partition_rules
@@ -36,7 +34,6 @@ __all__ = ["make_mesh", "local_mesh_axis_sizes", "functionalize", "TrainStep",
            "shard_batch", "ring_attention", "ring_attention_sharded",
            "flash_attention", "flash_attention_bh",
            "paged_attention", "paged_attention_multiquery",
-           "paged_attention_reference",
            "pipeline_apply", "pipeline_sharded",
            "moe_apply", "moe_sharded", "init_moe_params",
            "column_parallel_spec", "row_parallel_spec",

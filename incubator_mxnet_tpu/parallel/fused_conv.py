@@ -7,8 +7,10 @@ kernels, src/operator/nn/cudnn/).  On TPU, XLA already fuses elementwise
 epilogues into convs MOST of the time — so unlike the reference, nothing
 here is dispatched unconditionally: every kernel is a CANDIDATE the
 autotuner times against the plain-XLA composition per (shape, dtype,
-device), and the loser is never called (parallel/conv_backward.py is the
-cautionary measured-negative precedent).
+device), and the loser is never called.  A train step takes the XLA
+candidate at every site without a race (parallel/train.py traces its
+forward pass under tune.xla_only), so the race runs where a forward pass
+is the whole program: Predictor and eager ops.
 
 Two kernel families:
 
@@ -33,9 +35,9 @@ dtype.  Gradients come from ``jax.custom_vjp`` whose backward is the
 unfused path by construction, no hand backward kernel to drift.
 
 Layout: NHWC inside (channel-minor = MXU/VPU lane dim), NCHW at the
-boundary, like conv_backward.py.  Off-TPU the kernels run in interpret
-mode, but are only OFFERED to the tuner under MXTPU_TUNE_INTERPRET
-(interpret mode always loses a fair race; tests set it).
+boundary.  Off-TPU the kernels run in interpret mode, but are only
+OFFERED to the tuner under MXTPU_TUNE_INTERPRET (interpret mode always
+loses a fair race; tests set it).
 """
 from __future__ import annotations
 
@@ -46,8 +48,10 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+# the references (each family's implicit "xla" candidate, and the oracle
+# the kernels' backward is the vjp of) are the op layer's own compositions
+from ..ops.nn_ops import bn_act_reference, conv_bn_relu_reference
 from ..util import getenv_bool
-from .conv_backward import _compiler_params, _interpret
 
 __all__ = ["bn_act_reference", "conv_bn_relu_reference",
            "bn_act_candidates", "conv_bn_relu_candidates",
@@ -59,6 +63,14 @@ _VMEM_BUDGET = 11 * 1024 * 1024     # of the ~16MB scoped-vmem window
 _CONV_VMEM_BUDGET = 14 * 1024 * 1024
 
 
+def _compiler_params(pltpu):
+    return pltpu.CompilerParams(dimension_semantics=("arbitrary",))
+
+
+def _interpret():
+    return jax.default_backend() != "tpu"
+
+
 def _prec(dtype):
     # bf16 operands: DEFAULT is mandatory (Mosaic rejects the implicit
     # fp32 contract); f32: HIGHEST keeps true-f32 dots like the XLA conv
@@ -68,34 +80,6 @@ def _prec(dtype):
 
 def _lanes(c):
     return -(-c // 128) * 128
-
-
-# ---------------------------------------------------------------------------
-# references (the implicit "xla" candidate's math, and the backward oracle)
-# ---------------------------------------------------------------------------
-
-def bn_act_reference(z, scale, bias, residual=None, relu=True):
-    """The unfused BN-apply chain from ops/nn_ops.py batch_norm, plus the
-    optional residual add and ReLU exactly as the gluon blocks compose
-    them: round to the data dtype BEFORE the add."""
-    shape = (1, -1) + (1,) * (z.ndim - 2)
-    out = (z * jnp.reshape(scale, shape)
-           + jnp.reshape(bias, shape)).astype(z.dtype)
-    if residual is not None:
-        out = out + residual
-    return jnp.maximum(out, 0) if relu else out
-
-
-def conv_bn_relu_reference(x, w, scale, bias, k, pad_lo, pad_hi):
-    """Stride-1 NCHW conv (same math as nn_ops._conv_xla incl. the
-    trailing astype) followed by bn_act_reference."""
-    z = lax.conv_general_dilated(
-        x, w, window_strides=(1, 1),
-        padding=[(pad_lo[0], pad_hi[0]), (pad_lo[1], pad_hi[1])],
-        dimension_numbers=("NCHW", "OIHW", "NCHW"),
-        preferred_element_type=jnp.float32 if x.dtype == jnp.float32
-        else None).astype(x.dtype)
-    return bn_act_reference(z, scale, bias)
 
 
 # ---------------------------------------------------------------------------

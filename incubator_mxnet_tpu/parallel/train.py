@@ -11,7 +11,6 @@ kvstore priority=-key).
 """
 from __future__ import annotations
 
-import contextlib
 from collections import OrderedDict
 
 import jax
@@ -233,12 +232,13 @@ class TrainStep:
         self.preplaced_hits = 0
         non_diff = {p.name for p in self._param_list if p.grad_req == "null"}
 
-        # Under a multi-device mesh this is a GSPMD program, and a Mosaic
-        # kernel cannot be partitioned automatically: the tuner's Pallas
-        # candidates are withheld while the net is traced (tune.xla_only).
-        spans = (f"TrainStep traces one program over {mesh.devices.size} "
-                 f"devices" if mesh is not None and mesh.devices.size > 1
-                 else None)
+        # One behaviour on one device as on a mesh: every tuned site in the
+        # forward pass takes XLA (tune.xla_only). A Pallas candidate's
+        # backward is the vjp of the XLA reference on top of its own
+        # forward, which a race of forward calls cannot see.
+        withheld_why = ("TrainStep differentiates its forward pass: the "
+                        "race times a forward call on the host clock, and "
+                        "a Mosaic kernel cannot be partitioned over a mesh")
 
         def step_fn(params, opt_state, rng, step_i, *batch):
             inputs, label = batch[:-1], batch[-1]
@@ -246,8 +246,7 @@ class TrainStep:
             def loss_of(diff_params):
                 full = dict(params)
                 full.update(diff_params)
-                with (_tune.xla_only(spans) if spans
-                      else contextlib.nullcontext(),
+                with (_tune.xla_only(withheld_why),
                       jax.named_scope("forward")):
                     outs, writes = apply_fn(full, rng, *inputs)
                 out = outs[0]

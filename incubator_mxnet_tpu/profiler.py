@@ -28,8 +28,8 @@ pass itself: `jvp(forward)/..` is the forward pass, `transpose(jvp(forward))
 /..` the backward pass, and `../checkpoint/rematted_computation/..` the
 forward pass recomputed inside it. The flash-attention kernels appear as
 `flash_fwd`, `flash_bwd_dq` and `flash_bwd_dkv`, the fused convolution
-kernels as `conv_bn_relu_<variant>`, `bn_act`, `bn_add_act`, `bn_apply`,
-`conv3x3_bwd_patch` and `conv3x3_bwd_taps`. A fusion is listed under the
+kernels as `conv_bn_relu_<variant>`, `bn_act`, `bn_add_act` and
+`bn_apply`. A fusion is listed under the
 scope of the instruction XLA made its root, so a convolution fused with a
 BatchNorm epilogue is one `Convolution` entry. Eager dispatch enters no
 scope. `perfbench/op_scopes.py` reduces the same names to shares of device
@@ -698,7 +698,7 @@ def clock_sync_event(peer, offset_us, rtt_us):
 _costs = {}
 
 # Published per-chip peaks keyed by jax's exact `device_kind` (Google Cloud
-# documentation, "TPU v5e"). THE table: bench.py reads it too. A kind that
+# documentation, "TPU v5e"). A kind that
 # is not listed is an error — never a default, never a prefix match.
 DEVICE_PEAKS = {
     "TPU v5 lite": {"bf16_flops": 197e12, "int8_ops": 393e12,

@@ -8,7 +8,7 @@ waits longer than `max_latency_ms`, and a max-batch trigger so a full
 bucket dispatches immediately.
 
 Admission control is load-shed-first (the graceful-degradation idiom of
-fault.py / bench.py's backend probes): the request queue is BOUNDED, an
+fault.py): the request queue is BOUNDED, an
 overflowing submit fails fast with a distinct retryable error
 (`Overloaded`) instead of queueing into collapse, and requests whose
 deadline expired while queued are dropped before wasting a bucket slot

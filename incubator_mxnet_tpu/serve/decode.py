@@ -17,7 +17,7 @@ Three pieces:
     whole pages (``page_size`` token rows each); admit pops page ids
     off the free list, retire pushes them back — ZERO data copies in
     either direction, because the pages themselves never move: only the
-    per-sequence page table (the indirection the ragged kernel reads)
+    per-sequence page table (the indirection paged attention reads through)
     changes.
 
 ``DecodePredictor``

@@ -22,7 +22,7 @@ of ``G = k+1`` query tokens per slot:
   read a clamped one-key window, exactly like the decode executable's
   idle slots;
 * the attention read path is ``paged_attention_multiquery`` — one
-  shared page walk per sequence serves all G queries
+  shared gather of a sequence's pages serves all G queries
   (parallel/paged_attention.py), so verify costs one pass over the KV
   history, not G.
 

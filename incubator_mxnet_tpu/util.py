@@ -230,10 +230,6 @@ ENV_VARS = collections.OrderedDict([
     ("MXTPU_NO_NATIVE", EnvSpec(False, "bool",
      "Disable the C accelerators for recordio/image packing and fall "
      "back to pure python.")),
-    ("MXTPU_CONV_BWD_KERNEL", EnvSpec("patch", "str",
-     "Conv backward-data kernel choice: 'patch' (default) or 'taps'.")),
-    ("MXTPU_FUSED_CONV_BWD", EnvSpec(False, "bool",
-     "Enable the experimental fused conv backward pallas kernel.")),
     ("MXNET_TUNE", EnvSpec(True, "bool",
      "Enable the kernel autotuner (tune.py): per-(kernel, shape, dtype, "
      "device) timed selection between hand Pallas kernels and the plain "

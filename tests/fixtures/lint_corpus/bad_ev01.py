@@ -1,6 +1,6 @@
 """EV01 corpus: raw environment reads of package knobs."""
 import os
 
-KERNEL = os.environ.get("MXTPU_CONV_BWD_KERNEL", "patch")
+MATMUL = os.environ.get("MXTPU_FP32_MATMUL", "strict")
 DEBUG = os.getenv("MXNET_DEBUG_FLAG")
 HOME = os.environ["MXNET_HOME"]

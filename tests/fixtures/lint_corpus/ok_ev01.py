@@ -4,5 +4,5 @@ import os
 
 from util import getenv_str
 
-KERNEL = getenv_str("MXTPU_CONV_BWD_KERNEL")
+MATMUL = getenv_str("MXTPU_FP32_MATMUL")
 PLATFORM = os.environ.get("JAX_PLATFORMS")  # not an MXNET_/MXTPU_ knob

@@ -265,8 +265,8 @@ def test_getenv_helpers_semantics(monkeypatch):
         assert util.getenv_bool("MXTPU_NO_NATIVE") is False
     monkeypatch.setenv("MXTPU_NO_NATIVE", "1")
     assert util.getenv_bool("MXTPU_NO_NATIVE") is True
-    monkeypatch.delenv("MXTPU_CONV_BWD_KERNEL", raising=False)
-    assert util.getenv_str("MXTPU_CONV_BWD_KERNEL") == "patch"
+    monkeypatch.delenv("MXTPU_FP32_MATMUL", raising=False)
+    assert util.getenv_str("MXTPU_FP32_MATMUL") == "strict"
     with pytest.raises(MXNetError):
         util.getenv_int("MXNET_NEVER_DECLARED")
     # the registry itself is complete: every entry has kind + doc

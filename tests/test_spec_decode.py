@@ -3,8 +3,8 @@ page rollback, chaos failover.
 
 Acceptance criteria from the speculative-decoding milestone:
   * the multi-query paged-attention read path is bit-compatible with
-    the single-query reference per query row and parity-tight in Pallas
-    interpret mode,
+    the single-query form per query row and matches a numpy oracle on
+    ragged lengths,
   * >= 16 concurrent ragged streams decoded speculatively are
     bit-identical to the plain continuous-decode oracle under greedy,
     with ZERO steady-state retraces of the verify executable,
@@ -35,8 +35,7 @@ import pytest
 from incubator_mxnet_tpu import profiler
 from incubator_mxnet_tpu.base import MXNetError
 from incubator_mxnet_tpu.parallel.paged_attention import (
-    paged_attention_mq_pallas, paged_attention_mq_reference,
-    paged_attention_reference)
+    paged_attention, paged_attention_multiquery)
 from incubator_mxnet_tpu.serve import (DecodePredictor, DecodeScheduler,
                                        PrefillEngine, Router, SpecDecoder)
 from incubator_mxnet_tpu.serve.stats import ServingStats
@@ -91,24 +90,66 @@ def _mq_inputs(seed=0, B=3, G=4, H=2, D=8, ps=4, P=16, max_pages=5):
 
 
 def test_mq_reference_matches_single_query_per_row():
-    """Each (b, g) query of the multi-query reference must equal the
-    single-query reference run on that row alone — bit-identical, since
+    """Each (b, g) query of the multi-query form must equal the
+    single-query form run on that row alone — bit-identical, since
     the verify executable's equivalence proof rests on it."""
     q, kp, vp, pt, sl = _mq_inputs()
-    got = np.asarray(paged_attention_mq_reference(q, kp, vp, pt, sl))
+    got = np.asarray(paged_attention_multiquery(q, kp, vp, pt, sl))
     for b in range(q.shape[0]):
         for g in range(q.shape[1]):
-            want = np.asarray(paged_attention_reference(
+            want = np.asarray(paged_attention(
                 q[b:b + 1, g], kp, vp, pt[b:b + 1], sl[b:b + 1, g]))
             np.testing.assert_array_equal(got[b, g], want[0])
 
 
-def test_mq_pallas_parity_interpret():
-    q, kp, vp, pt, sl = _mq_inputs(seed=1)
-    want = np.asarray(paged_attention_mq_reference(q, kp, vp, pt, sl))
-    got = np.asarray(paged_attention_mq_pallas(q, kp, vp, pt, sl,
-                                               interpret=True))
-    np.testing.assert_allclose(got, want, rtol=2e-5, atol=2e-5)
+def test_mq_matches_numpy_oracle_on_ragged_lengths_with_an_idle_slot():
+    """Dense float64 softmax attention walking the page indirection row by
+    row, per (sequence, query): ragged lengths inside a block, and a slot
+    whose every length is 0 (idle: clamped to one key, finite)."""
+    q, kp, vp, pt, sl = _mq_inputs(seed=2)
+    sl[1] = 0                                     # the idle slot
+    sl[0] = [1, 2, 19, 20]                        # both ends of the range
+    got = np.asarray(paged_attention_multiquery(q, kp, vp, pt, sl))
+    B, G, H, D = q.shape
+    ps = kp.shape[1]
+    want = np.zeros(q.shape, np.float64)
+    for b in range(B):
+        for g in range(G):
+            n = max(1, int(sl[b, g]))
+            rows = [pt[b, t // ps] * ps + t % ps for t in range(n)]
+            k = kp.reshape(-1, H, D)[rows].astype(np.float64)
+            v = vp.reshape(-1, H, D)[rows].astype(np.float64)
+            for h in range(H):
+                s = (q[b, g, h].astype(np.float64) / np.sqrt(D)) @ k[:, h].T
+                p = np.exp(s - s.max())
+                want[b, g, h] = (p / p.sum()) @ v[:, h]
+    assert np.isfinite(got).all()
+    np.testing.assert_allclose(got, want, rtol=1e-4, atol=1e-5)
+    # an idle slot reads exactly what a length of 1 reads
+    one = sl.copy()
+    one[1] = 1
+    np.testing.assert_array_equal(
+        got, np.asarray(paged_attention_multiquery(q, kp, vp, pt, one)))
+
+
+def test_verify_executable_traces_without_the_tuner(toy, monkeypatch):
+    """As the decode executable: THE verify executable's trace moves no
+    tuner counter and stages no kernel, candidates on offer or not."""
+    import jax
+    import jax.numpy as jnp
+    from incubator_mxnet_tpu import tune
+    monkeypatch.setenv("MXTPU_TUNE_INTERPRET", "1")
+    spec = SpecDecoder(toy, k=3)
+    i32 = jnp.int32
+    kv = jax.ShapeDtypeStruct((toy.num_pages, toy.page_size, toy.num_heads,
+                               toy.head_dim), jnp.float32)
+    sg = jax.ShapeDtypeStruct((toy.slots, spec.width), i32)
+    before = tune.stats()
+    jaxpr = str(jax.make_jaxpr(spec._make_verify())(
+        toy._param_vals, sg, sg, kv, kv,
+        jax.ShapeDtypeStruct((toy.slots, toy.max_pages_per_seq), i32)))
+    assert tune.stats() == before
+    assert "pallas_call" not in jaxpr and "gather" in jaxpr
 
 
 # -- SpecDecoder construction / warmup ---------------------------------

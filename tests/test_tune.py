@@ -331,11 +331,13 @@ import numpy as np
 from incubator_mxnet_tpu import nd, tune
 x = nd.array(np.ones((2, 8, 8, 8), np.float32))
 w = nd.array(np.ones((8, 8, 3, 3), np.float32))
-y = nd.Convolution(x, w, kernel=(3, 3), stride=(1, 1), pad=(1, 1),
-                   num_filter=8, no_bias=True)
+c = nd.array(np.ones((8,), np.float32))
+y = nd.FusedConvBNReLU(x, w, c, c, c, c, kernel=(3, 3), stride=(1, 1),
+                       pad=(1, 1), num_filter=8)[0]
 y.asnumpy()
 s = tune.stats()
-s["winner"] = tune.winner_for("conv3x3", x._data, w._data)
+s["winner"] = tune.winner_for("conv_bn_relu", x._data, w._data, c._data,
+                              c._data, k=3, pad_lo=(1, 1), pad_hi=(1, 1))
 print(json.dumps(s))
 """
 
@@ -534,3 +536,163 @@ def test_resnet_block_fused_path_matches_oracle(monkeypatch):
     for k in st_ref:
         np.testing.assert_allclose(st_fused[k], st_ref[k], rtol=1e-5,
                                    atol=1e-5, err_msg=k)
+
+
+# ---------------------------------------------------------------------------
+# where the race lives: never in a train step, on one device as on a mesh;
+# still in a forward pass that is the whole program
+# ---------------------------------------------------------------------------
+
+def _residual_net():
+    """conv + BatchNorm, then a residual block: with MXTPU_FUSED_BLOCK at
+    its default the forward pass goes through `bn_apply`, `bn_act` and
+    `bn_add_act` sites in training, `conv_bn_relu` besides in inference."""
+    from incubator_mxnet_tpu import gluon
+    from incubator_mxnet_tpu.gluon.model_zoo.vision.resnet import \
+        BasicBlockV1
+    net = gluon.nn.HybridSequential()
+    net.add(gluon.nn.Conv2D(8, 3, padding=1, use_bias=False, in_channels=3),
+            gluon.nn.BatchNorm(in_channels=8),
+            BasicBlockV1(channels=8, stride=1, in_channels=8),
+            gluon.nn.GlobalAvgPool2D(), gluon.nn.Dense(3, in_units=8))
+    net.initialize(mx.init.Xavier())
+    return net
+
+
+def _train_step(mesh_devices):
+    from incubator_mxnet_tpu.parallel import TrainStep, make_mesh
+    mesh = (None if mesh_devices is None else
+            make_mesh({"dp": mesh_devices}, jax.devices()[:mesh_devices]))
+
+    def loss_fn(out, label):
+        logp = jax.nn.log_softmax(out.astype(jnp.float32), -1)
+        return -jnp.mean(jnp.take_along_axis(
+            logp, label.astype(jnp.int32)[:, None], 1))
+
+    return TrainStep(_residual_net(), loss_fn, optimizer="sgd",
+                     optimizer_params={"learning_rate": 0.1, "momentum": 0.9},
+                     mesh=mesh, example_inputs=[nd.ones((8, 3, 8, 8))])
+
+
+_BATCH = (np.ones((8, 3, 8, 8), np.float32), np.zeros((8,), np.int32))
+
+
+@pytest.mark.parametrize("mesh_devices", [None, 1, 8],
+                         ids=["no-mesh", "one-device-mesh", "dp8"])
+def test_train_step_takes_xla_at_every_tuned_site(tune_dir, mesh_devices):
+    """One behaviour for a train step, whatever the chip count: no search,
+    no winner record, every tuned site counted under `withheld`."""
+    step = _train_step(mesh_devices)
+    tune.clear(memory=True, stats=True)     # construction's shape-only pass
+    loss = float(step(*_BATCH))
+    assert np.isfinite(loss)
+    s = tune.stats()
+    assert s["searches"] == 0 and s["withheld"] > 0, s
+    assert tune.winners() == {}
+
+
+def test_train_step_compiles_no_pallas_call_when_candidates_are_offered(
+        tune_dir, monkeypatch):
+    """With the Pallas candidates on offer (interpret mode, as on a chip),
+    the one-device step's program still holds none of them."""
+    monkeypatch.setenv("MXTPU_TUNE_INTERPRET", "1")
+    step = _train_step(None)
+    x, y = (jnp.asarray(a) for a in _BATCH)
+    jaxpr = jax.make_jaxpr(step._step_fn)(
+        step.params, step.opt_state, jax.random.PRNGKey(0), 0, x, y)
+    assert "pallas_call" not in str(jaxpr)
+    assert "conv_general_dilated" in str(jaxpr)
+    assert tune.stats()["searches"] == 0 and tune.winners() == {}
+
+
+def test_inference_pass_of_the_same_net_still_races(tune_dir, monkeypatch):
+    """Where a forward pass is the whole program the race stays: a jitted
+    inference pass times the Pallas candidates at its tuned sites."""
+    monkeypatch.setenv("MXTPU_TUNE_INTERPRET", "1")
+    net = _residual_net()
+    net.hybridize()
+    out = net(nd.array(_BATCH[0])).asnumpy()
+    assert np.all(np.isfinite(out))
+    s = tune.stats()
+    assert s["searches"] > 0 and s["withheld"] == 0, s
+    raced = {r["kernel"] for r in tune.winners().values()
+             if any(n.startswith("pallas") for n in r["timings_us"])}
+    assert "conv_bn_relu" in raced, tune.winners()
+
+
+# ---------------------------------------------------------------------------
+# a 3x3 s1 p1 Convolution is plain XLA: no tuned site under it
+# ---------------------------------------------------------------------------
+
+@pytest.mark.parametrize("shape", [(4, 8, 16, 8), (2, 24, 8, 16),
+                                   (3, 16, 7, 16)])
+def test_conv_3x3_is_xla_and_touches_no_tuner_counter(tune_dir, shape):
+    n, ci, h, co = shape
+    rng = np.random.RandomState(0)
+    x = jnp.asarray(rng.randn(n, ci, h, h).astype(np.float32))
+    w = jnp.asarray(rng.randn(co, ci, 3, 3).astype(np.float32) * 0.1)
+    go = jnp.asarray(rng.randn(n, co, h, h).astype(np.float32))
+
+    def plain(x_, w_):
+        return jax.lax.conv_general_dilated(
+            x_, w_, window_strides=(1, 1), padding=[(1, 1), (1, 1)],
+            dimension_numbers=("NCHW", "OIHW", "NCHW"),
+            preferred_element_type=jnp.float32)
+
+    def op(x_, w_):
+        return mx.ops.nn_ops.convolution.fn(
+            x_, w_, kernel=(3, 3), stride=(1, 1), pad=(1, 1), num_filter=co,
+            no_bias=True)
+
+    before = tune.stats()
+    out, vjp = jax.vjp(op, x, w)
+    dx, dw = vjp(go)
+    assert tune.stats() == before
+    want, vjp = jax.vjp(plain, x, w)
+    dxr, dwr = vjp(go)
+    for got, ref in ((out, want), (dx, dxr), (dw, dwr)):
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(ref))
+    # the same primitive with the same parameters, and nothing beside it
+    # but the op's trailing astype (a no-op in f32)
+    eqns = jax.make_jaxpr(op)(x, w).eqns
+    ref_eqn, = jax.make_jaxpr(plain)(x, w).eqns
+    convs = [e for e in eqns if e.primitive.name == "conv_general_dilated"]
+    assert len(convs) == 1 and convs[0].params == ref_eqn.params
+    assert {e.primitive.name for e in eqns} <= {"conv_general_dilated",
+                                                "convert_element_type"}
+
+
+def test_gluon_conv_net_trains_on_the_xla_path(tune_dir):
+    """A Conv2D net trains through autograd on the path that remains, and
+    its weight gradient is lax.conv_general_dilated's own."""
+    rng = np.random.RandomState(0)
+    xh = rng.rand(2, 4, 8, 8).astype(np.float32)
+    wh = rng.randn(4, 4, 3, 3).astype(np.float32) * 0.1
+    x, w = nd.array(xh), nd.array(wh)
+    w.attach_grad()
+    with autograd.record():
+        y = nd.Convolution(x, w, num_filter=4, kernel=(3, 3), pad=(1, 1),
+                           no_bias=True)
+        loss = (y * y).sum()
+    loss.backward()
+
+    def ref_loss(w_):
+        return jnp.sum(jnp.square(jax.lax.conv_general_dilated(
+            jnp.asarray(xh), w_, (1, 1), [(1, 1), (1, 1)],
+            dimension_numbers=("NCHW", "OIHW", "NCHW"),
+            preferred_element_type=jnp.float32)))
+    np.testing.assert_allclose(w.grad.asnumpy(),
+                               np.asarray(jax.grad(ref_loss)(jnp.asarray(wh))),
+                               rtol=1e-4, atol=1e-3)
+    assert tune.stats()["searches"] == 0
+
+
+@pytest.mark.parametrize("reader,name", [
+    ("getenv_bool", "MXTPU_FUSED_CONV_BWD"),
+    ("getenv_str", "MXTPU_CONV_BWD_KERNEL")])
+def test_the_conv_backward_knobs_are_undeclared(reader, name):
+    from incubator_mxnet_tpu import util
+    from incubator_mxnet_tpu.base import MXNetError
+    assert name not in util.ENV_VARS and len(util.ENV_VARS) == 82
+    with pytest.raises(MXNetError, match="is not declared"):
+        getattr(util, reader)(name)

@@ -94,7 +94,6 @@ def main():
         from incubator_mxnet_tpu import tune
         # importing the kernel providers registers their search spaces so
         # winners() can decode what the persistent store holds
-        from incubator_mxnet_tpu.parallel import conv_backward  # noqa: F401
         from incubator_mxnet_tpu.parallel import fused_conv  # noqa: F401
         s = tune.stats()
         print("counters     :",
