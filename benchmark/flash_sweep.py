@@ -1,18 +1,24 @@
 """Device time of the three flash kernels, causal, by sub-block edge.
 
     python benchmark/flash_sweep.py [--subs 0,128,256,512] [--calls 10]
-        [--shapes 512x1024,256x2048,64x8192] [--out _chip/flash_sweep]
+        [--shapes 32x16x1024,16x16x2048,4x16x8192] [--out _chip/flash_sweep]
 
-One line of JSON per (shape, edge): microseconds a call of `flash_fwd`,
-`flash_bwd_dq` and `flash_bwd_dkv` at (BH, T, 64) bf16, read from a device
-trace by kernel name (`perfbench/op_scopes.py`; host timing of a 2 ms kernel
-is noise). Edge 0 leaves the module as it is, which is also all that a tree
-from before PR 26 can run: unpack the parent beside this tree and run the
-same file there for its column. Needs a TPU; exits 2 without one. The edge is
-set on the module for the sweep only: it is no option of the program
-(PERF.md section 6, PR 26, has the table this printed).
+One line of JSON per (shape B x H x T, edge): microseconds a call of
+`flash_fwd`, `flash_bwd_dq` and `flash_bwd_dkv` on (B, T, H, 64) bf16, the
+layout the model hands `flash_attention`, and `other_us`: what else the
+device ran for one forward and one backward call, which is the copies that
+stand round the kernels (PR 33 took them away for shapes that pack two heads
+to a 128-lane block). All read from a device trace by kernel name
+(`perfbench/op_scopes.py`; host timing of a 2 ms kernel is noise). Edge 0
+leaves the module as it is. The file calls nothing but the public entry, so
+an older tree runs it too: unpack the parent beside this tree, copy this file
+over its own, and run it there for the parent's column. Needs a TPU; exits 2
+without one. The edge is set on the module for the sweep only: it is no
+option of the program (PERF.md section 6, PR 26 and PR 33, has the tables
+this printed).
 """
 import argparse
+import functools
 import importlib
 import json
 import os
@@ -22,16 +28,20 @@ ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, ROOT)
 
 
-def kernel_us(trace_dir, body):
-    """{kernel name: microseconds a call} of the custom calls body() ran."""
+def kernel_us(trace_dir, body, rounds):
+    """({kernel name: microseconds a call} of the custom calls body() ran,
+    microseconds a round of everything else it ran)."""
     from perfbench import op_scopes, spans
     from perfbench.trace_reduce import find_xplane
     spans.traced_slice(trace_dir, body)
-    out = {}
+    out, other = {}, 0.0
     for row in op_scopes.reduce(find_xplane(trace_dir))["rows"]:
         if row["category"] == "custom-call":
-            out[row["op"].split()[1].split(".")[0]] = 1e6 * row["seconds"] / row["calls"]
-    return out
+            out[row["op"].split()[1].split(".")[0]] = \
+                1e6 * row["seconds"] / row["calls"]
+        else:
+            other += 1e6 * row["seconds"] / rounds
+    return out, other
 
 
 def check(fa, jax, jnp, np, t):
@@ -60,7 +70,7 @@ def check(fa, jax, jnp, np, t):
 def main():
     ap = argparse.ArgumentParser()
     ap.add_argument("--subs", default="0")
-    ap.add_argument("--shapes", default="512x1024,256x2048,64x8192")
+    ap.add_argument("--shapes", default="32x16x1024,16x16x2048,4x16x8192")
     ap.add_argument("--calls", type=int, default=10)
     ap.add_argument("--out", default=os.path.join(ROOT, "_chip",
                                                   "flash_sweep"))
@@ -79,26 +89,28 @@ def main():
             fa._SUB = sub
         worst = max(check(fa, jax, jnp, np, t) for t in (1024, 2048))
         for shape in args.shapes.split(","):
-            bh, t = map(int, shape.split("x"))
-            block = fa._pick_block(t)
+            b, h, t = map(int, shape.split("x"))
             keys = jax.random.split(jax.random.PRNGKey(t), 4)
-            q, k, v, do = (jax.random.normal(key, (bh, t, 64), jnp.bfloat16)
-                           for key in keys)
-            fwd = jax.jit(lambda q, k, v: fa._fa_forward(
-                q, k, v, True, 0.125, block, block, False))
-            bwd = jax.jit(lambda q, k, v, do, lse, out: fa._fa_backward(
-                q, k, v, do, lse, out, jnp.zeros_like(lse), True, 0.125,
-                block, block, False))
-            out, lse = fwd(q, k, v)
-            jax.block_until_ready(bwd(q, k, v, do, lse, out))
+            q, k, v, do = (jax.random.normal(key, (b, t, h, 64),
+                                             jnp.bfloat16) for key in keys)
+            attn = functools.partial(fa.flash_attention, causal=True)
+            # one forward call, and one backward call from its residuals
+            fwd = jax.jit(lambda q, k, v: jax.vjp(attn, q, k, v))
+            bwd = jax.jit(lambda pull, do: pull(do))
+            pull = fwd(q, k, v)[1]
+            jax.block_until_ready(bwd(pull, do))
 
             def body():
                 for _ in range(args.calls):
-                    res = fwd(q, k, v), bwd(q, k, v, do, lse, out)
+                    res = bwd(fwd(q, k, v)[1], do)
                 jax.block_until_ready(res)
-            us = kernel_us(os.path.join(args.out, f"{shape}-{sub}"), body)
-            print(json.dumps({"bh": bh, "t": t, "sub": sub, "block": block,
-                              "check_rel_err": worst, "us_a_call": us}),
+            us, other = kernel_us(os.path.join(args.out, f"{shape}-{sub}"),
+                                  body, args.calls)
+            print(json.dumps({"b": b, "h": h, "t": t, "sub": sub,
+                              "block": fa._pick_block(t),
+                              "check_rel_err": worst, "us_a_call": us,
+                              "other_us": other,
+                              "dispatch": fa.dispatch_stats()}),
                   flush=True)
     return 0
 

@@ -55,7 +55,9 @@ FULL = {
 REHEARSAL = {
     "train": dict(network="resnet18_v1", batch_per_chip=4, image=32,
                   classes=10, dtype="float32", steps=5, lr=0.02),
-    "lm": dict(layers=2, d_model=64, heads=4, d_ff=128, vocab=128,
+    # heads of 64 as on the chip, two to a tp shard: the kernels' paired
+    # layout, with no transpose round them
+    "lm": dict(layers=2, d_model=256, heads=4, d_ff=512, vocab=128,
                seq=64, batch_per_replica=2, steps=4, check_seq=64),
     "serve": dict(network="resnet18_v1", image=32, classes=10,
                   buckets=(1, 4), heads=2, head_dim=8, vocab=64,
@@ -372,6 +374,8 @@ def leg_lm(cfg, rehearse, result_path):
           flush=True)
     assert took["pallas"] > 0 and took["reference"] == 0, \
         "flash attention dropped to attention_reference"
+    assert took["direct"] > 0 and took["transposed"] == 0, \
+        "the step's q, k, v went through a transpose on their way to flash"
     run, square = took["causal_subblocks_run"], took["causal_subblocks_all"]
     print(f"[smoke:lm] causal score sub-blocks computed: {run} of {square}",
           flush=True)

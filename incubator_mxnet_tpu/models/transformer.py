@@ -150,12 +150,15 @@ class TransformerLM:
         if sp_axis is not None:
             attn = ring_attention(q, kk, v, sp_axis, causal=True)
         elif self.cfg.flash_attention:
-            # emitting (BH,T,hd) straight from the projection einsums to
-            # skip the _to_bh copies was slower end to end: XLA's
-            # bhtk-output einsum costs more than the transposes it saves
-            # (their share of a step: PERF.md section 5). Keep the
-            # standard layout; flash_attention_bh stays for callers that
-            # already hold (BH,T,D).
+            # (B,T,H,hd) is what the kernels index: two 64-wide heads
+            # to a block of 128 lanes of the projections' own (B,T,D), so
+            # no copy stands round a call (flash_attention._direct; an
+            # odd local head count still goes through a transpose).
+            # Emitting (BH,T,hd) from the projection einsums instead,
+            # tried while the kernels wanted that layout, was 4.4% slower
+            # end to end than transposing: XLA's bhtk-output einsum cost
+            # more than the copies it saved. flash_attention_bh stays for
+            # callers that already hold (BH,T,D).
             from ..parallel.flash_attention import flash_attention
             attn_fn = functools.partial(flash_attention, causal=True)
             if mesh is not None:

@@ -3,10 +3,10 @@
 The hot op the reference implements as fused CUDA matmuls
 (src/operator/contrib/transformer.cc interleaved-matmul attention) —
 here a real blocked online-softmax kernel: one grid instance per
-(batch*head, q_block), K/V streamed block-by-block from VMEM with running
-(max, sumexp, acc) statistics, so the full (Tq, Tk) score matrix never
-materializes in HBM. O(T) memory instead of O(T^2), the standard
-flash-attention recurrence (Dao et al.; same math as
+(batch, block of heads, q_block), K/V streamed block-by-block from VMEM
+with running (max, sumexp, acc) statistics, so the full (Tq, Tk) score
+matrix never materializes in HBM. O(T) memory instead of O(T^2), the
+standard flash-attention recurrence (Dao et al.; same math as
 ring_attention._block_attn).
 
 Public entry `flash_attention(q, k, v, causal, sm_scale)` uses the
@@ -14,6 +14,13 @@ reference layout (B, T, H, D) and falls back to `attention_reference`
 when the shape doesn't tile (tiny heads / ragged lengths). Off-TPU the
 kernel runs in Pallas interpret mode, so the same code path is tested on
 the CPU mesh.
+
+The kernels index the caller's layout: over the free reshape (B, T, H*D)
+a block of 128 lanes holds 128 // D whole heads (two of 64), which a
+kernel takes one at a time, so no transpose or copy stands between the
+projections' matmuls and a call. Shapes that do not pack so (an odd head
+count, D = 80) are transposed to (B*H, T, D) and take the same kernels
+with one head a block (_direct).
 
 Causal calls skip the masked half twice: the grid skips the blocks above
 the diagonal, and a block ON the diagonal is walked in strips of 256 rows
@@ -26,10 +33,9 @@ emits the per-row log-sum-exp; `_fa_bwd_dq_kernel` streams k/v blocks
 accumulating dq, `_fa_bwd_dkv_kernel` streams q blocks accumulating
 dk/dv, both recomputing p from the saved lse with bf16 matmuls and f32
 accumulation. O(block * T) memory end to end, which is what makes
-LONG-CONTEXT TRAINING possible on one chip: T=8,192 trains at 8.0k tok/s
-and T=16,384 at 3.8k tok/s on v5e where the XLA attention path cannot
-even compile (docs/perf_notes.md). An XLA lax.scan fallback covers
-untileable shapes and the no-pallas path.
+LONG-CONTEXT TRAINING possible on one chip, where the XLA attention path
+cannot even compile at T = 8,192 (speeds: PERF.md). An XLA lax.scan
+fallback covers untileable shapes and the no-pallas path.
 """
 from __future__ import annotations
 
@@ -68,8 +74,10 @@ def pallas_available():
         return False
 
 
-# The kernels' bodies are written in lax, not jnp: a jnp function is a jit of
-# its own, and tracing one costs five times what binding the primitive does.
+# The kernels' bodies are written in lax, not jnp, down to the arithmetic on
+# the grid's indices: a jnp function, an operator on a traced value among
+# them, is a jit of its own, and tracing one costs five times what binding the
+# primitive does.
 # A train step traces and lowers three kernels a layer in every process, and
 # a ref load is the dearest thing to lower, so each kernel loads its blocks
 # once and cuts strips out of the values (PERF.md section 6, PR 26).
@@ -81,9 +89,11 @@ def _keep(shape, q_off, k_off, transposed=False):
     from jax import lax
     a = lax.broadcasted_iota(jnp.int32, shape, 0)
     b = lax.broadcasted_iota(jnp.int32, shape, 1)
-    if transposed:                       # rows are k, cols are q
-        return (q_off + b) >= (k_off + a)
-    return (q_off + a) >= (k_off + b)    # rows are q, cols are k
+    q, k = (b, a) if transposed else (a, b)   # which of them counts q rows
+    # a sub-block on the diagonal sits at the numbers (0, 0): no op to add
+    at = lambda x, off: x if isinstance(off, int) and off == 0 \
+        else lax.add(x, off)
+    return lax.ge(at(q, q_off), at(k, k_off))
 
 
 def _cut(x, rows=None, cols=None):
@@ -142,9 +152,73 @@ def _dot(a, b, contract, prec):
                            preferred_element_type=jnp.float32)
 
 
+# A kernel block is (rows, w) over arrays (N, T, heads * d): w lanes hold
+# w // d whole heads, side by side. The kernels take one head of the block at
+# a time, in a ROLLED loop (one copy of the body whatever w // d is: a body's
+# size is set-up, see _SUB), and tell the heads apart by lanes alone: an
+# operand with the other heads' lanes zeroed, contracted over all w lanes,
+# gives this head's exact product (the zeros add 0.0 in f32) in the time the
+# d-deep one takes, and a product w lanes wide holds every head's columns, of
+# which a select keeps this head's. No lane is sliced or shifted.
+
+def _lanes(h, d, shape):
+    """Mask, of `shape` (rows, w), of the lanes that head h of a block
+    holds: d of w. None where the block is one head."""
+    from jax import lax
+    if shape[1] == d:
+        return None
+    lane = lax.broadcasted_iota(jnp.int32, shape, 1)
+    # d divides 128 here: a power of two
+    return lax.eq(lax.shift_right_logical(lane, d.bit_length() - 1), h)
+
+
+def _scaled(x, scale, h, d):
+    """x (rows, w) times `scale`, rounded to x's dtype as the product of
+    two such numbers is, with zeros in the lanes that are not head h's."""
+    from jax import lax
+    import numpy as np
+    lanes = _lanes(h, d, (1, x.shape[1]))
+    if lanes is None:
+        return x if scale == 1.0 else lax.mul(x, np.asarray(scale, x.dtype))
+    # the select in f32: Mosaic has no relayout of a (1, w) mask for bf16
+    s = lax.full(lanes.shape, float(np.asarray(scale, x.dtype)), jnp.float32)
+    s = lax.convert_element_type(
+        lax.select(lanes, s, lax.full_like(s, 0)), x.dtype)
+    return lax.mul(x, lax.broadcast_in_dim(s, x.shape, (0, 1)))
+
+
+def _kept(val, h, d):
+    """val (rows, w) with zeros in the lanes that are not head h's."""
+    from jax import lax
+    lanes = _lanes(h, d, val.shape)
+    return val if lanes is None else \
+        lax.select(lanes, val, lax.full_like(val, 0))
+
+
+def _put(ref, at, val, h, d):
+    """Write head h's lanes of val (rows, w) to ref[at]; the other lanes
+    stay as they are."""
+    from jax import lax
+    lanes = _lanes(h, d, val.shape)
+    if lanes is not None:
+        val = lax.select(lanes, val,
+                         lax.convert_element_type(ref[at], val.dtype))
+    ref[at] = lax.convert_element_type(val, ref.dtype)
+
+
+def _each_head(n, head):
+    """head(h) for each of a block's n heads: a rolled loop over a traced
+    h, so that the body is traced and lowered once; plain head(0) for a
+    block of one head."""
+    from jax import lax
+    if n == 1:
+        head(0)
+    else:
+        lax.fori_loop(0, n, lambda h, _: head(h), None)
+
+
 # Edge of the score sub-blocks a grid block ON the diagonal is walked in, in
-# all three kernels; a multiple of 128 (it cuts the LANE dim of the
-# pre-transposed key). On the chip 128 runs the three kernels 6%, 3% and 7%
+# all three kernels. On the chip 128 runs the three kernels 6%, 3% and 7%
 # faster than 256 at (BH, T, D) = (512, 1024, 64), and costs twice the strips
 # to trace and lower in every process: at 24 layers that is 7 s of set-up for
 # 1.2% of a GPT-2 medium step (PERF.md section 6, PR 26).
@@ -208,15 +282,17 @@ def _causal_plan(tq, tk, block_q, block_k):
 def _causal_branches(plan, q_off, k_off, block_q, block_k, full, walk):
     """Run the one of `full(masked)` / `walk()` that the grid block at
     (q_off, k_off) takes; a block wholly above the diagonal runs none."""
+    from jax import lax
     from jax.experimental import pallas as pl
-    below = k_off + block_k - 1 <= q_off
-    diag = q_off == k_off
+    below = lax.le(lax.add(k_off, block_k - 1), q_off)
+    diag = lax.eq(q_off, k_off)
     if plan.below:
         pl.when(below)(lambda: full(False))
     pl.when(diag)(walk if plan.sub else (lambda: full(True)))
     if plan.straddle:
-        reach = k_off <= q_off + block_q - 1
-        pl.when(reach & ~below & ~diag)(lambda: full(True))
+        reach = lax.le(k_off, lax.add(q_off, block_q - 1))
+        pl.when(lax.bitwise_and(reach, lax.bitwise_not(
+            lax.bitwise_or(below, diag))))(lambda: full(True))
 
 
 def _fwd_fold(carry, s, v, prec):
@@ -239,64 +315,70 @@ def _fwd_fold(carry, s, v, prec):
     return m, l, acc
 
 
-def _fa_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *scratch, block_q,
+def _fa_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *scratch, d, block_q,
                block_k, plan, sm_scale):
-    """One (batch*head, q_block, kv_block) grid step. The kv axis is the
-    innermost ('arbitrary') grid dimension, so Pallas double-buffers the
-    K/V block DMAs while this step computes; running (max, sumexp, acc)
-    stats live in VMEM scratch that persists across kv steps. `plan` is
-    None for a non-causal call (every block one unmasked pass), else the
-    call's _causal_plan. Without scratch the grid step is a head's only
-    one and is walked in strips that each hold their rows' every score:
-    the statistics go straight to the output.
+    """One (row, lane block, q_block, kv_block) grid step. The kv axis is
+    the innermost ('arbitrary') grid dimension, so Pallas double-buffers
+    the K/V block DMAs while this step computes; running (max, sumexp,
+    acc) stats live in VMEM scratch that persists across kv steps. `plan`
+    is None for a non-causal call (every block one unmasked pass), else
+    the call's _causal_plan. Without scratch the grid step is its heads'
+    only one and is walked in strips that each hold their rows' every
+    score: the statistics go straight to the output.
 
-    Refs: q (1, block_q, d) | kt (1, d, block_k) | v (1, block_k, d)
-    | o (1, block_q, d); scratch m,l (block_q, 128) acc (block_q, d)."""
+    Refs, g = w // d heads a block: q (1, block_q, w) | k, v (1, block_k,
+    w) | o (1, block_q, w) | lse (g, block_q, 1); scratch m, l (g,
+    block_q, 1), acc (block_q, w)."""
     from jax import lax
     from jax.experimental import pallas as pl
 
-    j = pl.program_id(2)
-    n_k = pl.num_programs(2)
-    q_off = pl.program_id(1) * block_q
-    k_off = j * block_k
+    j = pl.program_id(3)
+    n_k = pl.num_programs(3)
+    q_off = lax.mul(pl.program_id(2), block_q)
+    k_off = lax.mul(j, block_k)
     prec = _prec(q_ref.dtype)
+    heads = q_ref.shape[2] // d
     if scratch:
         m_sc, l_sc, acc_sc = scratch
 
-        @pl.when(j == 0)
+        @pl.when(lax.eq(j, 0))
         def _init():
-            m_sc[:] = jnp.full_like(m_sc, _NEG_INF)
-            l_sc[:] = jnp.zeros_like(l_sc)
-            acc_sc[:] = jnp.zeros_like(acc_sc)
+            m_sc[:] = lax.full(m_sc.shape, _NEG_INF, m_sc.dtype)
+            l_sc[:] = lax.full(l_sc.shape, 0.0, l_sc.dtype)
+            acc_sc[:] = lax.full(acc_sc.shape, 0.0, acc_sc.dtype)
 
-    def emit(m, l, acc):
-        some = lax.select(l == 0.0, lax.full_like(l, 1.0), l)
+    def emit(h, m, l, acc):
+        none = lax.eq(l, 0.0)
+        some = lax.select(none, lax.full_like(l, 1.0), l)
         # fully-masked rows: zeros out, and a +inf-ish log-sum-exp so that
         # exp(s - lse) underflows to 0 in the backward kernels
-        o_ref[0] = (acc / _over(some, acc)).astype(o_ref.dtype)
-        lse_ref[0] = lax.select(l == 0.0, lax.full_like(l, 1e30),
-                                m + lax.log(some))
+        _put(o_ref, 0, lax.div(acc, _over(some, acc)), h, d)
+        lse_ref[h] = lax.select(none, lax.full_like(l, 1e30),
+                                lax.add(m, lax.log(some)))
 
     def fold(strips):
-        """Fold k columns `cols`, masked by `keep`, into the running stats
+        """Fold k rows `cols`, masked by `keep`, into the running stats
         of q rows `rows`, for each (rows, cols, keep) of `strips`, which
-        together hold every q row once: every strip's scores first, so
-        that no strip's first matmul queues behind another's second on
-        its MXU."""
-        q = q_ref[0] * jnp.asarray(sm_scale, q_ref.dtype)
-        kt, v = k_ref[0], v_ref[0]
-        old = (m_sc[:, :1], l_sc[:, :1], acc_sc[:]) if scratch else None
-        scores = [_dot(_cut(q, rows), _cut(kt, cols=cols), (1, 0), prec)
-                  for rows, cols, _ in strips]
-        new = [_fwd_fold(old and [_cut(x, rows) for x in old],
-                         s if keep is None else _masked(s, keep),
-                         _cut(v, cols), prec)
-               for (rows, cols, keep), s in zip(strips, scores)]
-        m, l, acc = (_stack(list(x)) for x in zip(*new))
-        if scratch:
-            m_sc[:, :1], l_sc[:, :1], acc_sc[:] = m, l, acc
-        else:
-            emit(m, l, acc)
+        together hold every q row once, head by head: every strip's scores
+        first, so that no strip's first matmul queues behind another's
+        second on its MXU."""
+        def head(h):
+            q = _scaled(q_ref[0], sm_scale, h, d)
+            k, v = k_ref[0], v_ref[0]
+            old = (m_sc[h], l_sc[h], acc_sc[:]) if scratch else None
+            scores = [_dot(_cut(q, rows), _cut(k, cols), (1, 1), prec)
+                      for rows, cols, _ in strips]
+            new = [_fwd_fold(old and [_cut(x, rows) for x in old],
+                             s if keep is None else _masked(s, keep),
+                             _cut(v, cols), prec)
+                   for (rows, cols, keep), s in zip(strips, scores)]
+            m, l, acc = (_stack(list(x)) for x in zip(*new))
+            if scratch:
+                m_sc[h], l_sc[h] = m, l
+                _put(acc_sc, slice(None), acc, h, d)
+            else:
+                emit(h, m, l, acc)
+        _each_head(heads, head)
 
     def full(masked):
         fold([((0, block_q), (0, block_k),
@@ -314,81 +396,112 @@ def _fa_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *scratch, block_q,
         _causal_branches(plan, q_off, k_off, block_q, block_k, full, walk)
 
     if scratch:
-        @pl.when(j == n_k - 1)
+        @pl.when(lax.eq(j, lax.sub(n_k, 1)))
         def _finish():
-            emit(m_sc[:, :1], l_sc[:, :1], acc_sc[:])
+            _each_head(heads,
+                       lambda h: emit(h, m_sc[h], l_sc[h], acc_sc[:]))
 
 
 def _compiler_params():
     from jax.experimental.pallas import tpu as pltpu
-    return pltpu.CompilerParams(
-        dimension_semantics=("parallel", "parallel", "arbitrary"))
+    return pltpu.CompilerParams(dimension_semantics=(
+        "parallel", "parallel", "parallel", "arbitrary"))
 
 
 def _interpret():
     return jax.default_backend() != "tpu"
 
 
-def _to_bh(x):
+# What the kernels take: arrays (N, T, C) whose rows hold C // d heads of d
+# lanes side by side, cut along C into blocks of _lane_block lanes; the row
+# vectors (lse, dlse, delta) as (N * C // d, T, 1) f32, a head a row. A caller
+# with (B, T, H, D) gets there by one of two routes, chosen from the shape
+# alone (_direct): its FREE reshape (B, T, H * D), where a 128-lane block
+# holds whole heads, so that no copy stands round a call; or the transpose
+# (B * H, T, D), one head a row, the block spanning it (a pass over the array
+# for each operand and each result).
+
+def _lane_block(c, d):
+    """Lanes of a block over rows of c = heads * d lanes: the row where it
+    is one head, else the fewest whole tiles of 128 lanes that are whole
+    heads."""
+    w = d if c == d or d % 128 == 0 else 128
+    assert w % d == 0 and c % w == 0, (c, d)
+    return w
+
+
+def _direct(heads, d):
+    """Whether (B, T, heads, d) reaches the kernels as it lies: blocks of
+    128 // d heads (a pair of 64-wide ones), or of one head whose d is a
+    multiple of 128."""
+    return d % 128 == 0 or (128 % d == 0 and heads % (128 // d) == 0)
+
+
+def _operand(x, direct):
+    """(B, T, H, D) as the kernels take it, by the route `direct` names."""
     B, T, H, D = x.shape
-    return x.transpose(0, 2, 1, 3).reshape(B * H, T, D)
+    return x.reshape(B, T, H * D) if direct \
+        else x.transpose(0, 2, 1, 3).reshape(B * H, T, D)
 
 
-def _un_bh(x, B, H, T, D):
-    return x.reshape(B, H, T, D).transpose(0, 2, 1, 3)
+def _result(x, like, direct):
+    """A kernel's (N, T, C) result in the layout of the caller's `like`."""
+    B, T, H, D = like.shape
+    return x.reshape(B, T, H, D) if direct \
+        else x.reshape(B, H, T, D).transpose(0, 2, 1, 3)
 
 
-def _fa_forward(q, k, v, causal, sm_scale, block_q, block_k, interpret):
-    """q,k,v: (BH, T, D). Returns (out, lse) with lse the per-row
-    log-sum-exp (BH, T, 1) f32 the backward kernels consume."""
+def _fa_forward(q, k, v, d, causal, sm_scale, block_q, block_k, interpret):
+    """q, k, v: (N, T, C), rows of C // d heads. Returns (out, lse) with lse
+    the per-row log-sum-exp (N * C // d, T, 1) f32 the backward kernels
+    consume."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
-    bh, tq, d = q.shape
+    n, tq, c = q.shape
     tk = k.shape[1]
-    kt = k.transpose(0, 2, 1)   # (BH, D, Tk) for the kernel's matmul
-    grid = (bh, tq // block_q, tk // block_k)
+    w = _lane_block(c, d)
+    g, n_p = w // d, c // w
+    grid = (n, n_p, tq // block_q, tk // block_k)
     plan = _causal_plan(tq, tk, block_q, block_k) if causal else None
     if causal:
         with _dispatch_lock:
             _dispatch["causal_subblocks_run"] += plan.run
             _dispatch["causal_subblocks_all"] += plan.all
-    kern = functools.partial(_fa_kernel, block_q=block_q, block_k=block_k,
-                             plan=plan, sm_scale=sm_scale)
-    # a head that is one grid block walked in strips carries no running
+    kern = functools.partial(_fa_kernel, d=d, block_q=block_q,
+                             block_k=block_k, plan=plan, sm_scale=sm_scale)
+    # heads that are one grid block walked in strips carry no running
     # statistics from one kv step to the next: no scratch
-    alone = causal and plan.sub and grid[1:] == (1, 1)
-    params = _compiler_params()
+    alone = causal and plan.sub and grid[2:] == (1, 1)
+    of_q = pl.BlockSpec((1, block_q, w), lambda b, p, i, j: (b, i, p))
+    of_k = pl.BlockSpec((1, block_k, w), lambda b, p, i, j: (b, j, p))
     return pl.pallas_call(
         kern,
         grid=grid,
-        in_specs=[
-            pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0)),
-            pl.BlockSpec((1, d, block_k), lambda b, i, j: (b, 0, j)),
-            pl.BlockSpec((1, block_k, d), lambda b, i, j: (b, j, 0)),
-        ],
+        in_specs=[of_q, of_k, of_k],
         out_specs=[
-            pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0)),
+            of_q,
             # trailing singleton: TPU block rules need the last two dims
             # (block, 1) == (divisible-by-8, full-dim)
-            pl.BlockSpec((1, block_q, 1), lambda b, i, j: (b, i, 0)),
+            pl.BlockSpec((g, block_q, 1),
+                         lambda b, p, i, j: (b * n_p + p, i, 0)),
         ],
-        out_shape=[jax.ShapeDtypeStruct((bh, tq, d), q.dtype),
-                   jax.ShapeDtypeStruct((bh, tq, 1), jnp.float32)],
+        out_shape=[jax.ShapeDtypeStruct((n, tq, c), q.dtype),
+                   jax.ShapeDtypeStruct((n * c // d, tq, 1), jnp.float32)],
         scratch_shapes=[] if alone else [
-            pltpu.VMEM((block_q, 128), jnp.float32),   # running max
-            pltpu.VMEM((block_q, 128), jnp.float32),   # running sumexp
-            pltpu.VMEM((block_q, d), jnp.float32),     # output accumulator
+            pltpu.VMEM((g, block_q, 1), jnp.float32),  # running max
+            pltpu.VMEM((g, block_q, 1), jnp.float32),  # running sumexp
+            pltpu.VMEM((block_q, w), jnp.float32),     # output accumulator
         ],
-        compiler_params=params,
+        compiler_params=_compiler_params(),
         interpret=interpret,
         name="flash_fwd",
-    )(q, kt, v)
+    )(q, k, v)
 
 
 def _fa_bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, out_ref,
-                      dlse_ref, dq_ref, delta_ref, acc_sc, delta_sc, *,
-                      block_q, block_k, plan, sm_scale):
+                      dlse_ref, dq_ref, delta_ref, acc_sc, *, d, block_q,
+                      block_k, plan, sm_scale):
     """dq for one q block, streaming k/v blocks (innermost grid dim):
       delta = rowsum(dO * O) - dlse   (computed HERE at j==0 — fused, so
                                  no separate XLA pass re-reads dO and O;
@@ -399,50 +512,60 @@ def _fa_bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, out_ref,
                                  ring-attention merge consumes lse.)
       p  = exp(s*scale - lse);  dp = dO V^T
       ds = p * (dp - delta);    dq = scale * sum_k ds K
-    Matmuls keep input-dtype operands with f32 accumulation. delta is
-    also emitted as an output for the dk/dv kernel to consume. `plan` as
-    in _fa_kernel."""
+    Matmuls keep input-dtype operands with f32 accumulation. delta is an
+    output, for the dk/dv kernel to consume; its block stays where it is
+    over the kv steps, which read it back. `plan` and the refs as in
+    _fa_kernel; the row vectors are (g, block_q, 1), a head each."""
     from jax import lax
     from jax.experimental import pallas as pl
 
-    j = pl.program_id(2)
-    n_k = pl.num_programs(2)
-    q_off = pl.program_id(1) * block_q
-    k_off = j * block_k
+    j = pl.program_id(3)
+    n_k = pl.num_programs(3)
+    q_off = lax.mul(pl.program_id(2), block_q)
+    k_off = lax.mul(j, block_k)
     prec = _prec(q_ref.dtype)
+    heads = q_ref.shape[2] // d
+    f32 = functools.partial(lax.convert_element_type,
+                            new_dtype=jnp.float32)
 
-    @pl.when(j == 0)
+    @pl.when(lax.eq(j, 0))
     def _init():
-        acc_sc[:] = jnp.zeros_like(acc_sc)
-        d = jnp.sum(do_ref[0].astype(jnp.float32)
-                    * out_ref[0].astype(jnp.float32), axis=-1,
-                    keepdims=True) - dlse_ref[0]
-        delta_sc[:] = jnp.broadcast_to(d, delta_sc.shape)
-        delta_ref[0] = d
+        acc_sc[:] = lax.full(acc_sc.shape, 0.0, acc_sc.dtype)
+
+        def head(h):
+            both = _kept(lax.mul(f32(do_ref[0]), f32(out_ref[0])), h, d)
+            delta_ref[h] = lax.sub(
+                lax.expand_dims(lax.reduce_sum(both, (1,)), (1,)),
+                dlse_ref[h])
+        _each_head(heads, head)
 
     def add(strips):
         """Add to the dq of q rows `rows` what k rows `cols`, masked by
         `keep`, give, for each (rows, cols, keep) of `strips`, which
-        together hold every q row once: every strip's two score matmuls
-        first, so that none queues behind another strip's last."""
-        # scale q in the INPUT dtype before the dot, exactly like the
-        # forward — a post-dot f32 scale would recompute a subtly
-        # different s than the one that produced the saved lse
-        qs = q_ref[0] * jnp.asarray(sm_scale, q_ref.dtype)
-        k, v, do = k_ref[0], v_ref[0], do_ref[0]
-        lse, delta = lse_ref[0], delta_sc[:, :1]
-        first = [(_dot(_cut(qs, rows), _cut(k, cols), (1, 1), prec),
-                  _dot(_cut(do, rows), _cut(v, cols), (1, 1), prec))
-                 for rows, cols, _ in strips]
-        parts = []
-        for (rows, cols, keep), (s, dp) in zip(strips, first):
-            if keep is not None:
-                s = _masked(s, keep)
-            ds = lax.mul(lax.exp(lax.sub(s, _over(_cut(lse, rows), s))),
-                         lax.sub(dp, _over(_cut(delta, rows), dp)))
-            parts.append(_dot(lax.convert_element_type(ds, k.dtype),
-                              _cut(k, cols), (1, 0), prec))
-        acc_sc[:] += _stack(parts)
+        together hold every q row once, head by head: every strip's two
+        score matmuls first, so that none queues behind another strip's
+        last."""
+        def head(h):
+            # scale q in the INPUT dtype before the dot, exactly like the
+            # forward — a post-dot f32 scale would recompute a subtly
+            # different s than the one that produced the saved lse
+            qs = _scaled(q_ref[0], sm_scale, h, d)
+            do = _scaled(do_ref[0], 1.0, h, d)
+            k, v = k_ref[0], v_ref[0]
+            lse, delta = lse_ref[h], delta_ref[h]
+            first = [(_dot(_cut(qs, rows), _cut(k, cols), (1, 1), prec),
+                      _dot(_cut(do, rows), _cut(v, cols), (1, 1), prec))
+                     for rows, cols, _ in strips]
+            parts = []
+            for (rows, cols, keep), (s, dp) in zip(strips, first):
+                if keep is not None:
+                    s = _masked(s, keep)
+                ds = lax.mul(lax.exp(lax.sub(s, _over(_cut(lse, rows), s))),
+                             lax.sub(dp, _over(_cut(delta, rows), dp)))
+                parts.append(_dot(lax.convert_element_type(ds, k.dtype),
+                                  _cut(k, cols), (1, 0), prec))
+            acc_sc[:] = lax.add(acc_sc[:], _kept(_stack(parts), h, d))
+        _each_head(heads, head)
 
     def full(masked):
         add([((0, block_q), (0, block_k),
@@ -459,58 +582,67 @@ def _fa_bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, out_ref,
     else:
         _causal_branches(plan, q_off, k_off, block_q, block_k, full, walk)
 
-    @pl.when(j == n_k - 1)
+    @pl.when(lax.eq(j, lax.sub(n_k, 1)))
     def _finish():
-        dq_ref[0] = (acc_sc[:] * sm_scale).astype(dq_ref.dtype)
+        dq_ref[0] = lax.convert_element_type(lax.mul(acc_sc[:], sm_scale),
+                                             dq_ref.dtype)
 
 
 def _fa_bwd_dkv_kernel(k_ref, v_ref, q_ref, do_ref, lse_ref, delta_ref,
-                       dk_ref, dv_ref, dk_sc, dv_sc, *, block_q, block_k,
+                       dk_ref, dv_ref, dk_sc, dv_sc, *, d, block_q, block_k,
                        plan, sm_scale):
     """dk/dv for one k block, streaming q blocks (innermost grid dim):
       p^T  = exp(s^T*scale - lse);     dv = sum_q p^T dO
       ds^T = p^T * (dp^T - delta);     dk = scale * sum_q ds^T Q
-    `plan` as in _fa_kernel; the walk goes by k sub-block here, over the
-    q sub-blocks at and below the diagonal."""
+    `plan` and the refs as in _fa_kernel; the walk goes by k sub-block
+    here, over the q sub-blocks at and below the diagonal."""
     from jax import lax
     from jax.experimental import pallas as pl
 
-    i = pl.program_id(2)
-    n_q = pl.num_programs(2)
-    k_off = pl.program_id(1) * block_k
-    q_off = i * block_q
+    i = pl.program_id(3)
+    n_q = pl.num_programs(3)
+    k_off = lax.mul(pl.program_id(2), block_k)
+    q_off = lax.mul(i, block_q)
     prec = _prec(q_ref.dtype)
+    heads = q_ref.shape[2] // d
 
-    @pl.when(i == 0)
+    @pl.when(lax.eq(i, 0))
     def _init():
-        dk_sc[:] = jnp.zeros_like(dk_sc)
-        dv_sc[:] = jnp.zeros_like(dv_sc)
+        dk_sc[:] = lax.full(dk_sc.shape, 0.0, dk_sc.dtype)
+        dv_sc[:] = lax.full(dv_sc.shape, 0.0, dv_sc.dtype)
 
     def add(strips):
         """Add to the dk, dv of k rows `cols` what q rows `rows`, masked by
         `keep`, give, for each (cols, rows, keep) of `strips`, in
-        transposed scores (k rows, q rows); the score matmuls first, as in
-        the dq kernel. Strips that leave k rows out leave their dk, dv as
-        they are."""
-        q, do, k, v = q_ref[0], do_ref[0], k_ref[0], v_ref[0]
-        qs = q * jnp.asarray(sm_scale, q.dtype)      # as the forward
-        lse, delta = lse_ref[0], delta_ref[0]
-        first = [(_dot(_cut(k, cols), _cut(qs, rows), (1, 1), prec),
-                  _dot(_cut(v, cols), _cut(do, rows), (1, 1), prec))
-                 for cols, rows, _ in strips]
-        dks, dvs = [], []
-        for (cols, rows, keep), (st, dpt) in zip(strips, first):
-            if keep is not None:
-                st = _masked(st, keep, lead=True)
-            pt = lax.exp(lax.sub(st, _under(_cut(lse, rows), st)))
-            dvs.append(_dot(lax.convert_element_type(pt, do.dtype),
-                            _cut(do, rows), (1, 0), prec))
-            dst = lax.mul(pt, lax.sub(dpt, _under(_cut(delta, rows), dpt)))
-            dks.append(_dot(lax.convert_element_type(dst, q.dtype),
-                            _cut(q, rows), (1, 0), prec))
-        done = strips[-1][0][1]       # the strips' k rows run from 0 on
-        dk_sc[:done, :] += _stack(dks)
-        dv_sc[:done, :] += _stack(dvs)
+        transposed scores (k rows, q rows), head by head; the score matmuls
+        first, as in the dq kernel. Strips that leave k rows out leave
+        their dk, dv as they are."""
+        def head(h):
+            q, k, v = q_ref[0], k_ref[0], v_ref[0]
+            qs = _scaled(q, sm_scale, h, d)             # as the forward
+            do = _scaled(do_ref[0], 1.0, h, d)
+            lse, delta = lse_ref[h], delta_ref[h]
+            first = [(_dot(_cut(k, cols), _cut(qs, rows), (1, 1), prec),
+                      _dot(_cut(v, cols), _cut(do, rows), (1, 1), prec))
+                     for cols, rows, _ in strips]
+            dks, dvs = [], []
+            for (cols, rows, keep), (st, dpt) in zip(strips, first):
+                if keep is not None:
+                    st = _masked(st, keep, lead=True)
+                pt = lax.exp(lax.sub(st, _under(_cut(lse, rows), st)))
+                # dO has this head's lanes alone, so pt . dO leaves the
+                # other heads' dv as it is; q has them all
+                dvs.append(_dot(lax.convert_element_type(pt, do.dtype),
+                                _cut(do, rows), (1, 0), prec))
+                dst = lax.mul(pt, lax.sub(dpt,
+                                          _under(_cut(delta, rows), dpt)))
+                dks.append(_dot(lax.convert_element_type(dst, q.dtype),
+                                _cut(q, rows), (1, 0), prec))
+            done = strips[-1][0][1]   # the strips' k rows run from 0 on
+            dk_sc[:done, :] = lax.add(dk_sc[:done, :],
+                                      _kept(_stack(dks), h, d))
+            dv_sc[:done, :] = lax.add(dv_sc[:done, :], _stack(dvs))
+        _each_head(heads, head)
 
     def full(masked):
         add([((0, block_k), (0, block_q),
@@ -530,74 +662,65 @@ def _fa_bwd_dkv_kernel(k_ref, v_ref, q_ref, do_ref, lse_ref, delta_ref,
     else:
         _causal_branches(plan, q_off, k_off, block_q, block_k, full, walk)
 
-    @pl.when(i == n_q - 1)
+    @pl.when(lax.eq(i, lax.sub(n_q, 1)))
     def _finish():
-        dk_ref[0] = (dk_sc[:] * sm_scale).astype(dk_ref.dtype)
-        dv_ref[0] = dv_sc[:].astype(dv_ref.dtype)
+        dk_ref[0] = lax.convert_element_type(lax.mul(dk_sc[:], sm_scale),
+                                             dk_ref.dtype)
+        dv_ref[0] = lax.convert_element_type(dv_sc[:], dv_ref.dtype)
 
 
-def _fa_backward(q, k, v, do, lse, out, dlse, causal, sm_scale, block_q,
+def _fa_backward(q, k, v, do, lse, out, dlse, d, causal, sm_scale, block_q,
                  block_k, interpret):
-    """q,k,v,do,out: (BH, T, D); lse: (BH, Tq, 1) f32. Returns
-    (dq, dk, dv) via the two flash backward kernels — O(block * T)
-    memory, scores recomputed from the saved lse. delta = rowsum(dO*O)
-    is computed INSIDE the dq kernel (per q block, at its first kv step)
-    and handed to the dk/dv kernel as a (BH, Tq, 1) output — one fewer
-    full pass over dO and O than a separate XLA delta computation."""
+    """q, k, v, do, out: (N, T, C), rows of C // d heads; lse, dlse:
+    (N * C // d, Tq, 1) f32. Returns (dq, dk, dv) via the two flash
+    backward kernels — O(block * T) memory, scores recomputed from the
+    saved lse. delta = rowsum(dO*O) is computed INSIDE the dq kernel (per
+    q block, at its first kv step) and handed to the dk/dv kernel as an
+    output shaped like lse — one fewer full pass over dO and O than a
+    separate XLA delta computation."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
-    bh, tq, d = q.shape
+    n, tq, c = q.shape
     tk = k.shape[1]
+    w = _lane_block(c, d)
+    g, n_p = w // d, c // w
     params = _compiler_params()
     plan = _causal_plan(tq, tk, block_q, block_k) if causal else None
+    row = jax.ShapeDtypeStruct((n * c // d, tq, 1), jnp.float32)
 
+    of_q = pl.BlockSpec((1, block_q, w), lambda b, p, i, j: (b, i, p))
+    of_k = pl.BlockSpec((1, block_k, w), lambda b, p, i, j: (b, j, p))
+    of_row = pl.BlockSpec((g, block_q, 1),
+                          lambda b, p, i, j: (b * n_p + p, i, 0))
     dq, delta = pl.pallas_call(
-        functools.partial(_fa_bwd_dq_kernel, block_q=block_q,
+        functools.partial(_fa_bwd_dq_kernel, d=d, block_q=block_q,
                           block_k=block_k, plan=plan, sm_scale=sm_scale),
-        grid=(bh, tq // block_q, tk // block_k),
-        in_specs=[
-            pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0)),
-            pl.BlockSpec((1, block_k, d), lambda b, i, j: (b, j, 0)),
-            pl.BlockSpec((1, block_k, d), lambda b, i, j: (b, j, 0)),
-            pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0)),
-            pl.BlockSpec((1, block_q, 1), lambda b, i, j: (b, i, 0)),
-            pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0)),
-            pl.BlockSpec((1, block_q, 1), lambda b, i, j: (b, i, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0)),
-            pl.BlockSpec((1, block_q, 1), lambda b, i, j: (b, i, 0)),
-        ],
-        out_shape=[jax.ShapeDtypeStruct((bh, tq, d), q.dtype),
-                   jax.ShapeDtypeStruct((bh, tq, 1), jnp.float32)],
-        scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32),
-                        pltpu.VMEM((block_q, 128), jnp.float32)],
+        grid=(n, n_p, tq // block_q, tk // block_k),
+        in_specs=[of_q, of_k, of_k, of_q, of_row, of_q, of_row],
+        out_specs=[of_q, of_row],
+        out_shape=[jax.ShapeDtypeStruct((n, tq, c), q.dtype), row],
+        scratch_shapes=[pltpu.VMEM((block_q, w), jnp.float32)],
         compiler_params=params,
         interpret=interpret,
         name="flash_bwd_dq",
     )(q, k, v, do, lse, out, dlse)
 
+    # the grid's last two axes swap: k blocks outside, q blocks inside
+    of_q = pl.BlockSpec((1, block_q, w), lambda b, p, j, i: (b, i, p))
+    of_k = pl.BlockSpec((1, block_k, w), lambda b, p, j, i: (b, j, p))
+    of_row = pl.BlockSpec((g, block_q, 1),
+                          lambda b, p, j, i: (b * n_p + p, i, 0))
     dk, dv = pl.pallas_call(
-        functools.partial(_fa_bwd_dkv_kernel, block_q=block_q,
+        functools.partial(_fa_bwd_dkv_kernel, d=d, block_q=block_q,
                           block_k=block_k, plan=plan, sm_scale=sm_scale),
-        grid=(bh, tk // block_k, tq // block_q),
-        in_specs=[
-            pl.BlockSpec((1, block_k, d), lambda b, j, i: (b, j, 0)),
-            pl.BlockSpec((1, block_k, d), lambda b, j, i: (b, j, 0)),
-            pl.BlockSpec((1, block_q, d), lambda b, j, i: (b, i, 0)),
-            pl.BlockSpec((1, block_q, d), lambda b, j, i: (b, i, 0)),
-            pl.BlockSpec((1, block_q, 1), lambda b, j, i: (b, i, 0)),
-            pl.BlockSpec((1, block_q, 1), lambda b, j, i: (b, i, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, block_k, d), lambda b, j, i: (b, j, 0)),
-            pl.BlockSpec((1, block_k, d), lambda b, j, i: (b, j, 0)),
-        ],
-        out_shape=[jax.ShapeDtypeStruct((bh, tk, d), k.dtype),
-                   jax.ShapeDtypeStruct((bh, tk, d), v.dtype)],
-        scratch_shapes=[pltpu.VMEM((block_k, d), jnp.float32),
-                        pltpu.VMEM((block_k, d), jnp.float32)],
+        grid=(n, n_p, tk // block_k, tq // block_q),
+        in_specs=[of_k, of_k, of_q, of_q, of_row, of_row],
+        out_specs=[of_k, of_k],
+        out_shape=[jax.ShapeDtypeStruct((n, tk, c), k.dtype),
+                   jax.ShapeDtypeStruct((n, tk, c), v.dtype)],
+        scratch_shapes=[pltpu.VMEM((block_k, w), jnp.float32),
+                        pltpu.VMEM((block_k, w), jnp.float32)],
         compiler_params=params,
         interpret=interpret,
         name="flash_bwd_dkv",
@@ -608,10 +731,10 @@ def _fa_backward(q, k, v, do, lse, out, dlse, causal, sm_scale, block_q,
 def _pick_block(t, preferred=1024):
     """Kernel block for an axis of length `t`, under the TPU tiling rule:
     the last two dims of every block are multiples of (8, 128) or span
-    the array. The k block is the LANE dim of the pre-transposed key, so
-    a partial block is a multiple of 128; an axis that fits in
-    `preferred` is taken whole (rows in multiples of 8). None = no legal
-    block, the caller takes the XLA path.
+    the array. A block's rows are q's or k's, and the lanes of the score
+    block they make, so a partial block is a multiple of 128; an axis that
+    fits in `preferred` is taken whole (rows in multiples of 8). None = no
+    legal block, the caller takes the XLA path.
 
     1024 was tuned on the chip at T = 2,048 and 8,192, where the grid skips
     the blocks above the diagonal. At T <= 1,024 a head is ONE grid block
@@ -631,7 +754,7 @@ def _pick_block(t, preferred=1024):
 # Which implementation each traced call got, by reason. Attention drops
 # to the O(T^2) XLA reference when no block fits; that must be a choice
 # somebody can see, not a silent one (chip_smoke.py asserts on it).
-_dispatch = {"pallas": 0, "reference": 0,
+_dispatch = {"pallas": 0, "reference": 0, "direct": 0, "transposed": 0,
              "causal_subblocks_run": 0, "causal_subblocks_all": 0}
 _dispatch_lock = threading.Lock()
 
@@ -639,6 +762,9 @@ _dispatch_lock = threading.Lock()
 def dispatch_stats():
     """{"pallas": n, "reference": n}: traced flash_attention/flash_hop
     calls served by the Pallas kernels vs dropped to attention_reference;
+    of the former, "direct": those whose operands reached the kernels in
+    the caller's layout, and "transposed": those whose operands were
+    transposed to (B*H, T, D) first (_direct);
     "causal_subblocks_run" of "causal_subblocks_all": over the traced
     CAUSAL forward kernel calls, the score sub-blocks one head computes
     and those in its (Tq, Tk) square (_causal_plan; the backward pair
@@ -664,6 +790,14 @@ def _blocks_for(tq, tk, d):
     return bq, bk
 
 
+def _route(heads, d):
+    """_direct(heads, d), counted: once for each traced forward call."""
+    direct = _direct(heads, d)
+    with _dispatch_lock:
+        _dispatch["direct" if direct else "transposed"] += 1
+    return direct
+
+
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
 def _flash(q, k, v, causal, sm_scale):
     return _flash_fwd_impl(q, k, v, causal, sm_scale)
@@ -684,9 +818,11 @@ def _flash_fwd_impl(q, k, v, causal, sm_scale, want_lse=False):
                                   sm_scale=sm_scale)
         return (out, None) if want_lse else out
     bq, bk = blocks
-    out, lse = _fa_forward(_to_bh(q), _to_bh(k), _to_bh(v), causal,
-                           sm_scale, bq, bk, _interpret())
-    out = _un_bh(out, B, H, Tq, D)
+    direct = _route(H, D)
+    out, lse = _fa_forward(_operand(q, direct), _operand(k, direct),
+                           _operand(v, direct), D, causal, sm_scale, bq, bk,
+                           _interpret())
+    out = _result(out, q, direct)
     return (out, lse) if want_lse else out
 
 
@@ -713,13 +849,13 @@ def _flash_vjp_bwd(causal, sm_scale, res, g):
         # old (512,512); below T=2048 see _pick_block
         bq = _pick_block(Tq)
         bk = _pick_block(Tk)
-        do_bh = _to_bh(g)
-        dq, dk, dv = _fa_backward(_to_bh(q), _to_bh(k), _to_bh(v), do_bh,
-                                  lse, _to_bh(out),
-                                  jnp.zeros_like(lse), causal, sm_scale,
-                                  bq, bk, _interpret())
-        return (_un_bh(dq, B, H, Tq, D), _un_bh(dk, B, H, Tk, D),
-                _un_bh(dv, B, H, Tk, D))
+        direct = _direct(H, D)
+        dq, dk, dv = _fa_backward(
+            *(_operand(x, direct) for x in (q, k, v, g)), lse,
+            _operand(out, direct), jnp.zeros_like(lse), D, causal, sm_scale,
+            bq, bk, _interpret())
+        return (_result(dq, q, direct), _result(dk, k, direct),
+                _result(dv, v, direct))
     bq = _pick_block(Tq, 256)
     if bq is None or bq == Tq:
         # tiny/ragged: dense vjp of the reference is fine at this size
@@ -791,11 +927,13 @@ def _flash_hop_fwd_impl(q, k, v, causal, sm_scale):
     B, T, H, D = q.shape
     bq = _pick_block(T)
     bk = _pick_block(k.shape[1])
-    out, lse = _fa_forward(_to_bh(q), _to_bh(k), _to_bh(v), causal,
-                           sm_scale, bq, bk, _interpret())
+    direct = _route(H, D)
+    out, lse = _fa_forward(_operand(q, direct), _operand(k, direct),
+                           _operand(v, direct), D, causal, sm_scale, bq, bk,
+                           _interpret())
     lse_bht = lse.reshape(B, H, T)
     lse_bht = jnp.where(lse_bht >= 1e29, -jnp.inf, lse_bht)
-    return (_un_bh(out, B, H, T, D).astype(jnp.float32), lse_bht)
+    return (_result(out, q, direct).astype(jnp.float32), lse_bht)
 
 
 def _flash_hop_vjp_fwd(q, k, v, causal, sm_scale):
@@ -813,13 +951,14 @@ def _flash_hop_vjp_bwd(causal, sm_scale, res, cts):
     lse_kern = jnp.where(jnp.isfinite(lse), lse, 1e30).reshape(
         B * H, Tq, 1).astype(jnp.float32)
     dlse = g_lse.reshape(B * H, Tq, 1).astype(jnp.float32)
+    direct = _direct(H, D)
     dq, dk, dv = _fa_backward(
-        _to_bh(q), _to_bh(k), _to_bh(v), _to_bh(g_out.astype(q.dtype)),
-        lse_kern, _to_bh(out.astype(q.dtype)), dlse, causal, sm_scale,
-        bq, bk, _interpret())
-    return (_un_bh(dq, B, H, Tq, D).astype(q.dtype),
-            _un_bh(dk, B, H, Tk, D).astype(k.dtype),
-            _un_bh(dv, B, H, Tk, D).astype(v.dtype))
+        *(_operand(x, direct) for x in (q, k, v, g_out.astype(q.dtype))),
+        lse_kern, _operand(out.astype(q.dtype), direct), dlse, D, causal,
+        sm_scale, bq, bk, _interpret())
+    return (_result(dq, q, direct).astype(q.dtype),
+            _result(dk, k, direct).astype(k.dtype),
+            _result(dv, v, direct).astype(v.dtype))
 
 
 flash_hop.defvjp(_flash_hop_vjp_fwd, _flash_hop_vjp_bwd)
@@ -828,12 +967,15 @@ flash_hop.defvjp(_flash_hop_vjp_fwd, _flash_hop_vjp_bwd)
 def flash_attention_bh(q, k, v, causal=False, sm_scale=None):
     """(BH, T, D)-layout flash attention for callers that already hold
     merged batch*head arrays: a singleton-head view of flash_attention
-    (the (BH,T,1,D) reshape is free), so it shares the kernels, the
-    custom vjp, AND the O(block*T) scan fallback. Note: routing the
-    transformer through this entry to skip its _to_bh copies was
-    measured 4.4% SLOWER end to end (docs/perf_notes.md round-4
-    addendum) — the model keeps the standard layout; this entry is for
-    code that genuinely starts from (BH,T,D)."""
+    (the (BH,T,1,D) reshape is free, and so is its transpose), so
+    it shares the kernels, the custom vjp, AND the O(block*T) scan
+    fallback. Routing the transformer through this entry, its
+    projections emitting (BH,T,hd), was measured 4.4% SLOWER end to end
+    than transposing round the kernels (docs/perf_notes.md round-4
+    addendum): the copies moved into XLA's einsums. Since PR 33 the
+    model's own (B,T,H,D) reaches the kernels with no copy at all
+    (_direct); this entry is for code that genuinely starts from
+    (BH,T,D)."""
     return flash_attention(q[:, :, None, :], k[:, :, None, :],
                            v[:, :, None, :], causal=causal,
                            sm_scale=sm_scale)[:, :, 0, :]

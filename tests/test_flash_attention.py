@@ -332,6 +332,54 @@ _WALK_CASES = {
 }
 
 
+def _losses(fa, entry, causal, tq, tk, sm):
+    """(flash, dense): a scalar of the kernels' results and the same of the
+    dense reference, as functions of q, k, v (B, T, H, D). `hop` is
+    flash_hop with a cotangent on its lse too."""
+    f32 = lambda x: x.astype(jnp.float32)
+    if entry == "hop":
+        def flash(q_, k_, v_):
+            out, lse = fa.flash_hop(q_, k_, v_, causal, sm)
+            return jnp.sum(f32(out) ** 2) + 0.7 * jnp.sum(jnp.sin(lse))
+
+        def dense(q_, k_, v_):
+            s = jnp.einsum("bqhd,bkhd->bhqk", f32(q_), f32(k_)) * sm
+            if causal:
+                s = jnp.where(jnp.tril(jnp.ones((tq, tk), bool)), s,
+                              -jnp.inf)
+            out = jnp.einsum("bhqk,bkhd->bqhd", jax.nn.softmax(s, -1),
+                             f32(v_))
+            return jnp.sum(out ** 2) + 0.7 * jnp.sum(jnp.sin(
+                jax.scipy.special.logsumexp(s, axis=-1)))
+    else:
+        def flash(q_, k_, v_):
+            return jnp.sum(f32(fa.flash_attention(
+                q_, k_, v_, causal=causal)) ** 2)
+
+        def dense(q_, k_, v_):
+            return jnp.sum(f32(attention_reference(
+                q_, k_, v_, causal=causal)) ** 2)
+    return flash, dense
+
+
+def _against_dense(fa, entry, causal, q, k, v, tol):
+    """The loss and dq, dk, dv of the kernels against the dense reference's,
+    each within `tol` of the reference's largest; returns what
+    dispatch_stats() counted while the kernels were traced."""
+    flash, dense = _losses(fa, entry, causal, q.shape[1], k.shape[1],
+                           1.0 / np.sqrt(q.shape[3]))
+    before = fa.dispatch_stats()
+    got = jax.value_and_grad(flash, argnums=(0, 1, 2))(q, k, v)
+    after = fa.dispatch_stats()
+    want = jax.value_and_grad(dense, argnums=(0, 1, 2))(q, k, v)
+    for name, a, b in zip(("loss", "dq", "dk", "dv"),
+                          (got[0],) + got[1], (want[0],) + want[1]):
+        a, b = np.asarray(a, np.float32), np.asarray(b, np.float32)
+        err = np.abs(a - b).max() / max(1e-3, np.abs(b).max())
+        assert err < tol, (name, err)
+    return {key: after[key] - before[key] for key in after}
+
+
 @pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
 @pytest.mark.parametrize("case", list(_WALK_CASES))
 def test_causal_walk_matches_reference(case, dtype):
@@ -347,43 +395,91 @@ def test_causal_walk_matches_reference(case, dtype):
     B, H, D = 1, 2, 64
     q, k, v = (jnp.asarray(rng.randn(B, t, H, D).astype(np.float32) * 0.5
                            ).astype(dtype) for t in (tq, tk, tk))
-    sm = 1.0 / np.sqrt(D)
-    f32 = lambda x: x.astype(jnp.float32)
+    took = _against_dense(fa, entry, causal, q, k, v,
+                          2e-4 if dtype == "float32" else 4e-2)
+    assert (took["causal_subblocks_run"], took["causal_subblocks_all"]) \
+        == (run, square)
 
-    if entry == "hop":
-        def flash(q_, k_, v_):
-            out, lse = fa.flash_hop(q_, k_, v_, causal, sm)
-            return jnp.sum(f32(out) ** 2) + 0.7 * jnp.sum(jnp.sin(lse))
 
-        def dense(q_, k_, v_):
-            s = jnp.einsum("bqhd,bkhd->bhqk", f32(q_), f32(k_)) * sm
-            s = jnp.where(jnp.tril(jnp.ones((tq, tk), bool)), s, -jnp.inf)
-            out = jnp.einsum("bhqk,bkhd->bqhd", jax.nn.softmax(s, -1),
-                             f32(v_))
-            return jnp.sum(out ** 2) + 0.7 * jnp.sum(jnp.sin(
-                jax.scipy.special.logsumexp(s, axis=-1)))
-    else:
-        def flash(q_, k_, v_):
-            return jnp.sum(f32(fa.flash_attention(
-                q_, k_, v_, causal=causal)) ** 2)
+# -- the layout the kernels index (PR 33) -------------------------------------
+# (B, H, D, Tq, Tk, causal, entry, direct): over a caller's (B, T, H * D) a
+# block of 128 lanes holds 128 // D whole heads; a shape that does not pack so
+# is transposed to (B * H, T, D), one head a block, into the same kernels.
+_LAYOUT_CASES = {
+    # two 64-wide heads a block, two blocks a row; T 1,024 is one grid block
+    # a pair (the walk, no scratch), T 2,048 two a side (running statistics
+    # a head), T 256 one masked pass
+    "pairs_T1024_causal": (1, 4, 64, 1024, 1024, True, "attention", True),
+    "pairs_T1024_full": (1, 4, 64, 1024, 1024, False, "attention", True),
+    "pairs_T2048_causal": (1, 2, 64, 2048, 2048, True, "attention", True),
+    "pairs_T2048_full": (1, 2, 64, 2048, 2048, False, "attention", True),
+    "pairs_batch2_T256": (2, 4, 64, 256, 256, True, "attention", True),
+    "pairs_cross_causal": (1, 4, 64, 512, 1024, True, "attention", True),
+    "pairs_cross_full": (2, 2, 64, 256, 512, False, "attention", True),
+    "fours_D32_T512": (1, 4, 32, 512, 512, True, "attention", True),
+    # one head of 128 lanes a block
+    "one_D128_T1024_causal": (1, 2, 128, 1024, 1024, True, "attention", True),
+    "one_D128_T2048_full": (1, 2, 128, 2048, 2048, False, "attention", True),
+    # the transposed route: an odd head count, a head that is no divisor of
+    # 128, a pair's worth of lanes with no second head
+    "odd_H3_T1024_causal": (1, 3, 64, 1024, 1024, True, "attention", False),
+    "odd_H3_T2048_full": (1, 3, 64, 2048, 2048, False, "attention", False),
+    "D80_T1024_causal": (1, 2, 80, 1024, 1024, True, "attention", False),
+    "D80_cross_full": (2, 2, 80, 256, 512, False, "attention", False),
+    "alone_H1_T512": (2, 1, 64, 512, 512, True, "attention", False),
+    # flash_hop, a cotangent on lse too: the kernels' dlse operand, a head
+    # a row
+    "hop_pairs_T1024_causal": (1, 4, 64, 1024, 1024, True, "hop", True),
+    "hop_pairs_T2048_full": (1, 2, 64, 2048, 2048, False, "hop", True),
+    "hop_odd_T512_causal": (1, 3, 64, 512, 512, True, "hop", False),
+}
 
-        def dense(q_, k_, v_):
-            return jnp.sum(f32(attention_reference(
-                q_, k_, v_, causal=causal)) ** 2)
 
-    before = fa.dispatch_stats()
-    got = jax.value_and_grad(flash, argnums=(0, 1, 2))(q, k, v)
-    after = fa.dispatch_stats()
-    want = jax.value_and_grad(dense, argnums=(0, 1, 2))(q, k, v)
-    assert (after["causal_subblocks_run"] - before["causal_subblocks_run"],
-            after["causal_subblocks_all"] - before["causal_subblocks_all"]
-            ) == (run, square)
-    tol = 2e-4 if dtype == "float32" else 4e-2
-    for name, a, b in zip(("loss", "dq", "dk", "dv"),
-                          (got[0],) + got[1], (want[0],) + want[1]):
-        a, b = np.asarray(a, np.float32), np.asarray(b, np.float32)
-        err = np.abs(a - b).max() / max(1e-3, np.abs(b).max())
-        assert err < tol, (name, err)
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("case", list(_LAYOUT_CASES))
+def test_layouts_match_reference(case, dtype):
+    """The output (through the loss) and dq, dk, dv against the dense
+    reference over the layouts the shape rule tells apart, and the count
+    that says which route the traced call took."""
+    import importlib
+    fa = importlib.import_module(
+        "incubator_mxnet_tpu.parallel.flash_attention")
+    B, H, D, tq, tk, causal, entry, direct = _LAYOUT_CASES[case]
+    rng = np.random.RandomState(33)
+    q, k, v = (jnp.asarray(rng.randn(B, t, H, D).astype(np.float32) * 0.5
+                           ).astype(dtype) for t in (tq, tk, tk))
+    # bf16: this file's bound for gradients through bf16 probabilities (the
+    # transposed route read 0.041 on one draw, before PR 33 as after)
+    took = _against_dense(fa, entry, causal, q, k, v,
+                          2e-4 if dtype == "float32" else 5e-2)
+    assert (took["direct"], took["transposed"], took["reference"]) \
+        == ((1, 0, 0) if direct else (0, 1, 0))
+
+
+@pytest.mark.parametrize("heads, d, direct", [
+    (16, 64, True), (8, 64, True), (2, 128, True), (3, 256, True),
+    (8, 16, True), (4, 32, True),
+    (3, 64, False), (1, 64, False), (2, 80, False), (6, 32, False),
+    (2, 96, False), (12, 8, False)])
+def test_the_route_is_read_off_the_shape(heads, d, direct):
+    import importlib
+    fa = importlib.import_module(
+        "incubator_mxnet_tpu.parallel.flash_attention")
+    assert fa._direct(heads, d) is direct
+    if direct:
+        w = fa._lane_block(heads * d, d)
+        assert w % 128 == 0 and w % d == 0 and (heads * d) % w == 0
+    assert fa._lane_block(d, d) == d       # the transposed route's block
+
+
+def test_layout_output_matches_reference_elementwise():
+    """A pair of heads' output rows one by one, and the block beside it:
+    each head's lanes hold its own result, not its neighbour's."""
+    q, k, v = _qkv(B=2, T=512, H=4, D=64, seed=13)
+    np.testing.assert_allclose(
+        np.asarray(flash_attention(q, k, v, causal=True)),
+        np.asarray(attention_reference(q, k, v, causal=True)),
+        rtol=1e-4, atol=1e-5)
 
 
 def test_causal_walk_output_matches_reference_elementwise():

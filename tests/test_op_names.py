@@ -242,26 +242,31 @@ def one_chip(v5e):
 @pytest.mark.parametrize("kernels", [("flash_fwd",),
                                      ("flash_bwd_dq", "flash_bwd_dkv")])
 def test_flash_kernels_keep_their_names_in_a_tpu_program(one_chip, kernels):
-    """GPT-2 medium's shapes, (BH, T, D) = (512, 1024, 64) in 1024 x 1024
-    blocks: each kernel is ONE custom call whose instruction and `op_name`
-    carry the kernel's name under the caller's scopes. `flash_time_share`
-    reads the opcode, `flash_*_roofline` the name and the operand shapes."""
+    """GPT-2 medium's shapes, (B, T, H * D) = (32, 1024, 16 * 64) in 1024 x
+    1024 blocks of two heads: each kernel is ONE custom call whose
+    instruction and `op_name` carry the kernel's name under the caller's
+    scopes. `flash_time_share` reads the opcode, `flash_*_roofline` the name
+    and the operand shapes: q, k, v first, three dimensions each, from which
+    the benchmark counts what it counted from (B * H, T, D) = (512, 1024,
+    64)."""
     import importlib
+    from perfbench import op_scopes
     fa = importlib.import_module(     # the package exports a function by
         "incubator_mxnet_tpu.parallel.flash_attention")     # the same name
-    big = jax.ShapeDtypeStruct((512, 1024, 64), jnp.bfloat16,
+    big = jax.ShapeDtypeStruct((32, 1024, 1024), jnp.bfloat16,
                                sharding=one_chip)
     row = jax.ShapeDtypeStruct((512, 1024, 1), jnp.float32,
                                sharding=one_chip)
 
     def fwd(q, k, v):
         with jax.named_scope("forward"), jax.named_scope("attn"):
-            return fa._fa_forward(q, k, v, True, 0.125, 1024, 1024, False)
+            return fa._fa_forward(q, k, v, 64, True, 0.125, 1024, 1024,
+                                  False)
 
     def bwd(q, k, v, do, lse, out, dlse):
         with jax.named_scope("forward"), jax.named_scope("attn"):
-            return fa._fa_backward(q, k, v, do, lse, out, dlse, True, 0.125,
-                                   1024, 1024, False)
+            return fa._fa_backward(q, k, v, do, lse, out, dlse, 64, True,
+                                   0.125, 1024, 1024, False)
     if kernels == ("flash_fwd",):
         lowered = jax.jit(fwd).lower(big, big, big)
     else:
@@ -274,7 +279,77 @@ def test_flash_kernels_keep_their_names_in_a_tpu_program(one_chip, kernels):
         assert re.match(rf"\s*(ROOT )?%{name}(\.\d+)? = ", line), line[:200]
         assert re.search(rf'op_name="jit\(\w+\)/forward/attn/{name}/'
                          r'pallas_call"', line), line[-400:]
-        assert "bf16[512,1024,64]" in line.split("custom-call(")[1]
+        assert "bf16[32,1024,1024]" in line.split("custom-call(")[1]
+    # the benchmark's count of the first call, from the compiled call's own
+    # operand and result shapes, as `op_scopes` reads them off a trace
+    shapes = lambda text: [f"{m.group(1)}[{m.group(2)}]"
+                           for m in op_scopes.SHAPE.finditer(text)]
+    row = {"results": shapes(calls[0].split(" custom-call(")[0]),
+           "operands": shapes(calls[0].split(
+               "operand_layout_constraints={")[1].split("}}")[0])}
+    backward = kernels[0] == "flash_bwd_dq"
+    assert row["operands"][:3] == ["bf16[32,1024,1024]"] * 3
+    assert op_scopes.flash_dims(row) == (32, 1024, 1024, 1024)
+    peaks = {"bf16_flops_per_s": 197e12, "hbm_bytes_per_s": 819e9}
+    least, bound = op_scopes.flash_least_seconds(row, peaks, backward)
+    old = (512, 1024, 64)
+    flops = op_scopes.flash_flops(*op_scopes.flash_dims(row)[:3],
+                                  backward=backward)
+    assert flops == op_scopes.flash_flops(*old, backward=backward) \
+        == (2 if backward else 1) * 68_719_476_736
+    assert (least, bound) == (flops / 197e12, "FLOPs")
+    moved = sum(map(op_scopes.shape_bytes, row["operands"] + row["results"]))
+    # forward: q, k, v, o at 64 MiB and lse, 2 MiB by its shape: 270 MB
+    assert moved == (4 * 64 + 2 if not backward else 6 * 64 + 3 * 2) * 2**20
+
+
+_QKV_SHAPED = ("bf16[32,1024,16,64]", "bf16[32,16,1024,64]",
+               "bf16[512,1024,64]", "bf16[512,64,1024]")
+
+
+def test_no_copy_stands_round_a_flash_call_in_a_tpu_program(one_chip,
+                                                            monkeypatch):
+    """Value and gradient of one remat `TransformerLM` block at GPT-2
+    medium's widths, batch 32 x 1,024, compiled for the chip: the three
+    flash kernels by name (the forward twice: once recomputed), and no
+    `transpose` or `copy`, alone or as a fusion, over an array shaped like
+    q, k, v or O in either of the layouts the kernels took before PR 33."""
+    from incubator_mxnet_tpu.models.transformer import (TransformerConfig,
+                                                        TransformerLM)
+    # the kernels ask the backend whether to interpret: compile them
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    model = TransformerLM(TransformerConfig(
+        vocab_size=50257, d_model=1024, n_heads=16, n_layers=1, d_ff=4096,
+        max_len=1024, remat=True, flash_attention=True))
+    shapes = jax.eval_shape(model.init_params, jax.random.PRNGKey(0))
+    params = {k: jax.ShapeDtypeStruct(v.shape, v.dtype, sharding=one_chip)
+              for k, v in shapes.items() if k.startswith("layer0_")}
+    x = jax.ShapeDtypeStruct((32, 1024, 1024), jnp.bfloat16,
+                             sharding=one_chip)
+    block = jax.checkpoint(
+        lambda p, y: model._block(p, "layer0_", y, None))
+
+    def loss(p, y):
+        return block(p, y).astype(jnp.float32).sum()
+    text = jax.jit(jax.value_and_grad(loss, argnums=(0, 1))).lower(
+        params, x).compile().as_text()
+    calls, moves = [], []
+    for ln in text.splitlines():
+        # %name = type opcode(..: the type may be a tuple with spaces in it
+        m = re.match(r"\s*(?:ROOT )?%([\w.\-]+) = (\(.*?\)|\S+) ([\w\-]+)\(",
+                     ln)
+        if not m:
+            continue
+        name, shape, opcode = m.groups()
+        if 'custom_call_target="tpu_custom_call"' in ln:
+            calls.append(name.split(".")[0])
+        moving = opcode in ("transpose", "copy") or (
+            opcode == "fusion" and re.search("transpose|copy", name))
+        if moving and shape.startswith(_QKV_SHAPED):
+            moves.append(ln.strip()[:200])
+    assert sorted(calls) == ["flash_bwd_dkv", "flash_bwd_dq", "flash_fwd",
+                             "flash_fwd"], calls
+    assert not moves, moves
 
 
 # -- BatchNorm's all-reduces on a dp mesh -------------------------------------
