@@ -23,6 +23,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 from jax.ad_checkpoint import checkpoint_name
 
 from ..parallel.ring_attention import attention_reference, ring_attention
+from ..parallel.sparse_attention import BlockSelect, block_sparse_attention
 
 
 def _remat_policy(name):
@@ -49,6 +50,19 @@ __all__ = ["TransformerConfig", "TransformerLM"]
 
 @dataclasses.dataclass(frozen=True)
 class TransformerConfig:
+    """Sizes and switches of a TransformerLM. The defaults are GPT-2's block:
+    LayerNorm, learned positions, multi-head attention, a GELU MLP, the
+    output projection tied to the embedding. `mixers` names each layer's
+    mixer instead, one word a layer (empty: "mha" in every layer):
+
+    | mixer | what it computes | what it reads |
+    |---|---|---|
+    | "mha" | causal softmax attention, n_heads heads of d_model / n_heads; ring attention over `sp`, Megatron heads over `tp` | n_heads, flash_attention |
+    | "sparse" | grouped-query causal softmax attention, n_heads query heads over n_kv_heads K/V heads of d_model / n_heads, no rotary, sigmoid output gate; up to select.dense_len positions dense (the flash kernels), beyond that over the blocks InfLLM-v2 selection picks (parallel/sparse_attention.py) | n_heads, n_kv_heads, select, flash_attention |
+    | "lightning" | decayed linear attention in chunks (parallel/linear_attention.py): n_heads heads of d_model / n_heads, RMSNorm on each head of q and k, rotary over the whole head, decay exp(-2^(-8 (h + 1) / n_heads)), RMSNorm over all heads of the result, sigmoid output gate | n_heads, rope_theta |
+
+    Every layer shares `norm`, `mlp`, `residual_scale`; a model with no
+    "mha" layer needs no `learned_positions`."""
     vocab_size: int = 32000
     d_model: int = 512
     n_heads: int = 8
@@ -71,49 +85,132 @@ class TransformerConfig:
     # (docs/perf_notes.md; speeds in PERF.md section 5). Untileable
     # shapes fall back to attention_reference inside flash_attention().
     flash_attention: bool = True
+    # -- what a layer list needs; the defaults above and below are GPT-2's --
+    mixers: tuple = ()              # a word a layer: the table above
+    norm: str = "layernorm"         # or "rmsnorm": learned weight, no bias
+    norm_eps: float = 1e-5
+    mlp: str = "gelu"               # or "swiglu": (silu(h Wg) * (h Wu)) Wd
+    learned_positions: bool = True  # a (max_len, d_model) table at the input
+    tied_head: bool = True          # False: `head`, a (vocab, d_model) matrix
+    n_kv_heads: int | None = None   # "sparse": K/V heads (None: n_heads)
+    rope_theta: float = 10000.0     # "lightning": rotary base
+    select: BlockSelect = BlockSelect()     # "sparse": InfLLM-v2's sizes
+    embed_scale: float = 1.0        # x = embed_scale * E[token]
+    residual_scale: float = 1.0     # x += residual_scale * f(norm(x))
+    logit_scale: float = 1.0        # logits = (logit_scale * norm(x)) W
+
+
+MIXERS = ("mha", "sparse", "lightning")
+
+
+def _rms(x, g, eps):
+    """RMSNorm over the last axis in float32, learned weight, no bias."""
+    x32 = x.astype(jnp.float32)
+    y = x32 * jax.lax.rsqrt(jnp.mean(jnp.square(x32), -1, keepdims=True)
+                            + eps)
+    return y.astype(x.dtype) * g
+
+
+def _scaled(x, scale):
+    """x times a width scale, multiplied in float32; x itself at 1."""
+    if scale == 1.0:
+        return x
+    return (x.astype(jnp.float32) * scale).astype(x.dtype)
+
+
+def _rope(x, positions, theta):
+    """Rotary embedding over the whole head of x (B, T, H, D), the halves
+    paired (x_i with x_(i + D/2)), in float32."""
+    half = x.shape[-1] // 2
+    freq = jnp.exp(-math.log(theta) * jnp.arange(half, dtype=jnp.float32)
+                   / half)
+    angle = positions.astype(jnp.float32)[:, None] * freq[None, :]
+    cos, sin = jnp.cos(angle)[:, None, :], jnp.sin(angle)[:, None, :]
+    x1, x2 = jnp.split(x.astype(jnp.float32), 2, axis=-1)
+    return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin],
+                           -1).astype(x.dtype)
 
 
 class TransformerLM:
     def __init__(self, config: TransformerConfig):
         self.cfg = config
+        self.mixers = tuple(config.mixers) or ("mha",) * config.n_layers
+        if len(self.mixers) != config.n_layers or \
+                set(self.mixers) - set(MIXERS):
+            raise ValueError(f"mixers {self.mixers}: {config.n_layers} "
+                             f"words of {MIXERS}")
+        self.head_dim = config.d_model // config.n_heads
 
     # -- parameters ---------------------------------------------------------
+    def _shapes(self):
+        """[(name, shape, fan_in)] in the order the seed is spent: a matrix
+        is drawn normal / sqrt(fan_in); fan_in None is a norm's weight
+        (ones), 0 a bias (zeros). Matrices are stored (in, out)."""
+        cfg = self.cfg
+        d, f, hd = cfg.d_model, cfg.d_ff, self.head_dim
+
+        def norm(name):
+            return [(name + "_g", (d,), None)] + (
+                [(name + "_b", (d,), 0)] if cfg.norm == "layernorm" else [])
+        out = [("embed", (cfg.vocab_size, d), d)]
+        if cfg.learned_positions:
+            out.append(("pos_embed", (cfg.max_len, d), d))
+        for i, kind in enumerate(self.mixers):
+            p = f"layer{i}_"
+            out += norm(p + "ln1")
+            if kind == "mha":
+                out += [(p + w, (d, d), d) for w in ("wq", "wk", "wv", "wo")]
+            else:
+                kv = d if kind == "lightning" else \
+                    (cfg.n_kv_heads or cfg.n_heads) * hd
+                out += [(p + "wq", (d, d), d), (p + "wk", (d, kv), d),
+                        (p + "wv", (d, kv), d), (p + "wg", (d, d), d)]
+                if kind == "lightning":
+                    out += [(p + "q_norm_g", (hd,), None),
+                            (p + "k_norm_g", (hd,), None),
+                            (p + "o_norm_g", (d,), None)]
+                out.append((p + "wo", (d, d), d))
+            out += norm(p + "ln2")
+            if cfg.mlp == "swiglu":
+                out.append((p + "w_gate", (d, f), d))
+            out += [(p + "w_in", (d, f), d), (p + "w_out", (f, d), f)]
+        out += norm("lnf")
+        if not cfg.tied_head:
+            out.append(("head", (cfg.vocab_size, d), d))
+        return out
+
     def init_params(self, key):
         cfg = self.cfg
         dt = jnp.dtype(cfg.dtype)
-        d, h, f = cfg.d_model, cfg.n_heads, cfg.d_ff
-        params = {}
         k = iter(jax.random.split(key, 4 + 8 * cfg.n_layers))
-
-        def dense(key, fan_in, shape):
-            return (jax.random.normal(key, shape, jnp.float32) /
-                    math.sqrt(fan_in)).astype(dt)
-
-        params["embed"] = dense(next(k), d, (cfg.vocab_size, d))
-        params["pos_embed"] = dense(next(k), d, (cfg.max_len, d))
-        for i in range(cfg.n_layers):
-            p = f"layer{i}_"
-            params[p + "ln1_g"] = jnp.ones((d,), dt)
-            params[p + "ln1_b"] = jnp.zeros((d,), dt)
-            params[p + "wq"] = dense(next(k), d, (d, d))
-            params[p + "wk"] = dense(next(k), d, (d, d))
-            params[p + "wv"] = dense(next(k), d, (d, d))
-            params[p + "wo"] = dense(next(k), d, (d, d))
-            params[p + "ln2_g"] = jnp.ones((d,), dt)
-            params[p + "ln2_b"] = jnp.zeros((d,), dt)
-            params[p + "w_in"] = dense(next(k), d, (d, f))
-            params[p + "w_out"] = dense(next(k), f, (f, d))
-        params["lnf_g"] = jnp.ones((d,), dt)
-        params["lnf_b"] = jnp.zeros((d,), dt)
+        params = {}
+        for name, shape, fan_in in self._shapes():
+            if fan_in:
+                params[name] = (jax.random.normal(next(k), shape, jnp.float32)
+                                / math.sqrt(fan_in)).astype(dt)
+            else:
+                params[name] = (jnp.ones if fan_in is None
+                                else jnp.zeros)(shape, dt)
         return params
 
     # -- forward ------------------------------------------------------------
     def _ln(self, x, g, b):
         m = jnp.mean(x.astype(jnp.float32), axis=-1, keepdims=True)
         v = jnp.var(x.astype(jnp.float32), axis=-1, keepdims=True)
-        return ((x - m) * jax.lax.rsqrt(v + 1e-5)).astype(x.dtype) * g + b
+        return ((x - m) * jax.lax.rsqrt(v + self.cfg.norm_eps)).astype(
+            x.dtype) * g + b
 
-    def _block(self, params, prefix, x, sp_axis, tp_axis=None, mesh=None):
+    def _norm(self, x, params, name):
+        if self.cfg.norm == "layernorm":
+            return self._ln(x, params[name + "_g"], params[name + "_b"])
+        with jax.named_scope("norm"):
+            return _rms(x, params[name + "_g"], self.cfg.norm_eps)
+
+    def _residual(self, x, y):
+        return x + _scaled(y, self.cfg.residual_scale)
+
+    def _block(self, params, prefix, x, sp_axis, tp_axis=None, mesh=None,
+               positions=None):
         """One pre-norm block. Inside shard_map, attention/MLP weights may be
         Megatron-sharded over `tp_axis` (wq/wk/wv/w_in column-parallel,
         wo/w_out row-parallel): each device computes its local slice of heads
@@ -123,33 +220,86 @@ class TransformerLM:
         `mesh` is the multi-device mesh of a pure-jit (GSPMD) caller: the
         flash kernel then runs per shard."""
         with jax.named_scope("attn"):
-            x = x + self._attn(params, prefix, x, sp_axis, tp_axis, mesh)
+            x = self._residual(x, self._attn(params, prefix, x, sp_axis,
+                                             tp_axis, mesh, positions))
         with jax.named_scope("mlp"):
-            h = self._ln(x, params[prefix + "ln2_g"],
-                         params[prefix + "ln2_b"])
-            y = jax.nn.gelu(h @ params[prefix + "w_in"]) \
-                @ params[prefix + "w_out"]
+            h = self._norm(x, params, prefix + "ln2")
+            if self.cfg.mlp == "swiglu":
+                y = (jax.nn.silu(h @ params[prefix + "w_gate"])
+                     * (h @ params[prefix + "w_in"])) \
+                    @ params[prefix + "w_out"]
+            else:
+                y = jax.nn.gelu(h @ params[prefix + "w_in"]) \
+                    @ params[prefix + "w_out"]
             if tp_axis is not None:
                 y = jax.lax.psum(y, tp_axis)
             y = checkpoint_name(y, "mlp_out")
-            return x + y
+            return self._residual(x, y)
 
-    def _attn(self, params, prefix, x, sp_axis, tp_axis, mesh):
-        """The attention half of a block: ln1, projections, attention, the
-        output projection (psum over tp where sharded). Returns attn_out."""
-        cfg = self.cfg
+    def _attn(self, params, prefix, x, sp_axis, tp_axis, mesh,
+              positions=None):
+        """The mixer half of a block: ln1, projections, the layer's mixer
+        (TransformerConfig's table), the output projection (psum over tp
+        where sharded). Returns attn_out."""
         B, T, D = x.shape
-        hd = D // cfg.n_heads
-        h = self._ln(x, params[prefix + "ln1_g"], params[prefix + "ln1_b"])
-        wq = params[prefix + "wq"]
-        d_local = wq.shape[1]          # = D/tp inside shard_map with TP
-        h_local = d_local // hd        # local head count
-        q = (h @ wq).reshape(B, T, h_local, hd)
-        kk = (h @ params[prefix + "wk"]).reshape(B, T, h_local, hd)
-        v = (h @ params[prefix + "wv"]).reshape(B, T, h_local, hd)
+        hd = self.head_dim
+        kind = self.mixers[int(prefix[len("layer"):-1])]
+        if kind != "mha" and (sp_axis is not None or tp_axis is not None):
+            raise NotImplementedError(
+                f"a {kind!r} layer inside shard_map over sp / tp")
+        h = self._norm(x, params, prefix + "ln1")
+        # head counts are read off the LOCAL weight shapes (D/tp columns
+        # inside shard_map with TP)
+        q, kk, v = ((h @ params[prefix + w]).reshape(B, T, -1, hd)
+                    for w in ("wq", "wk", "wv"))
+        if kind == "lightning":
+            attn = self._lightning(params, prefix, q, kk, v, positions)
+        elif kind == "sparse":
+            with jax.named_scope("sparse_attn"):
+                if T > self.cfg.select.dense_len:
+                    attn = block_sparse_attention(q, kk, v, self.cfg.select)
+                else:
+                    attn = self._softmax_attention(q, kk, v, sp_axis, mesh)
+        else:
+            attn = self._softmax_attention(q, kk, v, sp_axis, mesh)
+        attn = attn.reshape(B, T, -1)
+        if kind != "mha":
+            gate = h @ params[prefix + "wg"]
+            with jax.named_scope("gate"):
+                attn = attn * jax.nn.sigmoid(gate)
+        attn_out = attn @ params[prefix + "wo"]
+        if tp_axis is not None:
+            attn_out = jax.lax.psum(attn_out, tp_axis)
+        return checkpoint_name(attn_out, "attn_out")
+
+    def _lightning(self, params, prefix, q, k, v, positions):
+        """QK-norm, rotary, the chunked decayed scan at 1 / sqrt(head_dim),
+        RMSNorm over all heads of the result."""
+        from ..parallel.linear_attention import (alibi_slopes,
+                                                 lightning_attention)
+        cfg = self.cfg
+        B, T, H, hd = q.shape
+        with jax.named_scope("norm"):
+            q = _rms(q, params[prefix + "q_norm_g"], cfg.norm_eps)
+            k = _rms(k, params[prefix + "k_norm_g"], cfg.norm_eps)
+        with jax.named_scope("rope"):
+            if positions is None:
+                positions = jnp.arange(T)
+            q, k = (_rope(x, positions, cfg.rope_theta) for x in (q, k))
+        with jax.named_scope("lightning"):
+            out = lightning_attention(q, k, v, alibi_slopes(H),
+                                      scale=1.0 / math.sqrt(hd))
+        with jax.named_scope("norm"):
+            return _rms(out.reshape(B, T, H * hd),
+                        params[prefix + "o_norm_g"], cfg.norm_eps)
+
+    def _softmax_attention(self, q, kk, v, sp_axis, mesh):
+        """Causal softmax attention of q (B, T, H, hd) over kk, v (B, T, H
+        or fewer, hd): ring attention over `sp_axis`, else the flash
+        kernels, else the dense reference."""
         if sp_axis is not None:
-            attn = ring_attention(q, kk, v, sp_axis, causal=True)
-        elif self.cfg.flash_attention:
+            return ring_attention(q, kk, v, sp_axis, causal=True)
+        if self.cfg.flash_attention:
             # (B,T,H,hd) is what the kernels index: two 64-wide heads
             # to a block of 128 lanes of the projections' own (B,T,D), so
             # no copy stands round a call (flash_attention._direct; an
@@ -170,13 +320,11 @@ class TransformerLM:
                 spec = P("dp" if "dp" in mesh.axis_names else None, None,
                          "tp" if "tp" in mesh.axis_names else None, None)
                 attn_fn = shard_map(attn_fn, mesh, (spec,) * 3, spec)
-            attn = attn_fn(q, kk, v)
-        else:
-            attn = attention_reference(q, kk, v, causal=True)
-        attn_out = attn.reshape(B, T, d_local) @ params[prefix + "wo"]
-        if tp_axis is not None:
-            attn_out = jax.lax.psum(attn_out, tp_axis)
-        return checkpoint_name(attn_out, "attn_out")
+            return attn_fn(q, kk, v)
+        if kk.shape[2] != q.shape[2]:
+            kk, v = (jnp.repeat(x, q.shape[2] // x.shape[2], axis=2)
+                     for x in (kk, v))
+        return attention_reference(q, kk, v, causal=True)
 
     def apply(self, params, tokens, sp_axis=None, positions=None, tp_axis=None,
               mesh=None):
@@ -186,25 +334,27 @@ class TransformerLM:
         the mesh when tracing a pure-jit program over several devices."""
         cfg = self.cfg
         with jax.named_scope("embed"):
-            x = params["embed"][tokens]
+            x = _scaled(params["embed"][tokens], cfg.embed_scale)
             if positions is None:
                 positions = jnp.arange(tokens.shape[1])
-            x = x + params["pos_embed"][positions]
+            if cfg.learned_positions:
+                x = x + params["pos_embed"][positions]
         if cfg.remat:
             block = jax.checkpoint(
                 lambda p, pref, y: self._block(p, pref, y, sp_axis, tp_axis,
-                                               mesh),
+                                               mesh, positions),
                 static_argnums=(1,), policy=_remat_policy(cfg.remat_policy))
         else:
             block = lambda p, pref, y: self._block(p, pref, y, sp_axis,
-                                                   tp_axis, mesh)
+                                                   tp_axis, mesh, positions)
         for i in range(cfg.n_layers):
             with jax.named_scope(f"layer{i}"):
                 x = block(params, f"layer{i}_", x)
         with jax.named_scope("final_ln"):
-            x = self._ln(x, params["lnf_g"], params["lnf_b"])
+            x = self._norm(x, params, "lnf")
         with jax.named_scope("logits"):
-            return (x @ params["embed"].T).astype(jnp.float32)
+            head = params["embed" if cfg.tied_head else "head"]
+            return (_scaled(x, cfg.logit_scale) @ head.T).astype(jnp.float32)
 
     def loss(self, params, tokens, targets, sp_axis=None, positions=None,
              tp_axis=None, mesh=None):
@@ -223,20 +373,19 @@ class TransformerLM:
     def param_sharding(self, mesh, tp_axis="tp"):
         from ..parallel.tensor_parallel import transformer_param_specs
         has_tp = tp_axis in mesh.axis_names
+        matrices = self._matrices()
         shd = {}
         for name in self._param_names():
             shd[name] = NamedSharding(
                 mesh, transformer_param_specs(name, _FakeNd(2), tp_axis)
-                if has_tp and _rank_of(name) >= 2 else P())
+                if has_tp and name in matrices else P())
         return shd
 
     def _param_names(self):
-        names = ["embed", "pos_embed", "lnf_g", "lnf_b"]
-        for i in range(self.cfg.n_layers):
-            p = f"layer{i}_"
-            names += [p + s for s in ("ln1_g", "ln1_b", "wq", "wk", "wv", "wo",
-                                      "ln2_g", "ln2_b", "w_in", "w_out")]
-        return names
+        return [name for name, _, _ in self._shapes()]
+
+    def _matrices(self):
+        return {name for name, shape, _ in self._shapes() if len(shape) == 2}
 
     def make_train_step(self, mesh, lr=1e-3, use_sp=True, n_steps=None):
         """Fully-sharded train step: dp on batch, tp on weights, sp on
@@ -256,8 +405,10 @@ class TransformerLM:
         has = {a: a in axis_names for a in ("dp", "tp", "sp")}
         sp_axis = "sp" if (use_sp and has["sp"]) else None
 
+        matrices = self._matrices()
+
         def _is_matmul(n):
-            return n.endswith(("wq", "wk", "wv", "wo", "w_in", "w_out"))
+            return n in matrices and n not in ("embed", "pos_embed", "head")
 
         # weights are tp-sharded only when the mesh actually has a 'tp' axis.
         # On the shard_map (sp) path the block does manual Megatron TP, so
@@ -270,7 +421,7 @@ class TransformerLM:
                      for n in self._param_names()}
         else:
             pspec = {n: (transformer_param_specs(n, _FakeNd(2))
-                         if has["tp"] and _rank_of(n) >= 2 else P())
+                         if has["tp"] and n in matrices else P())
                      for n in self._param_names()}
         data_spec = P("dp" if has["dp"] else None,
                       sp_axis)
@@ -360,13 +511,6 @@ class TransformerLM:
                     for k, v in params.items()}
 
         return jit_step, shard_params, init_opt
-
-
-def _rank_of(name):
-    if name in ("embed", "pos_embed") or name.endswith(("wq", "wk", "wv", "wo",
-                                                        "w_in", "w_out")):
-        return 2
-    return 1
 
 
 class _FakeNd:

@@ -907,10 +907,18 @@ _flash.defvjp(_flash_vjp_fwd, _flash_vjp_bwd)
 
 def flash_attention(q, k, v, causal=False, sm_scale=None):
     """Blocked flash attention. q,k,v: (B, T, H, D) (the layout of
-    attention_reference / the transformer flagship). Differentiable."""
+    attention_reference / the transformer flagship). Differentiable.
+    Grouped K/V heads: k and v may hold H / g heads, query head i reading
+    head i // g; they are repeated over the group before the kernels, whose
+    operands are then three arrays of q's shape (what the benchmark's count
+    of a call's work reads, perfbench/op_scopes.flash_dims), and the
+    repeat's own transpose sums dk and dv over the group."""
     if sm_scale is None:
         import math
         sm_scale = 1.0 / math.sqrt(q.shape[-1])
+    if k.shape[2] != q.shape[2]:
+        k, v = (jnp.repeat(x, q.shape[2] // x.shape[2], axis=2)
+                for x in (k, v))
     return _flash(q, k, v, bool(causal), float(sm_scale))
 
 

@@ -34,11 +34,12 @@ def transformer_param_specs(name, value, tp_axis="tp"):
     nd = getattr(value, "ndim", len(getattr(value, "shape", ())))
     if nd < 2:
         return P()
-    if any(t in name for t in ("wq", "wk", "wv", "w_in", "wi")):
+    if any(t in name for t in ("wq", "wk", "wv", "wg", "w_in", "w_gate",
+                               "wi")):
         return P(None, tp_axis)   # (d_model, d_head*H/tp) column
     if any(t in name for t in ("wo", "w_out")):
         return P(tp_axis, None)   # row parallel
-    if "embed" in name:
+    if "embed" in name or name == "head":
         return P(None, tp_axis)
     return P()
 
@@ -51,8 +52,8 @@ def transformer_partition_rules(tp_axis="tp"):
     coverage, and the trailing explicit catch-all is the declared
     replicate-everything-else decision, not a silent fallback."""
     return [
-        (r"(wq|wk|wv|w_in|wi)$", P(None, tp_axis)),   # column parallel
+        (r"(wq|wk|wv|wg|w_in|w_gate|wi)$", P(None, tp_axis)),   # column
         (r"(wo|w_out)$", P(tp_axis, None)),           # row parallel
-        (r"embed$", P(None, tp_axis)),                # embed + pos_embed
+        (r"(embed|^head)$", P(None, tp_axis)),        # embed, pos_embed, head
         (r".*", P()),   # layernorm scales/biases etc.: replicated
     ]

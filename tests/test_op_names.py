@@ -352,6 +352,71 @@ def test_no_copy_stands_round_a_flash_call_in_a_tpu_program(one_chip,
     assert not moves, moves
 
 
+@pytest.mark.parametrize("layer", [0, 1], ids=["sparse", "lightning"])
+def test_a_layer_lists_blocks_keep_their_names_in_a_tpu_program(
+        one_chip, monkeypatch, layer):
+    """Value and gradient of one remat block of `minicpm-sala.train-8k`, at
+    its published widths and 1 x 8,192 tokens, compiled for the chip. The
+    "sparse" layer on its dense path is the three flash kernels over K/V
+    repeated to the 32 query heads, so that the benchmark's count reads
+    (1, 8192, 32 * 128) off every operand; the "lightning" layer is plain
+    XLA under the scopes its metrics read, with no kernel and no (T, T)
+    array."""
+    from perfbench import cells, op_scopes
+    from perfbench.families import minicpm_sala
+    from incubator_mxnet_tpu.models.transformer import TransformerLM
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    cell = cells.resolve("minicpm-sala.train-8k")
+    model = TransformerLM(minicpm_sala.model_config(cell.config,
+                                                    cell.traffic))
+    shapes = jax.eval_shape(model.init_params, jax.random.PRNGKey(0))
+    prefix = f"layer{layer}_"
+    params = {k: jax.ShapeDtypeStruct(v.shape, v.dtype, sharding=one_chip)
+              for k, v in shapes.items() if k.startswith(prefix)}
+    x = jax.ShapeDtypeStruct((1, 8192, 4096), jnp.bfloat16,
+                             sharding=one_chip)
+    block = jax.checkpoint(lambda p, y: model._block(p, prefix, y, None))
+
+    def loss(p, y):
+        with jax.named_scope("forward"):
+            return block(p, y).astype(jnp.float32).sum()
+    text = jax.jit(jax.value_and_grad(loss, argnums=(0, 1))).lower(
+        params, x).compile().as_text()
+    calls = [ln for ln in text.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in ln]
+    names = set(re.findall(r'op_name="([^"]*)"', text))
+    under = lambda *words: any(all(f"/{w}/" in n for w in words)
+                               for n in names)
+    assert under("attn", "norm") and under("attn", "gate") \
+        and under("mlp", "norm")
+    if layer == 1:
+        assert not calls
+        assert under("attn", "lightning", "lightning_intra")
+        assert under("attn", "lightning", "lightning_state")
+        assert under("attn", "rope")
+        assert under("rematted_computation", "lightning")
+        assert not re.search(r"\[(\d+,)*8192,8192\]", text)
+        return
+    kernel = lambda ln: re.match(r"\s*(?:ROOT )?%(\w+?)(\.\d+)? = ",
+                                 ln).group(1)
+    assert sorted(map(kernel, calls)) == [
+        "flash_bwd_dkv", "flash_bwd_dq", "flash_fwd", "flash_fwd"]
+    assert under("attn", "sparse_attn", "flash_fwd")
+    assert not under("block_select")            # T = dense_len: dense
+    shaped = lambda text: [f"{m.group(1)}[{m.group(2)}]"
+                           for m in op_scopes.SHAPE.finditer(text)]
+    for ln in calls:
+        row = {"results": shaped(ln.split(" custom-call(")[0]),
+               "operands": shaped(ln.split(
+                   "operand_layout_constraints={")[1].split("}}")[0])}
+        assert row["operands"][:3] == ["bf16[1,8192,4096]"] * 3
+        if kernel(ln) in ("flash_fwd", "flash_bwd_dq"):
+            assert op_scopes.flash_dims(row) == (1, 8192, 4096, 4096)
+    # 32 heads x 8192^2 x (128 + 128) under the mask, as (B H, T, D) counts
+    assert op_scopes.flash_flops(1, 8192, 4096) == \
+        op_scopes.flash_flops(32, 8192, 128) == 549_755_813_888
+
+
 # -- BatchNorm's all-reduces on a dp mesh -------------------------------------
 # Under GSPMD a BatchNorm over a batch sharded on `dp` takes the statistics of
 # the global batch, and every reduction over the batch becomes an all-reduce.
