@@ -1,21 +1,23 @@
 """Device time of the three flash kernels, causal, by sub-block edge.
 
     python benchmark/flash_sweep.py [--subs 0,128,256,512] [--calls 10]
-        [--shapes 32x16x1024,16x16x2048,4x16x8192] [--out _chip/flash_sweep]
+        [--shapes 32x16x1024,16x16x2048,4x16x8192,1x32x8192x128]
+        [--out _chip/flash_sweep]
 
-One line of JSON per (shape B x H x T, edge): microseconds a call of
-`flash_fwd`, `flash_bwd_dq` and `flash_bwd_dkv` on (B, T, H, 64) bf16, the
-layout the model hands `flash_attention`, and `other_us`: what else the
-device ran for one forward and one backward call, which is the copies that
-stand round the kernels (PR 33 took them away for shapes that pack two heads
-to a 128-lane block). All read from a device trace by kernel name
-(`perfbench/op_scopes.py`; host timing of a 2 ms kernel is noise). Edge 0
-leaves the module as it is. The file calls nothing but the public entry, so
-an older tree runs it too: unpack the parent beside this tree, copy this file
-over its own, and run it there for the parent's column. Needs a TPU; exits 2
-without one. The edge is set on the module for the sweep only: it is no
-option of the program (PERF.md section 6, PR 26 and PR 33, has the tables
-this printed).
+One line of JSON per (shape B x H x T, or B x H x T x D; edge): microseconds
+a call of `flash_fwd`, `flash_bwd_dq` and `flash_bwd_dkv` on (B, T, H, D)
+bf16, D = 64 unless given, the layout the model hands `flash_attention`, and
+`other_us`: what else the device ran for one forward and one backward call,
+which is the copies that stand round the kernels (PR 33 took them away for
+shapes that pack two heads to a 128-lane block) and, before PR 35, the array
+of zeros in the place of lse's cotangent. All read from a device trace by
+kernel name (`perfbench/op_scopes.py`; host timing of a 2 ms kernel is
+noise). Edge 0 leaves the module as it is. The file calls nothing but the
+public entry, so an older tree runs it too: unpack the parent beside this
+tree, copy this file over its own, and run it there for the parent's column.
+Needs a TPU; exits 2 without one. The edge is set on the module for the sweep
+only: it is no option of the program (PERF.md section 6, PR 26, PR 33 and PR
+35, has the tables this printed).
 """
 import argparse
 import functools
@@ -70,7 +72,8 @@ def check(fa, jax, jnp, np, t):
 def main():
     ap = argparse.ArgumentParser()
     ap.add_argument("--subs", default="0")
-    ap.add_argument("--shapes", default="32x16x1024,16x16x2048,4x16x8192")
+    ap.add_argument("--shapes", default="32x16x1024,16x16x2048,4x16x8192,"
+                                        "1x32x8192x128")
     ap.add_argument("--calls", type=int, default=10)
     ap.add_argument("--out", default=os.path.join(ROOT, "_chip",
                                                   "flash_sweep"))
@@ -89,9 +92,9 @@ def main():
             fa._SUB = sub
         worst = max(check(fa, jax, jnp, np, t) for t in (1024, 2048))
         for shape in args.shapes.split(","):
-            b, h, t = map(int, shape.split("x"))
+            b, h, t, d = (tuple(map(int, shape.split("x"))) + (64,))[:4]
             keys = jax.random.split(jax.random.PRNGKey(t), 4)
-            q, k, v, do = (jax.random.normal(key, (b, t, h, 64),
+            q, k, v, do = (jax.random.normal(key, (b, t, h, d),
                                              jnp.bfloat16) for key in keys)
             attn = functools.partial(fa.flash_attention, causal=True)
             # one forward call, and one backward call from its residuals
@@ -106,7 +109,7 @@ def main():
                 jax.block_until_ready(res)
             us, other = kernel_us(os.path.join(args.out, f"{shape}-{sub}"),
                                   body, args.calls)
-            print(json.dumps({"b": b, "h": h, "t": t, "sub": sub,
+            print(json.dumps({"b": b, "h": h, "t": t, "d": d, "sub": sub,
                               "block": fa._pick_block(t),
                               "check_rel_err": worst, "us_a_call": us,
                               "other_us": other,

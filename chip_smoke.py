@@ -383,6 +383,14 @@ def leg_lm(cfg, rehearse, result_path):
     # skip; below that the whole square is one masked pass
     assert 0 < run <= square and (run < square or cfg["seq"] < 256), \
         "the causal walk inside the kernels did not engage"
+    # a block's checkpoint keeps the forward kernel's output and lse, so the
+    # step holds the kernel once a layer, not once more in each backward
+    fwds = str(jax.make_jaxpr(step)(params, opt, tokens, targets, 0)).count(
+        "name=flash_fwd")
+    print(f"[smoke:lm] flash_fwd calls in the step: {fwds} for "
+          f"{cfg['layers']} layers", flush=True)
+    assert fwds == cfg["layers"], \
+        "a block's checkpoint runs its flash forward again"
     if n > 1:
         check_spread("lm", {"tokens": tokens,
                             "layer0_wq": params["layer0_wq"],

@@ -22,17 +22,22 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 
 from jax.ad_checkpoint import checkpoint_name
 
+from ..parallel.flash_attention import RESIDUAL_NAMES
 from ..parallel.ring_attention import attention_reference, ring_attention
 from ..parallel.sparse_attention import BlockSelect, block_sparse_attention
 
 
 def _remat_policy(name):
     """Map TransformerConfig.remat_policy to a jax.checkpoint policy
-    (None = recompute everything; reference analog: the
-    MXNET_BACKWARD_DO_MIRROR recompute knob, graph_executor.cc:351)."""
-    if not name:
-        return None
+    (reference analog: the MXNET_BACKWARD_DO_MIRROR recompute knob,
+    graph_executor.cc:351). Every policy, None among them, keeps what a
+    flash forward kernel leaves for its backward (its output and lse, 66 MB
+    a layer at GPT-2 medium's sizes): with both kept the kernel is not run
+    again; None recomputes everything else."""
     cp = jax.checkpoint_policies
+    flash = cp.save_only_these_names(*RESIDUAL_NAMES)
+    if not name:
+        return flash
     table = {
         "dots": cp.checkpoint_dots,
         "dots_no_batch": cp.checkpoint_dots_with_no_batch_dims,
@@ -43,7 +48,7 @@ def _remat_policy(name):
     if name not in table:
         raise ValueError(f"unknown remat_policy {name!r}; "
                          f"one of {sorted(table)}")
-    return table[name]
+    return cp.save_from_both_policies(table[name], flash)
 
 __all__ = ["TransformerConfig", "TransformerLM"]
 
@@ -71,7 +76,8 @@ class TransformerConfig:
     max_len: int = 2048
     dtype: str = "bfloat16"
     remat: bool = True          # jax.checkpoint each block (HBM for FLOPs)
-    # Selective rematerialization policy. None = recompute everything
+    # Selective rematerialization policy. None = recompute everything but
+    # the flash kernel's forward, whose output and lse are kept
     # (the recomputed forward's share of a step is `remat_time_share`,
     # PERF.md section 5). "dots" / "dots_no_batch" are XLA's stock
     # save-matmul-outputs policies; "save_attn" / "save_attn_mlp" save

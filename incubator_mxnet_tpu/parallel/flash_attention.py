@@ -32,7 +32,11 @@ Backward: REAL flash backward kernels (custom_vjp) — the forward also
 emits the per-row log-sum-exp; `_fa_bwd_dq_kernel` streams k/v blocks
 accumulating dq, `_fa_bwd_dkv_kernel` streams q blocks accumulating
 dk/dv, both recomputing p from the saved lse with bf16 matmuls and f32
-accumulation. O(block * T) memory end to end, which is what makes
+accumulation. lse and delta pass between the kernels as (heads, 1, T)
+f32, rows of lanes that no tile pads; the forward's output and lse carry
+names (RESIDUAL_NAMES) under which a caller's checkpoint keeps them, so
+that its backward runs no forward kernel again (TransformerLM's blocks
+do). O(block * T) memory end to end, which is what makes
 LONG-CONTEXT TRAINING possible on one chip, where the XLA attention path
 cannot even compile at T = 8,192 (speeds: PERF.md). An XLA lax.scan
 fallback covers untileable shapes and the no-pallas path.
@@ -46,6 +50,7 @@ import threading
 
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 
 __all__ = ["flash_attention", "flash_attention_bh", "pallas_available",
            "dispatch_stats"]
@@ -133,12 +138,34 @@ def _over(col, like):
     return lax.broadcast_in_dim(col, like.shape, (0, 1))
 
 
-def _under(col, like):
-    """A column (w, 1) turned into a row of lanes and spread down the
-    sublanes of `like` (r, w)."""
+def _under(row, cols, like):
+    """Lanes `cols` of a row (1, n) spread down the sublanes of `like` (r,
+    w): spread first and cut then, since Mosaic spreads no row that starts
+    off lane 0."""
     from jax import lax
-    row = lax.expand_dims(lax.squeeze(col, (1,)), (0,))
-    return lax.broadcast_in_dim(row, like.shape, (0, 1))
+    flat = lax.squeeze(row, (0,))
+    if cols != (0, flat.shape[0]):
+        flat = lax.slice_in_dim(flat, cols[0], cols[1], axis=0)
+    return lax.broadcast_in_dim(flat, like.shape, (1,))
+
+
+# The row vectors (lse, dlse, delta) lie in HBM as rows of lanes, a head a
+# row: a (T, 1) f32 column is tiled T(8,128) there, 128 times its numbers
+# (PERF.md section 6, PR 35). A kernel that wants a row's numbers down its
+# score block's sublanes turns the row on the chip, once a head a grid step.
+
+def _row(col):
+    """A column (r, 1) as a row of lanes (1, r): a transpose, which Mosaic
+    gives to the transpose unit; its own relayout of the squeezed column
+    costs 1.3x the forward kernel's bundles (PERF.md section 6, PR 35)."""
+    from jax import lax
+    return lax.transpose(col, (1, 0))
+
+
+def _col(row):
+    """A row of lanes (1, r) as a column (r, 1)."""
+    from jax import lax
+    return lax.expand_dims(lax.squeeze(row, (0,)), (1,))
 
 
 def _dot(a, b, contract, prec):
@@ -327,8 +354,9 @@ def _fa_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *scratch, d, block_q,
     score: the statistics go straight to the output.
 
     Refs, g = w // d heads a block: q (1, block_q, w) | k, v (1, block_k,
-    w) | o (1, block_q, w) | lse (g, block_q, 1); scratch m, l (g,
-    block_q, 1), acc (block_q, w)."""
+    w) | o (1, block_q, w) | lse (g, 1, block_q), a row of lanes a head;
+    scratch m, l (g, block_q, 1), columns as the fold uses them, acc
+    (block_q, w)."""
     from jax import lax
     from jax.experimental import pallas as pl
 
@@ -347,14 +375,21 @@ def _fa_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *scratch, d, block_q,
             l_sc[:] = lax.full(l_sc.shape, 0.0, l_sc.dtype)
             acc_sc[:] = lax.full(acc_sc.shape, 0.0, acc_sc.dtype)
 
-    def emit(h, m, l, acc):
-        none = lax.eq(l, 0.0)
-        some = lax.select(none, lax.full_like(l, 1.0), l)
-        # fully-masked rows: zeros out, and a +inf-ish log-sum-exp so that
-        # exp(s - lse) underflows to 0 in the backward kernels
-        _put(o_ref, 0, lax.div(acc, _over(some, acc)), h, d)
-        lse_ref[h] = lax.select(none, lax.full_like(l, 1e30),
-                                lax.add(m, lax.log(some)))
+    def emit(h, parts):
+        """Head h's output and lse from `parts`: the final (m, l, acc) of
+        its q rows, strip by strip. Each strip's lse is turned into a row
+        of lanes by itself, under the strips that follow it."""
+        outs, lses = [], []
+        for m, l, acc in parts:
+            none = lax.eq(l, 0.0)
+            some = lax.select(none, lax.full_like(l, 1.0), l)
+            # fully-masked rows: zeros out, and a +inf-ish log-sum-exp so
+            # that exp(s - lse) underflows to 0 in the backward kernels
+            outs.append(lax.div(acc, _over(some, acc)))
+            lses.append(_row(lax.select(none, lax.full_like(l, 1e30),
+                                        lax.add(m, lax.log(some)))))
+        _put(o_ref, 0, _stack(outs), h, d)
+        lse_ref[h] = _stack(lses, axis=1)
 
     def fold(strips):
         """Fold k rows `cols`, masked by `keep`, into the running stats
@@ -372,12 +407,12 @@ def _fa_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *scratch, d, block_q,
                              s if keep is None else _masked(s, keep),
                              _cut(v, cols), prec)
                    for (rows, cols, keep), s in zip(strips, scores)]
-            m, l, acc = (_stack(list(x)) for x in zip(*new))
             if scratch:
+                m, l, acc = (_stack(list(x)) for x in zip(*new))
                 m_sc[h], l_sc[h] = m, l
                 _put(acc_sc, slice(None), acc, h, d)
             else:
-                emit(h, m, l, acc)
+                emit(h, new)
         _each_head(heads, head)
 
     def full(masked):
@@ -399,7 +434,7 @@ def _fa_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *scratch, d, block_q,
         @pl.when(lax.eq(j, lax.sub(n_k, 1)))
         def _finish():
             _each_head(heads,
-                       lambda h: emit(h, m_sc[h], l_sc[h], acc_sc[:]))
+                       lambda h: emit(h, [(m_sc[h], l_sc[h], acc_sc[:])]))
 
 
 def _compiler_params():
@@ -414,7 +449,8 @@ def _interpret():
 
 # What the kernels take: arrays (N, T, C) whose rows hold C // d heads of d
 # lanes side by side, cut along C into blocks of _lane_block lanes; the row
-# vectors (lse, dlse, delta) as (N * C // d, T, 1) f32, a head a row. A caller
+# vectors (lse, dlse, delta) as (N * C // d, 1, T) f32, a head a row of
+# lanes (_row_spec). A caller
 # with (B, T, H, D) gets there by one of two routes, chosen from the shape
 # alone (_direct): its FREE reshape (B, T, H * D), where a 128-lane block
 # holds whole heads, so that no copy stands round a call; or the transpose
@@ -451,9 +487,17 @@ def _result(x, like, direct):
         else x.reshape(B, H, T, D).transpose(0, 2, 1, 3)
 
 
+def _row_spec(g, n_p, block_q, q_axis):
+    """Block of a row vector (N * C // d, 1, Tq): the g heads of lane block
+    p of row b, q block `q_axis` (2 or 3) of the grid's indices."""
+    from jax.experimental import pallas as pl
+    return pl.BlockSpec((g, 1, block_q),
+                        lambda *at: (at[0] * n_p + at[1], 0, at[q_axis]))
+
+
 def _fa_forward(q, k, v, d, causal, sm_scale, block_q, block_k, interpret):
     """q, k, v: (N, T, C), rows of C // d heads. Returns (out, lse) with lse
-    the per-row log-sum-exp (N * C // d, T, 1) f32 the backward kernels
+    the per-row log-sum-exp (N * C // d, 1, T) f32 the backward kernels
     consume."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
@@ -479,15 +523,9 @@ def _fa_forward(q, k, v, d, causal, sm_scale, block_q, block_k, interpret):
         kern,
         grid=grid,
         in_specs=[of_q, of_k, of_k],
-        out_specs=[
-            of_q,
-            # trailing singleton: TPU block rules need the last two dims
-            # (block, 1) == (divisible-by-8, full-dim)
-            pl.BlockSpec((g, block_q, 1),
-                         lambda b, p, i, j: (b * n_p + p, i, 0)),
-        ],
+        out_specs=[of_q, _row_spec(g, n_p, block_q, 2)],
         out_shape=[jax.ShapeDtypeStruct((n, tq, c), q.dtype),
-                   jax.ShapeDtypeStruct((n * c // d, tq, 1), jnp.float32)],
+                   jax.ShapeDtypeStruct((n * c // d, 1, tq), jnp.float32)],
         scratch_shapes=[] if alone else [
             pltpu.VMEM((g, block_q, 1), jnp.float32),  # running max
             pltpu.VMEM((g, block_q, 1), jnp.float32),  # running sumexp
@@ -499,23 +537,25 @@ def _fa_forward(q, k, v, d, causal, sm_scale, block_q, block_k, interpret):
     )(q, k, v)
 
 
-def _fa_bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, out_ref,
-                      dlse_ref, dq_ref, delta_ref, acc_sc, *, d, block_q,
-                      block_k, plan, sm_scale):
+def _fa_bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, out_ref, *rest,
+                      d, block_q, block_k, plan, sm_scale):
     """dq for one q block, streaming k/v blocks (innermost grid dim):
       delta = rowsum(dO * O) - dlse   (computed HERE at j==0 — fused, so
                                  no separate XLA pass re-reads dO and O;
                                  dlse is the cotangent of the emitted
                                  lse — d lse/d s = p, so it enters ds
-                                 with the OPPOSITE sign of delta. Zero
-                                 for plain attention; nonzero when the
-                                 ring-attention merge consumes lse.)
+                                 with the OPPOSITE sign of delta. Plain
+                                 attention has none and passes no such
+                                 operand; the ring-attention merge
+                                 consumes lse and does.)
       p  = exp(s*scale - lse);  dp = dO V^T
       ds = p * (dp - delta);    dq = scale * sum_k ds K
     Matmuls keep input-dtype operands with f32 accumulation. delta is an
     output, for the dk/dv kernel to consume; its block stays where it is
     over the kv steps, which read it back. `plan` and the refs as in
-    _fa_kernel; the row vectors are (g, block_q, 1), a head each."""
+    _fa_kernel; the row vectors are (g, 1, block_q), a row of lanes a head,
+    turned into columns once a head a step. `rest`: dlse where the caller
+    has one, then the results dq, delta and the scratch acc (block_q, w)."""
     from jax import lax
     from jax.experimental import pallas as pl
 
@@ -525,6 +565,7 @@ def _fa_bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, out_ref,
     k_off = lax.mul(j, block_k)
     prec = _prec(q_ref.dtype)
     heads = q_ref.shape[2] // d
+    *dlse_ref, dq_ref, delta_ref, acc_sc = rest
     f32 = functools.partial(lax.convert_element_type,
                             new_dtype=jnp.float32)
 
@@ -534,9 +575,9 @@ def _fa_bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, out_ref,
 
         def head(h):
             both = _kept(lax.mul(f32(do_ref[0]), f32(out_ref[0])), h, d)
-            delta_ref[h] = lax.sub(
-                lax.expand_dims(lax.reduce_sum(both, (1,)), (1,)),
-                dlse_ref[h])
+            delta = _row(lax.expand_dims(lax.reduce_sum(both, (1,)), (1,)))
+            delta_ref[h] = lax.sub(delta, dlse_ref[0][h]) if dlse_ref \
+                else delta
         _each_head(heads, head)
 
     def add(strips):
@@ -552,7 +593,7 @@ def _fa_bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, out_ref,
             qs = _scaled(q_ref[0], sm_scale, h, d)
             do = _scaled(do_ref[0], 1.0, h, d)
             k, v = k_ref[0], v_ref[0]
-            lse, delta = lse_ref[h], delta_ref[h]
+            lse, delta = _col(lse_ref[h]), _col(delta_ref[h])
             first = [(_dot(_cut(qs, rows), _cut(k, cols), (1, 1), prec),
                       _dot(_cut(do, rows), _cut(v, cols), (1, 1), prec))
                      for rows, cols, _ in strips]
@@ -595,7 +636,8 @@ def _fa_bwd_dkv_kernel(k_ref, v_ref, q_ref, do_ref, lse_ref, delta_ref,
       p^T  = exp(s^T*scale - lse);     dv = sum_q p^T dO
       ds^T = p^T * (dp^T - delta);     dk = scale * sum_q ds^T Q
     `plan` and the refs as in _fa_kernel; the walk goes by k sub-block
-    here, over the q sub-blocks at and below the diagonal."""
+    here, over the q sub-blocks at and below the diagonal. The scores are
+    transposed, so lse and delta are read as the rows of lanes they are."""
     from jax import lax
     from jax.experimental import pallas as pl
 
@@ -629,13 +671,13 @@ def _fa_bwd_dkv_kernel(k_ref, v_ref, q_ref, do_ref, lse_ref, delta_ref,
             for (cols, rows, keep), (st, dpt) in zip(strips, first):
                 if keep is not None:
                     st = _masked(st, keep, lead=True)
-                pt = lax.exp(lax.sub(st, _under(_cut(lse, rows), st)))
+                pt = lax.exp(lax.sub(st, _under(lse, rows, st)))
                 # dO has this head's lanes alone, so pt . dO leaves the
                 # other heads' dv as it is; q has them all
                 dvs.append(_dot(lax.convert_element_type(pt, do.dtype),
                                 _cut(do, rows), (1, 0), prec))
                 dst = lax.mul(pt, lax.sub(dpt,
-                                          _under(_cut(delta, rows), dpt)))
+                                          _under(delta, rows, dpt)))
                 dks.append(_dot(lax.convert_element_type(dst, q.dtype),
                                 _cut(q, rows), (1, 0), prec))
             done = strips[-1][0][1]   # the strips' k rows run from 0 on
@@ -671,13 +713,14 @@ def _fa_bwd_dkv_kernel(k_ref, v_ref, q_ref, do_ref, lse_ref, delta_ref,
 
 def _fa_backward(q, k, v, do, lse, out, dlse, d, causal, sm_scale, block_q,
                  block_k, interpret):
-    """q, k, v, do, out: (N, T, C), rows of C // d heads; lse, dlse:
-    (N * C // d, Tq, 1) f32. Returns (dq, dk, dv) via the two flash
-    backward kernels — O(block * T) memory, scores recomputed from the
-    saved lse. delta = rowsum(dO*O) is computed INSIDE the dq kernel (per
-    q block, at its first kv step) and handed to the dk/dv kernel as an
-    output shaped like lse — one fewer full pass over dO and O than a
-    separate XLA delta computation."""
+    """q, k, v, do, out: (N, T, C), rows of C // d heads; lse, and dlse
+    where the caller has a cotangent for lse (None: the dq kernel takes no
+    such operand): (N * C // d, 1, Tq) f32. Returns (dq, dk, dv) via the
+    two flash backward kernels — O(block * T) memory, scores recomputed
+    from the saved lse. delta = rowsum(dO*O) is computed INSIDE the dq
+    kernel (per q block, at its first kv step) and handed to the dk/dv
+    kernel as an output shaped like lse — one fewer full pass over dO and O
+    than a separate XLA delta computation."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -687,30 +730,29 @@ def _fa_backward(q, k, v, do, lse, out, dlse, d, causal, sm_scale, block_q,
     g, n_p = w // d, c // w
     params = _compiler_params()
     plan = _causal_plan(tq, tk, block_q, block_k) if causal else None
-    row = jax.ShapeDtypeStruct((n * c // d, tq, 1), jnp.float32)
+    row = jax.ShapeDtypeStruct((n * c // d, 1, tq), jnp.float32)
+    dlse = [] if dlse is None else [dlse]
 
     of_q = pl.BlockSpec((1, block_q, w), lambda b, p, i, j: (b, i, p))
     of_k = pl.BlockSpec((1, block_k, w), lambda b, p, i, j: (b, j, p))
-    of_row = pl.BlockSpec((g, block_q, 1),
-                          lambda b, p, i, j: (b * n_p + p, i, 0))
+    of_row = _row_spec(g, n_p, block_q, 2)
     dq, delta = pl.pallas_call(
         functools.partial(_fa_bwd_dq_kernel, d=d, block_q=block_q,
                           block_k=block_k, plan=plan, sm_scale=sm_scale),
         grid=(n, n_p, tq // block_q, tk // block_k),
-        in_specs=[of_q, of_k, of_k, of_q, of_row, of_q, of_row],
+        in_specs=[of_q, of_k, of_k, of_q, of_row, of_q] + [of_row] * len(dlse),
         out_specs=[of_q, of_row],
         out_shape=[jax.ShapeDtypeStruct((n, tq, c), q.dtype), row],
         scratch_shapes=[pltpu.VMEM((block_q, w), jnp.float32)],
         compiler_params=params,
         interpret=interpret,
         name="flash_bwd_dq",
-    )(q, k, v, do, lse, out, dlse)
+    )(q, k, v, do, lse, out, *dlse)
 
     # the grid's last two axes swap: k blocks outside, q blocks inside
     of_q = pl.BlockSpec((1, block_q, w), lambda b, p, j, i: (b, i, p))
     of_k = pl.BlockSpec((1, block_k, w), lambda b, p, j, i: (b, j, p))
-    of_row = pl.BlockSpec((g, block_q, 1),
-                          lambda b, p, j, i: (b * n_p + p, i, 0))
+    of_row = _row_spec(g, n_p, block_q, 3)
     dk, dv = pl.pallas_call(
         functools.partial(_fa_bwd_dkv_kernel, d=d, block_q=block_q,
                           block_k=block_k, plan=plan, sm_scale=sm_scale),
@@ -800,10 +842,13 @@ def _route(heads, d):
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
 def _flash(q, k, v, causal, sm_scale):
-    return _flash_fwd_impl(q, k, v, causal, sm_scale)
+    return _flash_vjp_fwd(q, k, v, causal, sm_scale)[0]
 
 
-def _flash_fwd_impl(q, k, v, causal, sm_scale, want_lse=False):
+def _flash_fwd_impl(q, k, v, causal, sm_scale):
+    """(out, lse) as the forward kernel leaves them, out (N, T, C) by the
+    route _direct names; (out, None) from attention_reference, out in q's
+    layout, where no block fits."""
     from .ring_attention import attention_reference
 
     B, Tq, H, D = q.shape
@@ -814,23 +859,34 @@ def _flash_fwd_impl(q, k, v, causal, sm_scale, want_lse=False):
     # (docs/perf_notes.md). T <= 1024 is one block a head: _pick_block
     blocks = _blocks_for(Tq, Tk, D)
     if blocks is None:
-        out = attention_reference(q, k, v, causal=causal,
-                                  sm_scale=sm_scale)
-        return (out, None) if want_lse else out
+        return attention_reference(q, k, v, causal=causal,
+                                   sm_scale=sm_scale), None
     bq, bk = blocks
     direct = _route(H, D)
-    out, lse = _fa_forward(_operand(q, direct), _operand(k, direct),
-                           _operand(v, direct), D, causal, sm_scale, bq, bk,
-                           _interpret())
-    out = _result(out, q, direct)
-    return (out, lse) if want_lse else out
+    return _fa_forward(_operand(q, direct), _operand(k, direct),
+                       _operand(v, direct), D, causal, sm_scale, bq, bk,
+                       _interpret())
+
+
+# What the Pallas forward leaves for its backward beside q, k, v, by the names
+# a caller's `jax.checkpoint` policy can keep them under: with both kept, the
+# recomputed forward is dead code (TransformerLM's blocks keep them: a layer's
+# 66 MB at GPT-2 medium's sizes for a quarter of its recomputed time). The
+# output is kept as the kernel wrote it, (N, T, C): the caller's (B, T, H, D)
+# is another array to the chip's tiles wherever D is under 128 lanes, and XLA
+# copied a residual of that shape twice a layer on its way to the backward
+# kernels (PERF.md section 6, PR 35).
+RESIDUAL_NAMES = ("flash_out", "flash_lse")
 
 
 def _flash_vjp_fwd(q, k, v, causal, sm_scale):
-    out, lse = _flash_fwd_impl(q, k, v, causal, sm_scale, want_lse=True)
-    # the scan fallback recomputes everything from q/k/v — keeping `out`
-    # alive would cost an activation-sized residual for nothing
-    return out, (q, k, v, out if lse is not None else None, lse)
+    out, lse = _flash_fwd_impl(q, k, v, causal, sm_scale)
+    if lse is None:
+        # the scan fallback recomputes everything from q/k/v — keeping `out`
+        # alive would cost an activation-sized residual for nothing
+        return out, (q, k, v, None, None)
+    out, lse = map(checkpoint_name, (out, lse), RESIDUAL_NAMES)
+    return _result(out, q, _direct(*q.shape[2:])), (q, k, v, out, lse)
 
 
 def _flash_vjp_bwd(causal, sm_scale, res, g):
@@ -851,9 +907,8 @@ def _flash_vjp_bwd(causal, sm_scale, res, g):
         bk = _pick_block(Tk)
         direct = _direct(H, D)
         dq, dk, dv = _fa_backward(
-            *(_operand(x, direct) for x in (q, k, v, g)), lse,
-            _operand(out, direct), jnp.zeros_like(lse), D, causal, sm_scale,
-            bq, bk, _interpret())
+            *(_operand(x, direct) for x in (q, k, v, g)), lse, out, None, D,
+            causal, sm_scale, bq, bk, _interpret())
         return (_result(dq, q, direct), _result(dk, k, direct),
                 _result(dv, v, direct))
     bq = _pick_block(Tq, 256)
@@ -957,8 +1012,8 @@ def _flash_hop_vjp_bwd(causal, sm_scale, res, cts):
     bq = _pick_block(Tq)
     bk = _pick_block(Tk)
     lse_kern = jnp.where(jnp.isfinite(lse), lse, 1e30).reshape(
-        B * H, Tq, 1).astype(jnp.float32)
-    dlse = g_lse.reshape(B * H, Tq, 1).astype(jnp.float32)
+        B * H, 1, Tq).astype(jnp.float32)
+    dlse = g_lse.reshape(B * H, 1, Tq).astype(jnp.float32)
     direct = _direct(H, D)
     dq, dk, dv = _fa_backward(
         *(_operand(x, direct) for x in (q, k, v, g_out.astype(q.dtype))),

@@ -490,3 +490,101 @@ def test_causal_walk_output_matches_reference_elementwise():
         np.asarray(flash_attention(q, k, v, causal=True)),
         np.asarray(attention_reference(q, k, v, causal=True)),
         rtol=1e-4, atol=1e-5)
+
+
+# -- what the forward leaves for its backward (PR 35) -------------------------
+# (N, T, C, d, causal): lse, delta and dlse are (N * C // d, 1, T) f32, a
+# head a row of lanes, whatever the heads a block (g) and the grid.
+_ROW_CASES = {
+    "g2_one_block": (2, 256, 256, 64, True),
+    "g2_walked_block": (1, 1024, 128, 64, True),
+    "g2_several_blocks": (1, 2048, 128, 64, True),
+    "g1_one_block": (2, 256, 128, 128, False),
+    "g1_several_blocks": (1, 2048, 128, 128, True),
+    "g1_half_a_tile": (3, 512, 64, 64, True),      # the transposed route's
+}
+
+
+def _kernel_operands(case, dtype=np.float32):
+    import importlib
+    fa = importlib.import_module(
+        "incubator_mxnet_tpu.parallel.flash_attention")
+    n, t, c, d, causal = _ROW_CASES[case]
+    rng = np.random.RandomState(35)
+    q, k, v, do = (jnp.asarray(rng.randn(n, t, c).astype(np.float32) * 0.5
+                               ).astype(dtype) for _ in range(4))
+    block = fa._pick_block(t)
+    return fa, (q, k, v, do), (d, causal, 1.0 / np.sqrt(d), block, block,
+                               fa._interpret())
+
+
+@pytest.mark.parametrize("case", list(_ROW_CASES))
+def test_the_row_vectors_are_rows_of_lanes(case):
+    """lse comes out of the forward kernel as (N * C // d, 1, T), head h of
+    row b at b * (C // d) + h, and holds the dense log-sum-exp."""
+    fa, (q, k, v, _), static = _kernel_operands(case)
+    n, t, c, d, causal = _ROW_CASES[case]
+    out, lse = fa._fa_forward(q, k, v, *static)
+    assert out.shape == (n, t, c)
+    assert lse.shape == (n * c // d, 1, t) and lse.dtype == jnp.float32
+    heads = lambda x: x.reshape(n, t, c // d, d)
+    s = jnp.einsum("bqhd,bkhd->bhqk", heads(q), heads(k)) / np.sqrt(d)
+    if causal:
+        s = jnp.where(jnp.tril(jnp.ones((t, t), bool)), s, -jnp.inf)
+    np.testing.assert_allclose(
+        np.asarray(lse).reshape(n, c // d, t),
+        np.asarray(jax.scipy.special.logsumexp(s, axis=-1)),
+        rtol=1e-5, atol=1e-5)
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("case", ["g2_walked_block", "g2_several_blocks",
+                                  "g1_one_block"])
+def test_no_lse_cotangent_is_a_zero_one_bit_for_bit(case, dtype):
+    """Plain attention hands the dq kernel no dlse operand; a ring hop
+    hands it one. With zeros in it the three gradients are the same bits."""
+    fa, (q, k, v, do), static = _kernel_operands(case, dtype)
+    out, lse = fa._fa_forward(q, k, v, *static)
+    none = fa._fa_backward(q, k, v, do, lse, out, None, *static)
+    zeros = fa._fa_backward(q, k, v, do, lse, out, jnp.zeros_like(lse),
+                            *static)
+    for a, b in zip(none, zeros):
+        assert a.dtype == b.dtype and a.shape == q.shape
+        np.testing.assert_array_equal(np.asarray(a, np.float32),
+                                      np.asarray(b, np.float32))
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+def test_a_block_that_keeps_the_flash_residuals_is_the_unkept_block(dtype):
+    """Value and gradient of one `jax.checkpoint`ed TransformerLM block
+    under the model's own policy (the kernel's output and lse kept, the
+    forward kernel traced once) against a checkpoint that keeps nothing
+    (traced twice): the kept residuals are the same kernel's on the same
+    operands, so every number is the same bits."""
+    from incubator_mxnet_tpu.models.transformer import (TransformerConfig,
+                                                        TransformerLM,
+                                                        _remat_policy)
+    model = TransformerLM(TransformerConfig(
+        vocab_size=64, d_model=256, n_heads=4, n_layers=1, d_ff=128,
+        max_len=256, dtype=dtype, remat=True, flash_attention=True))
+    params = {k: v for k, v in
+              model.init_params(jax.random.PRNGKey(0)).items()
+              if k.startswith("layer0_")}
+    x = jnp.asarray(np.random.RandomState(5).randn(2, 256, 256) * 0.5,
+                    dtype)
+    body = lambda p, y: model._block(p, "layer0_", y, None)
+
+    def value_and_grad(policy):
+        block = jax.checkpoint(body, policy=policy)
+        f = jax.value_and_grad(
+            lambda p, y: jnp.sum(block(p, y).astype(jnp.float32) ** 2),
+            argnums=(0, 1))
+        return f(params, x), str(jax.make_jaxpr(f)(params, x))
+    kept, kept_jaxpr = value_and_grad(_remat_policy(None))
+    unkept, unkept_jaxpr = value_and_grad(None)
+    assert kept_jaxpr.count("name=flash_fwd") == 1
+    assert unkept_jaxpr.count("name=flash_fwd") == 2
+    for a, b in zip(jax.tree_util.tree_leaves(kept),
+                    jax.tree_util.tree_leaves(unkept)):
+        np.testing.assert_array_equal(np.asarray(a, np.float32),
+                                      np.asarray(b, np.float32))

@@ -91,13 +91,23 @@ def test_trainstep_operations_carry_the_programs_names(trainstep_names,
     r"^jit\(step\)/transpose\(jvp\(forward\)\)/layer1/.*/checkpoint/"
     r"rematted_computation/attn/",
     r"/jvp\(forward\)/layer0/attn/flash_fwd/",
-    r"/rematted_computation/attn/flash_fwd/",
+    r"/rematted_computation/mlp/",
     r"/attn/flash_bwd_dq/",
     r"/attn/flash_bwd_dkv/",
 ])
 def test_lm_step_operations_carry_the_programs_names(lm_names, pattern):
     assert any(re.search(pattern, n) for n in lm_names), \
         sorted(lm_names)[:40]
+
+
+def test_a_lm_step_runs_no_flash_forward_twice(lm_names):
+    """The block's checkpoint keeps the kernel's output and lse (PR 35), so
+    the recomputed forward holds the projections and no `flash_fwd`."""
+    again = sorted(n for n in lm_names
+                   if re.search(r"/rematted_computation/.*flash_fwd", n))
+    assert again == []
+    assert any(re.search(r"/rematted_computation/attn/", n)
+               for n in lm_names)
 
 
 @pytest.mark.parametrize("which", ["trainstep_names", "lm_names"])
@@ -239,23 +249,55 @@ def one_chip(v5e):
     return SingleDeviceSharding(v5e.devices[0])
 
 
-@pytest.mark.parametrize("kernels", [("flash_fwd",),
-                                     ("flash_bwd_dq", "flash_bwd_dkv")])
-def test_flash_kernels_keep_their_names_in_a_tpu_program(one_chip, kernels):
+_TILED = re.compile(r"\b(f32)\[([\d,]*)\]\{[\d,]*:T\((\d+),(\d+)\)")
+
+
+def _padded(line):
+    """The f32 arrays named on a compiled instruction's line whose tiled
+    layout holds more than twice their shape's numbers: [(shape, times)]."""
+    found = []
+    for m in _TILED.finditer(line):
+        dims = [int(x) for x in m.group(2).split(",") if x]
+        if len(dims) < 2:
+            continue
+        a, b = int(m.group(3)), int(m.group(4))
+        times = (-(-dims[-2] // a) * a * -(-dims[-1] // b) * b) \
+            / (dims[-2] * dims[-1])
+        if times > 2:
+            found.append((m.group(0), times))
+    return found
+
+
+def test_the_padding_reader_tells_a_column_from_a_row():
+    assert _padded("f32[512,1024,1]{2,1,0:T(8,128)} custom-call(") \
+        == [("f32[512,1024,1]{2,1,0:T(8,128)", 128.0)]
+    assert not _padded("(bf16[32,1024,1024]{2,1,0:T(8,128)(2,1)}, "
+                       "f32[512,1,1024]{2,1,0:T(1,128)}) custom-call(")
+
+
+@pytest.mark.parametrize("kernels, dlse", [
+    (("flash_fwd",), False),
+    (("flash_bwd_dq", "flash_bwd_dkv"), False),
+    (("flash_bwd_dq", "flash_bwd_dkv"), True)],
+    ids=["flash_fwd", "flash_bwd", "flash_bwd_hop"])
+def test_flash_kernels_keep_their_names_in_a_tpu_program(one_chip, kernels,
+                                                         dlse):
     """GPT-2 medium's shapes, (B, T, H * D) = (32, 1024, 16 * 64) in 1024 x
     1024 blocks of two heads: each kernel is ONE custom call whose
     instruction and `op_name` carry the kernel's name under the caller's
     scopes. `flash_time_share` reads the opcode, `flash_*_roofline` the name
     and the operand shapes: q, k, v first, three dimensions each, from which
     the benchmark counts what it counted from (B * H, T, D) = (512, 1024,
-    64)."""
+    64). The row vectors are (B * H, 1, T), rows of lanes that no tile pads;
+    plain attention's dq call has six operands, a ring hop's a seventh, the
+    cotangent of lse."""
     import importlib
     from perfbench import op_scopes
     fa = importlib.import_module(     # the package exports a function by
         "incubator_mxnet_tpu.parallel.flash_attention")     # the same name
     big = jax.ShapeDtypeStruct((32, 1024, 1024), jnp.bfloat16,
                                sharding=one_chip)
-    row = jax.ShapeDtypeStruct((512, 1024, 1), jnp.float32,
+    vec = jax.ShapeDtypeStruct((512, 1, 1024), jnp.float32,
                                sharding=one_chip)
 
     def fwd(q, k, v):
@@ -263,14 +305,15 @@ def test_flash_kernels_keep_their_names_in_a_tpu_program(one_chip, kernels):
             return fa._fa_forward(q, k, v, 64, True, 0.125, 1024, 1024,
                                   False)
 
-    def bwd(q, k, v, do, lse, out, dlse):
+    def bwd(q, k, v, do, lse, out, dlse=None):
         with jax.named_scope("forward"), jax.named_scope("attn"):
             return fa._fa_backward(q, k, v, do, lse, out, dlse, 64, True,
                                    0.125, 1024, 1024, False)
     if kernels == ("flash_fwd",):
         lowered = jax.jit(fwd).lower(big, big, big)
     else:
-        lowered = jax.jit(bwd).lower(big, big, big, big, row, big, row)
+        lowered = jax.jit(bwd).lower(big, big, big, big, vec, big,
+                                     *[vec] * dlse)
     text = lowered.compile().as_text()
     calls = [ln for ln in text.splitlines()
              if 'custom_call_target="tpu_custom_call"' in ln]
@@ -280,6 +323,7 @@ def test_flash_kernels_keep_their_names_in_a_tpu_program(one_chip, kernels):
         assert re.search(rf'op_name="jit\(\w+\)/forward/attn/{name}/'
                          r'pallas_call"', line), line[-400:]
         assert "bf16[32,1024,1024]" in line.split("custom-call(")[1]
+        assert not _padded(line), _padded(line)
     # the benchmark's count of the first call, from the compiled call's own
     # operand and result shapes, as `op_scopes` reads them off a trace
     shapes = lambda text: [f"{m.group(1)}[{m.group(2)}]"
@@ -299,8 +343,11 @@ def test_flash_kernels_keep_their_names_in_a_tpu_program(one_chip, kernels):
         == (2 if backward else 1) * 68_719_476_736
     assert (least, bound) == (flops / 197e12, "FLOPs")
     moved = sum(map(op_scopes.shape_bytes, row["operands"] + row["results"]))
-    # forward: q, k, v, o at 64 MiB and lse, 2 MiB by its shape: 270 MB
-    assert moved == (4 * 64 + 2 if not backward else 6 * 64 + 3 * 2) * 2**20
+    # forward: q, k, v, o at 64 MiB and lse, 2 MiB by its shape: 270 MB; dq:
+    # q, k, v, dO, O, dq, and lse, delta and a hop's dlse
+    assert len(row["operands"]) == (3 if not backward else 6 + dlse)
+    assert moved == (4 * 64 + 2 if not backward
+                     else 6 * 64 + (2 + dlse) * 2) * 2**20
 
 
 _QKV_SHAPED = ("bf16[32,1024,16,64]", "bf16[32,16,1024,64]",
@@ -311,11 +358,14 @@ def test_no_copy_stands_round_a_flash_call_in_a_tpu_program(one_chip,
                                                             monkeypatch):
     """Value and gradient of one remat `TransformerLM` block at GPT-2
     medium's widths, batch 32 x 1,024, compiled for the chip: the three
-    flash kernels by name (the forward twice: once recomputed), and no
-    `transpose` or `copy`, alone or as a fusion, over an array shaped like
-    q, k, v or O in either of the layouts the kernels took before PR 33."""
+    flash kernels by name, each once (the block's checkpoint keeps the
+    forward's output and lse, so it is not run again), no `transpose` or
+    `copy`, alone or as a fusion, over an array shaped like q, k, v or O in
+    either of the layouts the kernels took before PR 33, and no f32 operand
+    or result of a kernel that its tiles pad to over twice its numbers."""
     from incubator_mxnet_tpu.models.transformer import (TransformerConfig,
-                                                        TransformerLM)
+                                                        TransformerLM,
+                                                        _remat_policy)
     # the kernels ask the backend whether to interpret: compile them
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     model = TransformerLM(TransformerConfig(
@@ -327,13 +377,14 @@ def test_no_copy_stands_round_a_flash_call_in_a_tpu_program(one_chip,
     x = jax.ShapeDtypeStruct((32, 1024, 1024), jnp.bfloat16,
                              sharding=one_chip)
     block = jax.checkpoint(
-        lambda p, y: model._block(p, "layer0_", y, None))
+        lambda p, y: model._block(p, "layer0_", y, None),
+        policy=_remat_policy(None))
 
     def loss(p, y):
         return block(p, y).astype(jnp.float32).sum()
     text = jax.jit(jax.value_and_grad(loss, argnums=(0, 1))).lower(
         params, x).compile().as_text()
-    calls, moves = [], []
+    calls, moves, padded = [], [], []
     for ln in text.splitlines():
         # %name = type opcode(..: the type may be a tuple with spaces in it
         m = re.match(r"\s*(?:ROOT )?%([\w.\-]+) = (\(.*?\)|\S+) ([\w\-]+)\(",
@@ -343,13 +394,62 @@ def test_no_copy_stands_round_a_flash_call_in_a_tpu_program(one_chip,
         name, shape, opcode = m.groups()
         if 'custom_call_target="tpu_custom_call"' in ln:
             calls.append(name.split(".")[0])
+            padded += _padded(ln)
         moving = opcode in ("transpose", "copy") or (
             opcode == "fusion" and re.search("transpose|copy", name))
         if moving and shape.startswith(_QKV_SHAPED):
             moves.append(ln.strip()[:200])
-    assert sorted(calls) == ["flash_bwd_dkv", "flash_bwd_dq", "flash_fwd",
+    assert sorted(calls) == ["flash_bwd_dkv", "flash_bwd_dq",
                              "flash_fwd"], calls
     assert not moves, moves
+    assert not padded, padded
+
+
+def test_keeping_the_flash_residuals_costs_no_memory_in_a_tpu_program(
+        one_chip, monkeypatch):
+    """Value and gradient of `TransformerLM.loss` at GPT-2 medium's sizes,
+    24 layers at 32 x 1,024, compiled for the chip twice: as the model
+    builds its blocks' checkpoints, and with checkpoints that keep nothing.
+    Kept: each of a layer's three kernels once (the unkept program runs the
+    forward twice), no row vector that its tiles pad, no array of zeros in
+    the cotangent's place, no copy of an activation that the unkept program
+    does not make (the output kept as (B, T, H, D) was copied twice a layer:
+    to the chip's tiles that is another array than the kernel's (N, T, C)),
+    and temporaries within 0.3 GB of the unkept program's (the loss's f32
+    logits set the peak; lse as a column of 268 MB a layer made this +4.1
+    GB)."""
+    from incubator_mxnet_tpu.models import transformer
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    model = transformer.TransformerLM(transformer.TransformerConfig(
+        vocab_size=50257, d_model=1024, n_heads=16, n_layers=24, d_ff=4096,
+        max_len=1024, remat=True, flash_attention=True))
+    shapes = jax.eval_shape(model.init_params, jax.random.PRNGKey(0))
+    params = {k: jax.ShapeDtypeStruct(v.shape, v.dtype, sharding=one_chip)
+              for k, v in shapes.items()}
+    tokens = jax.ShapeDtypeStruct((32, 1024), jnp.int32, sharding=one_chip)
+
+    def compiled():
+        done = jax.jit(jax.value_and_grad(model.loss)).lower(
+            params, tokens, tokens).compile()
+        text = done.as_text()
+        calls = [ln for ln in text.splitlines()
+                 if 'custom_call_target="tpu_custom_call"' in ln]
+        names = [re.match(r"\s*(?:ROOT )?%(\w+?)(\.\d+)? = ", ln).group(1)
+                 for ln in calls]
+        return ({n: names.count(n) for n in set(names)}, calls,
+                done.memory_analysis().temp_size_in_bytes, text)
+    copies = lambda text: len(re.findall(
+        r"= \w+\[32,1024,(1024|16,64)\]\S* copy\(", text))
+    counts, calls, kept, text = compiled()
+    assert counts == {"flash_fwd": 24, "flash_bwd_dq": 24,
+                      "flash_bwd_dkv": 24}
+    assert not [p for ln in calls for p in _padded(ln)]
+    assert not re.search(r"= f32\[512,1,1024\]\S* broadcast\(", text)
+    monkeypatch.setattr(transformer, "_remat_policy", lambda name: None)
+    counts, _, unkept, unkept_text = compiled()
+    assert counts["flash_fwd"] == 48
+    assert copies(text) <= copies(unkept_text)
+    assert abs(kept - unkept) < 0.3e9, (kept, unkept)
 
 
 @pytest.mark.parametrize("layer", [0, 1], ids=["sparse", "lightning"])
@@ -364,7 +464,8 @@ def test_a_layer_lists_blocks_keep_their_names_in_a_tpu_program(
     array."""
     from perfbench import cells, op_scopes
     from perfbench.families import minicpm_sala
-    from incubator_mxnet_tpu.models.transformer import TransformerLM
+    from incubator_mxnet_tpu.models.transformer import (TransformerLM,
+                                                        _remat_policy)
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     cell = cells.resolve("minicpm-sala.train-8k")
     model = TransformerLM(minicpm_sala.model_config(cell.config,
@@ -375,7 +476,8 @@ def test_a_layer_lists_blocks_keep_their_names_in_a_tpu_program(
               for k, v in shapes.items() if k.startswith(prefix)}
     x = jax.ShapeDtypeStruct((1, 8192, 4096), jnp.bfloat16,
                              sharding=one_chip)
-    block = jax.checkpoint(lambda p, y: model._block(p, prefix, y, None))
+    block = jax.checkpoint(lambda p, y: model._block(p, prefix, y, None),
+                           policy=_remat_policy(None))
 
     def loss(p, y):
         with jax.named_scope("forward"):
@@ -400,7 +502,8 @@ def test_a_layer_lists_blocks_keep_their_names_in_a_tpu_program(
     kernel = lambda ln: re.match(r"\s*(?:ROOT )?%(\w+?)(\.\d+)? = ",
                                  ln).group(1)
     assert sorted(map(kernel, calls)) == [
-        "flash_bwd_dkv", "flash_bwd_dq", "flash_fwd", "flash_fwd"]
+        "flash_bwd_dkv", "flash_bwd_dq", "flash_fwd"]
+    assert not [p for ln in calls for p in _padded(ln)]
     assert under("attn", "sparse_attn", "flash_fwd")
     assert not under("block_select")            # T = dense_len: dense
     shaped = lambda text: [f"{m.group(1)}[{m.group(2)}]"
