@@ -1,10 +1,11 @@
 """Device time of the three flash kernels, causal, by sub-block edge.
 
     python benchmark/flash_sweep.py [--subs 0,128,256,512] [--calls 10]
-        [--shapes 32x16x1024,16x16x2048,4x16x8192,1x32x8192x128]
+        [--shapes 32x16x1024,16x16x2048,4x16x8192,1x32x8192x128,1x72x8192x128w512]
         [--out _chip/flash_sweep]
 
-One line of JSON per (shape B x H x T, or B x H x T x D; edge): microseconds
+One line of JSON per (shape B x H x T, or B x H x T x D, `w` and a window
+after it for a windowed call, whose kernels are `flash_win_*`; edge): microseconds
 a call of `flash_fwd`, `flash_bwd_dq` and `flash_bwd_dkv` on (B, T, H, D)
 bf16, D = 64 unless given, the layout the model hands `flash_attention`, and
 `other_us`: what else the device ran for one forward and one backward call,
@@ -92,11 +93,14 @@ def main():
             fa._SUB = sub
         worst = max(check(fa, jax, jnp, np, t) for t in (1024, 2048))
         for shape in args.shapes.split(","):
-            b, h, t, d = (tuple(map(int, shape.split("x"))) + (64,))[:4]
+            dims, _, window = shape.partition("w")
+            b, h, t, d = (tuple(map(int, dims.split("x"))) + (64,))[:4]
             keys = jax.random.split(jax.random.PRNGKey(t), 4)
             q, k, v, do = (jax.random.normal(key, (b, t, h, d),
                                              jnp.bfloat16) for key in keys)
-            attn = functools.partial(fa.flash_attention, causal=True)
+            attn = functools.partial(
+                fa.flash_attention, causal=True,
+                **({"window": int(window)} if window else {}))
             # one forward call, and one backward call from its residuals
             fwd = jax.jit(lambda q, k, v: jax.vjp(attn, q, k, v))
             bwd = jax.jit(lambda pull, do: pull(do))
@@ -110,6 +114,7 @@ def main():
             us, other = kernel_us(os.path.join(args.out, f"{shape}-{sub}"),
                                   body, args.calls)
             print(json.dumps({"b": b, "h": h, "t": t, "d": d, "sub": sub,
+                              "window": int(window) if window else None,
                               "block": fa._pick_block(t),
                               "check_rel_err": worst, "us_a_call": us,
                               "other_us": other,

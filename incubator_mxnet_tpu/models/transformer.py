@@ -14,6 +14,7 @@ from __future__ import annotations
 
 import dataclasses
 import functools
+import itertools
 import math
 
 import jax
@@ -50,7 +51,50 @@ def _remat_policy(name):
                          f"one of {sorted(table)}")
     return cp.save_from_both_policies(table[name], flash)
 
-__all__ = ["TransformerConfig", "TransformerLM"]
+__all__ = ["TransformerConfig", "TransformerLM", "Rotary", "GQA", "Experts"]
+
+
+@dataclasses.dataclass(frozen=True)
+class Rotary:
+    """Rotary embedding of a "gqa" layer: over the first `share` of each
+    head (the halves of that part paired, as _rope pairs a whole head's),
+    base `theta`. `yarn` = (factor, original_len, beta_fast, beta_slow,
+    attention_factor): YaRN's frequencies (yarn_inv_freq), cos and sin
+    times the attention factor."""
+    theta: float = 10000.0
+    share: float = 1.0
+    yarn: tuple | None = None
+
+
+@dataclasses.dataclass(frozen=True)
+class GQA:
+    """One "gqa" layer: `heads` query heads over the model's n_kv_heads K/V
+    heads of head_dim; causal, and with a `window` query i reads keys j with
+    i - j < window; a sigmoid output gate A HEAD (a (d_model, heads)
+    projection of the block's input) before the output projection."""
+    heads: int
+    window: int | None = None
+    rotary: Rotary = Rotary()
+
+
+@dataclasses.dataclass(frozen=True)
+class Experts:
+    """An "experts" layer's MLP: a router over `count` experts, a token's
+    `per_token` largest scores (`score`: "sigmoid" | "softmax", float32),
+    weights scaling * s / sum(s) (`norm_topk`) or scaling * s; of the
+    chosen, this chip computes the experts [held[0], held[0] + held[1]),
+    each a SwiGLU of `width` (parallel/moe.moe_routed: `rows` the one
+    buffer's rows); beside them one ungated shared SwiGLU of `shared_width`
+    (0: none)."""
+    count: int
+    held: tuple
+    per_token: int
+    width: int
+    shared_width: int = 0
+    score: str = "sigmoid"
+    scaling: float = 1.0
+    norm_topk: bool = True
+    rows: int = 0
 
 
 @dataclasses.dataclass(frozen=True)
@@ -65,9 +109,13 @@ class TransformerConfig:
     | "mha" | causal softmax attention, n_heads heads of d_model / n_heads; ring attention over `sp`, Megatron heads over `tp` | n_heads, flash_attention |
     | "sparse" | grouped-query causal softmax attention, n_heads query heads over n_kv_heads K/V heads of d_model / n_heads, no rotary, sigmoid output gate; up to select.dense_len positions dense (the flash kernels), beyond that over the blocks InfLLM-v2 selection picks (parallel/sparse_attention.py) | n_heads, n_kv_heads, select, flash_attention |
     | "lightning" | decayed linear attention in chunks (parallel/linear_attention.py): n_heads heads of d_model / n_heads, RMSNorm on each head of q and k, rotary over the whole head, decay exp(-2^(-8 (h + 1) / n_heads)), RMSNorm over all heads of the result, sigmoid output gate | n_heads, rope_theta |
+    | "gqa" | grouped-query causal softmax attention, the layer's own count of query heads over n_kv_heads K/V heads of head_dim, rotary over a share of the head (YaRN's frequencies where given), full or windowed (the flash kernels; the windowed ones run the band alone), a sigmoid output gate a head | gqa[layer] (GQA), n_kv_heads, head_dim, flash_attention |
 
-    Every layer shares `norm`, `mlp`, `residual_scale`; a model with no
-    "mha" layer needs no `learned_positions`."""
+    `mlps` names each layer's MLP, one word a layer (empty: "dense" in
+    every layer): "dense" is `mlp` at d_ff, "experts" the routed and shared
+    experts of `experts` (Experts). Every layer shares `norm`,
+    `residual_scale`; a model with no "mha" layer needs no
+    `learned_positions`."""
     vocab_size: int = 32000
     d_model: int = 512
     n_heads: int = 8
@@ -104,9 +152,19 @@ class TransformerConfig:
     embed_scale: float = 1.0        # x = embed_scale * E[token]
     residual_scale: float = 1.0     # x += residual_scale * f(norm(x))
     logit_scale: float = 1.0        # logits = (logit_scale * norm(x)) W
+    head_dim: int | None = None     # None: d_model // n_heads
+    gqa: tuple = ()                 # "gqa": a GQA a layer (None elsewhere)
+    mlps: tuple = ()                # a word a layer: "dense" | "experts"
+    experts: Experts | None = None  # what an "experts" layer holds
 
 
-MIXERS = ("mha", "sparse", "lightning")
+MIXERS = ("mha", "sparse", "lightning", "gqa")
+MLPS = ("dense", "experts")
+
+
+def _layer_of(prefix):
+    """The index i of a block's parameter prefix "layer{i}_"."""
+    return int(prefix[len("layer"):-1])
 
 
 def _rms(x, g, eps):
@@ -137,6 +195,48 @@ def _rope(x, positions, theta):
                            -1).astype(x.dtype)
 
 
+def yarn_inv_freq(dim, theta, factor, original_len, beta_fast, beta_slow):
+    """YaRN's rotary frequencies over `dim` rotated lanes (dim / 2 of
+    them), as numpy float64: 1 / theta^(2i/dim) (extrapolation) where a
+    frequency turns more than beta_fast times over original_len positions,
+    that over `factor` (interpolation) where it turns fewer than beta_slow
+    times, a linear ramp over i between the two (Peng et al.,
+    arXiv:2309.00071; the bounds floored and ceiled as the published code
+    does)."""
+    import numpy as np
+    extra = 1.0 / theta ** (np.arange(0, dim, 2, dtype=np.float64) / dim)
+
+    def turns_at(turns):
+        return dim * math.log(original_len / (turns * 2 * math.pi)) \
+            / (2 * math.log(theta))
+    low = max(math.floor(turns_at(beta_fast)), 0)
+    high = min(math.ceil(turns_at(beta_slow)), dim - 1)
+    ramp = np.clip((np.arange(dim // 2) - low) / max(high - low, 1e-3), 0, 1)
+    return extra / factor * ramp + extra * (1 - ramp)
+
+
+def _rope_part(x, positions, rotary):
+    """x (B, T, H, D) turned as `rotary` (Rotary) says: the first share of
+    each head, halves paired, in float32."""
+    import numpy as np
+    rot = int(x.shape[-1] * rotary.share)
+    if rotary.yarn is None and rot == x.shape[-1]:
+        return _rope(x, positions, rotary.theta)
+    scale = 1.0
+    if rotary.yarn is None:
+        freq = 1.0 / rotary.theta ** (np.arange(0, rot, 2) / rot)
+    else:
+        *bounds, scale = rotary.yarn
+        freq = yarn_inv_freq(rot, rotary.theta, *bounds)
+    angle = positions.astype(jnp.float32)[:, None] * \
+        jnp.asarray(freq, jnp.float32)[None, :]
+    cos, sin = (scale * f(angle)[:, None, :] for f in (jnp.cos, jnp.sin))
+    x32 = x.astype(jnp.float32)
+    x1, x2, rest = jnp.split(x32, [rot // 2, rot], axis=-1)
+    return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin, rest],
+                           -1).astype(x.dtype)
+
+
 class TransformerLM:
     def __init__(self, config: TransformerConfig):
         self.cfg = config
@@ -145,7 +245,23 @@ class TransformerLM:
                 set(self.mixers) - set(MIXERS):
             raise ValueError(f"mixers {self.mixers}: {config.n_layers} "
                              f"words of {MIXERS}")
-        self.head_dim = config.d_model // config.n_heads
+        self.mlps = tuple(config.mlps) or ("dense",) * config.n_layers
+        if len(self.mlps) != config.n_layers or set(self.mlps) - set(MLPS):
+            raise ValueError(f"mlps {self.mlps}: {config.n_layers} words "
+                             f"of {MLPS}")
+        if "experts" in self.mlps and (config.experts is None
+                                       or config.mlp != "swiglu"):
+            raise ValueError("an \"experts\" layer wants `experts` (Experts) "
+                             "and mlp=\"swiglu\"")
+        if "gqa" in self.mixers and (len(config.gqa) != config.n_layers or any(
+                not isinstance(g, GQA) for g, m in zip(config.gqa,
+                                                       self.mixers)
+                if m == "gqa")):
+            raise ValueError(f"a \"gqa\" layer wants its GQA in `gqa`, one "
+                             f"entry a layer: {config.gqa}")
+        self.head_dim = config.head_dim or config.d_model // config.n_heads
+        self.expert_layers = tuple(i for i, m in enumerate(self.mlps)
+                                   if m == "experts")
 
     # -- parameters ---------------------------------------------------------
     def _shapes(self):
@@ -166,6 +282,13 @@ class TransformerLM:
             out += norm(p + "ln1")
             if kind == "mha":
                 out += [(p + w, (d, d), d) for w in ("wq", "wk", "wv", "wo")]
+            elif kind == "gqa":
+                wide = cfg.gqa[i].heads * hd
+                kv = (cfg.n_kv_heads or cfg.n_heads) * hd
+                out += [(p + "wq", (d, wide), d), (p + "wk", (d, kv), d),
+                        (p + "wv", (d, kv), d),
+                        (p + "wg", (d, cfg.gqa[i].heads), d),
+                        (p + "wo", (wide, d), wide)]
             else:
                 kv = d if kind == "lightning" else \
                     (cfg.n_kv_heads or cfg.n_heads) * hd
@@ -177,6 +300,19 @@ class TransformerLM:
                             (p + "o_norm_g", (d,), None)]
                 out.append((p + "wo", (d, d), d))
             out += norm(p + "ln2")
+            if self.mlps[i] == "experts":
+                ex = cfg.experts
+                n, fe, fs = ex.held[1], ex.width, ex.shared_width
+                # a held expert's gate and up projections side by side:
+                # one grouped product takes both
+                out += [(p + "router", (d, ex.count), d),
+                        (p + "e_gate_in", (n, d, 2 * fe), d),
+                        (p + "e_out", (n, fe, d), fe)]
+                if fs:
+                    out += [(p + "s_gate", (d, fs), d),
+                            (p + "s_in", (d, fs), d),
+                            (p + "s_out", (fs, d), fs)]
+                continue
             if cfg.mlp == "swiglu":
                 out.append((p + "w_gate", (d, f), d))
             out += [(p + "w_in", (d, f), d), (p + "w_out", (f, d), f)]
@@ -188,7 +324,11 @@ class TransformerLM:
     def init_params(self, key):
         cfg = self.cfg
         dt = jnp.dtype(cfg.dtype)
-        k = iter(jax.random.split(key, 4 + 8 * cfg.n_layers))
+        # the split is what the dense models' seeds were drawn with; a
+        # layer list with more matrices than it holds folds the rest in
+        k = itertools.chain(
+            jax.random.split(key, 4 + 8 * cfg.n_layers),
+            (jax.random.fold_in(key, 1 << 20 | j) for j in itertools.count()))
         params = {}
         for name, shape, fan_in in self._shapes():
             if fan_in:
@@ -224,12 +364,20 @@ class TransformerLM:
         restores the full residual stream. Head/hidden split is read off the
         *local* weight shapes, so the same code serves the unsharded path.
         `mesh` is the multi-device mesh of a pure-jit (GSPMD) caller: the
-        flash kernel then runs per shard."""
+        flash kernel then runs per shard. A layer with experts returns
+        (x, its routing counts)."""
         with jax.named_scope("attn"):
             x = self._residual(x, self._attn(params, prefix, x, sp_axis,
                                              tp_axis, mesh, positions))
         with jax.named_scope("mlp"):
             h = self._norm(x, params, prefix + "ln2")
+            if self.mlps[_layer_of(prefix)] == "experts":
+                if sp_axis is not None or tp_axis is not None:
+                    raise NotImplementedError(
+                        "an \"experts\" layer inside shard_map over sp / tp")
+                y, counts = self._experts(params, prefix, h)
+                return self._residual(x, checkpoint_name(y, "mlp_out")), \
+                    counts
             if self.cfg.mlp == "swiglu":
                 y = (jax.nn.silu(h @ params[prefix + "w_gate"])
                      * (h @ params[prefix + "w_in"])) \
@@ -242,6 +390,28 @@ class TransformerLM:
             y = checkpoint_name(y, "mlp_out")
             return self._residual(x, y)
 
+    def _experts(self, params, prefix, h):
+        """The MLP of an "experts" layer on h (B, T, d): the routed part
+        this chip's experts give (parallel/moe.moe_routed) plus the shared
+        expert. Returns (y, moe_routed's counts)."""
+        from ..parallel.moe import moe_routed
+        ex = self.cfg.experts
+        B, T, d = h.shape
+        with jax.named_scope("moe"):
+            y, counts = moe_routed(
+                h.reshape(B * T, d), params[prefix + "router"],
+                params[prefix + "e_gate_in"], params[prefix + "e_out"],
+                held=tuple(ex.held), k=ex.per_token,
+                rows=ex.rows or B * T * ex.per_token, score=ex.score,
+                scaling=ex.scaling, norm_topk=ex.norm_topk)
+            y = y.reshape(B, T, d)
+            if ex.shared_width:
+                with jax.named_scope("moe_shared"):
+                    y = y + (jax.nn.silu(h @ params[prefix + "s_gate"])
+                             * (h @ params[prefix + "s_in"])) \
+                        @ params[prefix + "s_out"]
+            return y, counts
+
     def _attn(self, params, prefix, x, sp_axis, tp_axis, mesh,
               positions=None):
         """The mixer half of a block: ln1, projections, the layer's mixer
@@ -249,7 +419,7 @@ class TransformerLM:
         where sharded). Returns attn_out."""
         B, T, D = x.shape
         hd = self.head_dim
-        kind = self.mixers[int(prefix[len("layer"):-1])]
+        kind = self.mixers[_layer_of(prefix)]
         if kind != "mha" and (sp_axis is not None or tp_axis is not None):
             raise NotImplementedError(
                 f"a {kind!r} layer inside shard_map over sp / tp")
@@ -260,6 +430,9 @@ class TransformerLM:
                     for w in ("wq", "wk", "wv"))
         if kind == "lightning":
             attn = self._lightning(params, prefix, q, kk, v, positions)
+        elif kind == "gqa":
+            attn = self._gqa(self.cfg.gqa[_layer_of(prefix)],
+                             q, kk, v, mesh, positions)
         elif kind == "sparse":
             with jax.named_scope("sparse_attn"):
                 if T > self.cfg.select.dense_len:
@@ -268,8 +441,12 @@ class TransformerLM:
                     attn = self._softmax_attention(q, kk, v, sp_axis, mesh)
         else:
             attn = self._softmax_attention(q, kk, v, sp_axis, mesh)
+        if kind == "gqa":       # a gate a head
+            gate = h @ params[prefix + "wg"]
+            with jax.named_scope("gate"):
+                attn = attn * jax.nn.sigmoid(gate)[..., None]
         attn = attn.reshape(B, T, -1)
-        if kind != "mha":
+        if kind in ("sparse", "lightning"):
             gate = h @ params[prefix + "wg"]
             with jax.named_scope("gate"):
                 attn = attn * jax.nn.sigmoid(gate)
@@ -299,10 +476,24 @@ class TransformerLM:
             return _rms(out.reshape(B, T, H * hd),
                         params[prefix + "o_norm_g"], cfg.norm_eps)
 
-    def _softmax_attention(self, q, kk, v, sp_axis, mesh):
+    def _gqa(self, layer, q, k, v, mesh, positions):
+        """Rotary as the layer's Rotary says, then causal softmax attention
+        at 1 / sqrt(head_dim), inside the layer's window where it has
+        one."""
+        with jax.named_scope("rope"):
+            if positions is None:
+                positions = jnp.arange(q.shape[1])
+            q, k = (_rope_part(x, positions, layer.rotary) for x in (q, k))
+        if layer.window is None:
+            return self._softmax_attention(q, k, v, None, mesh)
+        with jax.named_scope("window_attn"):
+            return self._softmax_attention(q, k, v, None, mesh, layer.window)
+
+    def _softmax_attention(self, q, kk, v, sp_axis, mesh, window=None):
         """Causal softmax attention of q (B, T, H, hd) over kk, v (B, T, H
         or fewer, hd): ring attention over `sp_axis`, else the flash
-        kernels, else the dense reference."""
+        kernels, else the dense reference. `window`: query i reads keys j
+        with i - j < window."""
         if sp_axis is not None:
             return ring_attention(q, kk, v, sp_axis, causal=True)
         if self.cfg.flash_attention:
@@ -317,6 +508,8 @@ class TransformerLM:
             # callers that already hold (BH,T,D).
             from ..parallel.flash_attention import flash_attention
             attn_fn = functools.partial(flash_attention, causal=True)
+            if window is not None:
+                attn_fn = functools.partial(attn_fn, window=window)
             if mesh is not None:
                 # a Mosaic kernel cannot be partitioned automatically (jax
                 # refuses at lowering): run it per shard. Attention is
@@ -330,14 +523,19 @@ class TransformerLM:
         if kk.shape[2] != q.shape[2]:
             kk, v = (jnp.repeat(x, q.shape[2] // x.shape[2], axis=2)
                      for x in (kk, v))
+        if window is not None:
+            return attention_reference(q, kk, v, causal=True, window=window)
         return attention_reference(q, kk, v, causal=True)
 
     def apply(self, params, tokens, sp_axis=None, positions=None, tp_axis=None,
-              mesh=None):
+              mesh=None, counts=False):
         """tokens (B, T) int32 -> logits (B, T, vocab). When called inside a
         shard_map with a sequence axis, pass sp_axis and per-shard positions;
         pass tp_axis when attention/MLP weights are Megatron-sharded; pass
-        the mesh when tracing a pure-jit program over several devices."""
+        the mesh when tracing a pure-jit program over several devices. With
+        `counts`, (logits, the expert layers' routing: {"held_slots",
+        "slots_over"}, a number an expert layer, and "experts" (layers, B *
+        T, per_token), what each token chose)."""
         cfg = self.cfg
         with jax.named_scope("embed"):
             x = _scaled(params["embed"][tokens], cfg.embed_scale)
@@ -353,27 +551,42 @@ class TransformerLM:
         else:
             block = lambda p, pref, y: self._block(p, pref, y, sp_axis,
                                                    tp_axis, mesh, positions)
+        routed = []
         for i in range(cfg.n_layers):
             with jax.named_scope(f"layer{i}"):
                 x = block(params, f"layer{i}_", x)
+                if i in self.expert_layers:
+                    x, seen = x
+                    routed.append(seen)
         with jax.named_scope("final_ln"):
             x = self._norm(x, params, "lnf")
         with jax.named_scope("logits"):
             head = params["embed" if cfg.tied_head else "head"]
-            return (_scaled(x, cfg.logit_scale) @ head.T).astype(jnp.float32)
+            logits = (_scaled(x, cfg.logit_scale) @ head.T).astype(
+                jnp.float32)
+        if not counts:
+            return logits
+        return logits, {k: jnp.stack([seen[k] for seen in routed])
+                        for k in ("held_slots", "slots_over", "experts")}
 
     def loss(self, params, tokens, targets, sp_axis=None, positions=None,
-             tp_axis=None, mesh=None):
+             tp_axis=None, mesh=None, counts=False):
+        """Mean next-token negative log-likelihood; with `counts` (a model
+        with an expert layer), (loss, apply's "held_slots" and
+        "slots_over")."""
         # `forward`, `loss` and (in the train step) `optimizer` are the top
         # words a device trace is read by (PERF.md section 3)
         with jax.named_scope("forward"):
             logits = self.apply(params, tokens, sp_axis, positions, tp_axis,
-                                mesh)
+                                mesh, counts)
+            if counts:      # the step's counters: two numbers a layer
+                logits, routed = logits
+                routed = {k: routed[k] for k in ("held_slots", "slots_over")}
         with jax.named_scope("loss"):
             logp = jax.nn.log_softmax(logits, axis=-1)
             nll = -jnp.take_along_axis(logp, targets[..., None],
                                        axis=-1)[..., 0]
-            return jnp.mean(nll)
+            return (jnp.mean(nll), routed) if counts else jnp.mean(nll)
 
     # -- sharded training ---------------------------------------------------
     def param_sharding(self, mesh, tp_axis="tp"):
@@ -403,7 +616,12 @@ class TransformerLM:
         n_steps: compile a MULTI-step program — lax.scan of the step with
         params/opt carried on device, one dispatch for the whole window
         (the TrainStep.run_steps analog; per-step RNG/step_i advance in
-        the scan)."""
+        the scan).
+
+        A model with an expert layer: step_fn returns (params, opt_state,
+        loss, counts), counts the step's routing counts a layer
+        ({"held_slots", "slots_over"}: TransformerLM.apply), which cost the
+        step nothing it would not compute anyway."""
         from ..parallel._compat import shard_map
         from ..parallel.tensor_parallel import transformer_param_specs
 
@@ -433,6 +651,10 @@ class TransformerLM:
                       sp_axis)
 
         model = self
+        routed = bool(self.expert_layers)
+        if routed and (n_steps or sp_axis is not None):
+            raise NotImplementedError("an expert layer's counts through a "
+                                      "scan of steps or a shard_map over sp")
         tp_in_block = "tp" if (sp_axis is not None and has["tp"]) else None
 
         def loss_fn(params, tokens, targets):
@@ -455,13 +677,15 @@ class TransformerLM:
                                (pspec, data_spec, data_spec), P())
                 return fn(params, tokens, targets)
             return model.loss(params, tokens, targets,
-                              mesh=mesh if mesh.devices.size > 1 else None)
+                              mesh=mesh if mesh.devices.size > 1 else None,
+                              counts=routed)
 
         from ..parallel.train import _make_update_rule
         _, adam_rule = _make_update_rule("adam", lr, 0.0, 0.0, {})
 
         def step(params, opt_state, tokens, targets, step_i):
-            loss, grads = jax.value_and_grad(loss_fn)(params, tokens, targets)
+            loss, grads = jax.value_and_grad(loss_fn, has_aux=routed)(
+                params, tokens, targets)
             new_params, new_opt = {}, {}
             with jax.named_scope("optimizer"):
                 t = step_i + 1
@@ -471,6 +695,9 @@ class TransformerLM:
                         params[k].astype(jnp.float32), g.astype(jnp.float32),
                         opt_state[k], t)
                     new_params[k] = w32.astype(params[k].dtype)
+            if routed:
+                loss, counts = loss
+                return new_params, new_opt, loss, counts
             return new_params, new_opt, loss
 
         if n_steps:
@@ -497,7 +724,8 @@ class TransformerLM:
         jit_step = jax.jit(step,
                            in_shardings=(param_sh, opt_sh, data_sh, data_sh,
                                          None),
-                           out_shardings=(param_sh, opt_sh, None),
+                           out_shardings=(param_sh, opt_sh, None)
+                           + (None,) * routed,
                            donate_argnums=(0, 1))
 
         def shard_params(params):

@@ -132,6 +132,32 @@ def _masked(s, keep, lead=False):
     return _stack([on, rest] if lead else [rest, on], axis=1)
 
 
+def _masked_at(s, pieces):
+    """s with -inf where a piece's mask is False: `pieces` are (first column,
+    last, keep) in order, each `keep` as wide as its columns; what lies
+    between them stays as it is (a band's strip: _band_strips)."""
+    from jax import lax
+    out, at = [], 0
+    for lo, hi, keep in pieces:
+        if lo > at:
+            out.append(_cut(s, cols=(at, lo)))
+        on = _cut(s, cols=(lo, hi))
+        out.append(lax.select(keep, on, lax.full_like(on, _NEG_INF)))
+        at = hi
+    if at < s.shape[1]:
+        out.append(_cut(s, cols=(at, s.shape[1])))
+    return _stack(out, axis=1)
+
+
+def _apply(s, keep, lead=False):
+    """s under a strip's `keep`: None, one mask for _masked, or a band's
+    pieces for _masked_at."""
+    if keep is None:
+        return s
+    return _masked_at(s, keep) if isinstance(keep, tuple) \
+        else _masked(s, keep, lead)
+
+
 def _over(col, like):
     """A column (r, 1) spread over the lanes of `like` (r, w)."""
     from jax import lax
@@ -322,6 +348,110 @@ def _causal_branches(plan, q_off, k_off, block_q, block_k, full, walk):
             lax.bitwise_or(below, diag))))(lambda: full(True))
 
 
+# A WINDOWED causal call (query i reads keys j with 0 <= i - j < window) runs,
+# for a q block, only the k blocks that meet the band: its own and the
+# `n_k - 1` before it, the grid's last axis (the index maps clamp at the
+# sequence's edge, so that a block the band does not reach is neither
+# fetched nor computed). Inside a block the sub-blocks the band misses are
+# skipped as the causal walk skips those above the diagonal, and only the
+# sub-blocks the band's two edges cross are masked. Lengths and blocks are
+# equal on both sides (self-attention). `run` of `all`: score sub-blocks a
+# head computes, and those in its (T, T) square, as _Plan counts them.
+_Band = collections.namedtuple("_Band", "window sub n_k blocks run all")
+
+
+def _band_strips(block, c, delta, window, by_k=False):
+    """The strips of the grid block whose q rows start `delta` blocks past
+    its k rows, in sub-blocks of edge c: ((outer rows), (inner rows),
+    pieces), a strip a sub-block of q rows (of k rows with `by_k`: the
+    dk/dv kernel's transposed scores) against the contiguous k (q)
+    sub-blocks the band lets it meet. `pieces`: (first, last, offset,
+    causal, windowed) of each inner sub-block that an edge of the band
+    crosses, in the strip's own columns; offset = its q rows' start less
+    its k rows'. A block the band reaches only in part leaves out whole
+    strips: the q rows (k rows) left are a leading (trailing) run."""
+    n = block // c
+    reach = (window + c - 2) // c        # farthest sub-block the band meets
+    out = []
+    for a in range(n):
+        if by_k:
+            lo, hi = max(a - delta * n, 0), min(a - delta * n + reach, n - 1)
+        else:
+            lo, hi = max(delta * n + a - reach, 0), min(delta * n + a, n - 1)
+        if lo > hi:
+            continue
+        pieces = []
+        for b in range(lo, hi + 1):
+            s = delta * n + (b - a if by_k else a - b)
+            causal, windowed = s == 0, (s + 1) * c > window
+            if causal or windowed:
+                pieces.append(((b - lo) * c, (b - lo + 1) * c, s * c, causal,
+                               windowed))
+        out.append(((a * c, a * c + c), (lo * c, hi * c + c), tuple(pieces)))
+    return tuple(out)
+
+
+def _band_plan(t, block, window):
+    """The _Band of a windowed causal call over (t, t) in grid blocks of
+    `block` a side."""
+    sub = _sub_block(block, block)
+    c = sub or block
+    n_k = min(1 + -(-(window - 1) // block), t // block)
+    run = sum((cols[1] - cols[0]) // c
+              for i in range(t // block) for delta in range(min(n_k, i + 1))
+              for _, cols, _ in _band_strips(block, c, delta, window))
+    return _Band(window, sub, n_k, t // block, run, (t // c) ** 2)
+
+
+def _band_branches(band, block, at, step, run, by_k=False):
+    """run(strips) with the strips of the grid block at outer block `at`,
+    inner step `step` of band.n_k: step s of a q block reads the k block
+    n_k - 1 - s before it (of a k block, with `by_k`, the q block s after
+    it); a step past the sequence's edge runs nothing. `strips` carry their
+    masks built (_masked_at's pieces); steps that share strips share a
+    branch."""
+    from jax import lax
+    from jax.experimental import pallas as pl
+    c = band.sub or block
+    branches = {}
+
+    def keep(masks, off, causal, windowed):
+        """The mask of one sub-block an edge crosses; `masks` are its
+        branch's own (a value traced in one branch is no other's)."""
+        if (off, causal, windowed) not in masks:
+            a = lax.broadcasted_iota(jnp.int32, (c, c), 0)
+            b = lax.broadcasted_iota(jnp.int32, (c, c), 1)
+            gap = lax.sub(a, b) if not by_k else lax.sub(b, a)  # q row - k
+            m = lax.ge(gap, -off) if causal else None
+            if windowed:
+                w = lax.lt(gap, band.window - off)
+                m = w if m is None else lax.bitwise_and(m, w)
+            masks[off, causal, windowed] = m
+        return masks[off, causal, windowed]
+
+    for s in range(band.n_k):
+        delta = s if by_k else band.n_k - 1 - s
+        strips = _band_strips(block, c, delta, band.window, by_k)
+        if strips:
+            branches.setdefault(strips, []).append((s, delta))
+    for strips, steps in branches.items():
+        hit = None
+        for s, delta in steps:
+            ok = lax.eq(step, s)
+            if delta:
+                ok = lax.bitwise_and(ok, lax.le(lax.add(at, delta),
+                                                band.blocks - 1)
+                                     if by_k else lax.ge(at, delta))
+            hit = ok if hit is None else lax.bitwise_or(hit, ok)
+
+        def branch(strips=strips):
+            masks = {}
+            run([(outer, inner, tuple(
+                (lo, hi, keep(masks, *what)) for lo, hi, *what in pieces)
+                or None) for outer, inner, pieces in strips])
+        pl.when(hit)(branch)
+
+
 def _fwd_fold(carry, s, v, prec):
     """One online-softmax step: scores s (r, w) folded into carry = (max,
     sumexp, acc), columns (r, 1) and (r, d), with v (w, d). Without a
@@ -343,13 +473,14 @@ def _fwd_fold(carry, s, v, prec):
 
 
 def _fa_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *scratch, d, block_q,
-               block_k, plan, sm_scale):
+               block_k, plan, sm_scale, band=None):
     """One (row, lane block, q_block, kv_block) grid step. The kv axis is
     the innermost ('arbitrary') grid dimension, so Pallas double-buffers
     the K/V block DMAs while this step computes; running (max, sumexp,
     acc) stats live in VMEM scratch that persists across kv steps. `plan`
     is None for a non-causal call (every block one unmasked pass), else
-    the call's _causal_plan. Without scratch the grid step is its heads'
+    the call's _causal_plan; a windowed call has a `band` too (_Band).
+    Without scratch the grid step is its heads'
     only one and is walked in strips that each hold their rows' every
     score: the statistics go straight to the output.
 
@@ -404,13 +535,17 @@ def _fa_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *scratch, d, block_q,
             scores = [_dot(_cut(q, rows), _cut(k, cols), (1, 1), prec)
                       for rows, cols, _ in strips]
             new = [_fwd_fold(old and [_cut(x, rows) for x in old],
-                             s if keep is None else _masked(s, keep),
-                             _cut(v, cols), prec)
+                             _apply(s, keep), _cut(v, cols), prec)
                    for (rows, cols, keep), s in zip(strips, scores)]
             if scratch:
                 m, l, acc = (_stack(list(x)) for x in zip(*new))
+                done = strips[-1][0][1]
+                if done < block_q:      # a band's far block: leading rows
+                    m, l = (_stack([x, _cut(o, (done, block_q))])
+                            for x, o in zip((m, l), old))
                 m_sc[h], l_sc[h] = m, l
-                _put(acc_sc, slice(None), acc, h, d)
+                _put(acc_sc, slice(None) if done == block_q
+                     else slice(0, done), acc, h, d)
             else:
                 emit(h, new)
         _each_head(heads, head)
@@ -427,6 +562,8 @@ def _fa_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *scratch, d, block_q,
 
     if plan is None:
         full(False)
+    elif band is not None:
+        _band_branches(band, block_q, pl.program_id(2), j, fold)
     else:
         _causal_branches(plan, q_off, k_off, block_q, block_k, full, walk)
 
@@ -487,18 +624,43 @@ def _result(x, like, direct):
         else x.reshape(B, H, T, D).transpose(0, 2, 1, 3)
 
 
-def _row_spec(g, n_p, block_q, q_axis):
+def _row_spec(g, n_p, block_q, q_axis, q_block=None):
     """Block of a row vector (N * C // d, 1, Tq): the g heads of lane block
-    p of row b, q block `q_axis` (2 or 3) of the grid's indices."""
+    p of row b, q block `q_axis` (2 or 3) of the grid's indices, or
+    q_block(the grid's last two indices) where a band picks it."""
     from jax.experimental import pallas as pl
-    return pl.BlockSpec((g, 1, block_q),
-                        lambda *at: (at[0] * n_p + at[1], 0, at[q_axis]))
+    return pl.BlockSpec((g, 1, block_q), lambda *at: (
+        at[0] * n_p + at[1], 0,
+        at[q_axis] if q_block is None else q_block(*at[2:])))
 
 
-def _fa_forward(q, k, v, d, causal, sm_scale, block_q, block_k, interpret):
+def _band_of(window, causal, tq, tk, block_q, block_k):
+    """The _Band of a call with a `window`, None without one."""
+    if window is None:
+        return None
+    if not causal or tq != tk or block_q != block_k or window < 1:
+        raise ValueError(f"a window of {window} wants causal self-attention "
+                         f"in square blocks: causal {causal}, lengths "
+                         f"({tq}, {tk}), blocks ({block_q}, {block_k})")
+    return _band_plan(tq, block_q, window)
+
+
+def _band_k_spec(block, w, n_k):
+    """The k block a q block reads at a band's step: n_k - 1 - step before
+    its own, held at the sequence's first (and not fetched again) where
+    the band ends before it."""
+    from jax import lax
+    from jax.experimental import pallas as pl
+    return pl.BlockSpec((1, block, w), lambda b, p, i, j: (
+        b, lax.max(i - (n_k - 1) + j, 0), p))
+
+
+def _fa_forward(q, k, v, d, causal, sm_scale, block_q, block_k, interpret,
+                window=None):
     """q, k, v: (N, T, C), rows of C // d heads. Returns (out, lse) with lse
     the per-row log-sum-exp (N * C // d, 1, T) f32 the backward kernels
-    consume."""
+    consume. With a `window` the kernel is `flash_win_fwd`: the same body
+    over the band's grid (_Band)."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -506,19 +668,26 @@ def _fa_forward(q, k, v, d, causal, sm_scale, block_q, block_k, interpret):
     tk = k.shape[1]
     w = _lane_block(c, d)
     g, n_p = w // d, c // w
-    grid = (n, n_p, tq // block_q, tk // block_k)
+    band = _band_of(window, causal, tq, tk, block_q, block_k)
+    grid = (n, n_p, tq // block_q, band.n_k if band else tk // block_k)
     plan = _causal_plan(tq, tk, block_q, block_k) if causal else None
     if causal:
         with _dispatch_lock:
-            _dispatch["causal_subblocks_run"] += plan.run
-            _dispatch["causal_subblocks_all"] += plan.all
+            if band:
+                _dispatch["window_subblocks_run"] += band.run
+                _dispatch["window_subblocks_all"] += band.all
+            else:
+                _dispatch["causal_subblocks_run"] += plan.run
+                _dispatch["causal_subblocks_all"] += plan.all
     kern = functools.partial(_fa_kernel, d=d, block_q=block_q,
-                             block_k=block_k, plan=plan, sm_scale=sm_scale)
+                             block_k=block_k, plan=plan, sm_scale=sm_scale,
+                             **({"band": band} if band else {}))
     # heads that are one grid block walked in strips carry no running
     # statistics from one kv step to the next: no scratch
     alone = causal and plan.sub and grid[2:] == (1, 1)
     of_q = pl.BlockSpec((1, block_q, w), lambda b, p, i, j: (b, i, p))
-    of_k = pl.BlockSpec((1, block_k, w), lambda b, p, i, j: (b, j, p))
+    of_k = _band_k_spec(block_k, w, band.n_k) if band else \
+        pl.BlockSpec((1, block_k, w), lambda b, p, i, j: (b, j, p))
     return pl.pallas_call(
         kern,
         grid=grid,
@@ -533,12 +702,12 @@ def _fa_forward(q, k, v, d, causal, sm_scale, block_q, block_k, interpret):
         ],
         compiler_params=_compiler_params(),
         interpret=interpret,
-        name="flash_fwd",
+        name="flash_win_fwd" if band else "flash_fwd",
     )(q, k, v)
 
 
 def _fa_bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, out_ref, *rest,
-                      d, block_q, block_k, plan, sm_scale):
+                      d, block_q, block_k, plan, sm_scale, band=None):
     """dq for one q block, streaming k/v blocks (innermost grid dim):
       delta = rowsum(dO * O) - dlse   (computed HERE at j==0 — fused, so
                                  no separate XLA pass re-reads dO and O;
@@ -599,13 +768,14 @@ def _fa_bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, out_ref, *rest,
                      for rows, cols, _ in strips]
             parts = []
             for (rows, cols, keep), (s, dp) in zip(strips, first):
-                if keep is not None:
-                    s = _masked(s, keep)
+                s = _apply(s, keep)
                 ds = lax.mul(lax.exp(lax.sub(s, _over(_cut(lse, rows), s))),
                              lax.sub(dp, _over(_cut(delta, rows), dp)))
                 parts.append(_dot(lax.convert_element_type(ds, k.dtype),
                                   _cut(k, cols), (1, 0), prec))
-            acc_sc[:] = lax.add(acc_sc[:], _kept(_stack(parts), h, d))
+            done = strips[-1][0][1]     # short of block_q: a band's far block
+            at = slice(None) if done == block_q else slice(0, done)
+            acc_sc[at] = lax.add(acc_sc[at], _kept(_stack(parts), h, d))
         _each_head(heads, head)
 
     def full(masked):
@@ -620,6 +790,8 @@ def _fa_bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, out_ref, *rest,
 
     if plan is None:
         full(False)
+    elif band is not None:
+        _band_branches(band, block_q, pl.program_id(2), j, add)
     else:
         _causal_branches(plan, q_off, k_off, block_q, block_k, full, walk)
 
@@ -631,7 +803,7 @@ def _fa_bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, out_ref, *rest,
 
 def _fa_bwd_dkv_kernel(k_ref, v_ref, q_ref, do_ref, lse_ref, delta_ref,
                        dk_ref, dv_ref, dk_sc, dv_sc, *, d, block_q, block_k,
-                       plan, sm_scale):
+                       plan, sm_scale, band=None):
     """dk/dv for one k block, streaming q blocks (innermost grid dim):
       p^T  = exp(s^T*scale - lse);     dv = sum_q p^T dO
       ds^T = p^T * (dp^T - delta);     dk = scale * sum_q ds^T Q
@@ -669,8 +841,7 @@ def _fa_bwd_dkv_kernel(k_ref, v_ref, q_ref, do_ref, lse_ref, delta_ref,
                      for cols, rows, _ in strips]
             dks, dvs = [], []
             for (cols, rows, keep), (st, dpt) in zip(strips, first):
-                if keep is not None:
-                    st = _masked(st, keep, lead=True)
+                st = _apply(st, keep, lead=True)
                 pt = lax.exp(lax.sub(st, _under(lse, rows, st)))
                 # dO has this head's lanes alone, so pt . dO leaves the
                 # other heads' dv as it is; q has them all
@@ -680,10 +851,11 @@ def _fa_bwd_dkv_kernel(k_ref, v_ref, q_ref, do_ref, lse_ref, delta_ref,
                                           _under(delta, rows, dpt)))
                 dks.append(_dot(lax.convert_element_type(dst, q.dtype),
                                 _cut(q, rows), (1, 0), prec))
-            done = strips[-1][0][1]   # the strips' k rows run from 0 on
-            dk_sc[:done, :] = lax.add(dk_sc[:done, :],
-                                      _kept(_stack(dks), h, d))
-            dv_sc[:done, :] = lax.add(dv_sc[:done, :], _stack(dvs))
+            # the strips' k rows run from 0 on (a band's far block: up to
+            # the block's end)
+            at = slice(strips[0][0][0], strips[-1][0][1])
+            dk_sc[at, :] = lax.add(dk_sc[at, :], _kept(_stack(dks), h, d))
+            dv_sc[at, :] = lax.add(dv_sc[at, :], _stack(dvs))
         _each_head(heads, head)
 
     def full(masked):
@@ -701,6 +873,8 @@ def _fa_bwd_dkv_kernel(k_ref, v_ref, q_ref, do_ref, lse_ref, delta_ref,
 
     if plan is None:
         full(False)
+    elif band is not None:
+        _band_branches(band, block_k, pl.program_id(2), i, add, by_k=True)
     else:
         _causal_branches(plan, q_off, k_off, block_q, block_k, full, walk)
 
@@ -712,7 +886,7 @@ def _fa_bwd_dkv_kernel(k_ref, v_ref, q_ref, do_ref, lse_ref, delta_ref,
 
 
 def _fa_backward(q, k, v, do, lse, out, dlse, d, causal, sm_scale, block_q,
-                 block_k, interpret):
+                 block_k, interpret, window=None):
     """q, k, v, do, out: (N, T, C), rows of C // d heads; lse, and dlse
     where the caller has a cotangent for lse (None: the dq kernel takes no
     such operand): (N * C // d, 1, Tq) f32. Returns (dq, dk, dv) via the
@@ -720,7 +894,9 @@ def _fa_backward(q, k, v, do, lse, out, dlse, d, causal, sm_scale, block_q,
     from the saved lse. delta = rowsum(dO*O) is computed INSIDE the dq
     kernel (per q block, at its first kv step) and handed to the dk/dv
     kernel as an output shaped like lse — one fewer full pass over dO and O
-    than a separate XLA delta computation."""
+    than a separate XLA delta computation. With a `window` the kernels are
+    `flash_win_bwd_dq` and `flash_win_bwd_dkv`, over the band's grids."""
+    from jax import lax
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -730,33 +906,40 @@ def _fa_backward(q, k, v, do, lse, out, dlse, d, causal, sm_scale, block_q,
     g, n_p = w // d, c // w
     params = _compiler_params()
     plan = _causal_plan(tq, tk, block_q, block_k) if causal else None
+    band = _band_of(window, causal, tq, tk, block_q, block_k)
+    sizes = dict(d=d, block_q=block_q, block_k=block_k, plan=plan,
+                 sm_scale=sm_scale, **({"band": band} if band else {}))
     row = jax.ShapeDtypeStruct((n * c // d, 1, tq), jnp.float32)
     dlse = [] if dlse is None else [dlse]
 
     of_q = pl.BlockSpec((1, block_q, w), lambda b, p, i, j: (b, i, p))
-    of_k = pl.BlockSpec((1, block_k, w), lambda b, p, i, j: (b, j, p))
+    of_k = _band_k_spec(block_k, w, band.n_k) if band else \
+        pl.BlockSpec((1, block_k, w), lambda b, p, i, j: (b, j, p))
     of_row = _row_spec(g, n_p, block_q, 2)
     dq, delta = pl.pallas_call(
-        functools.partial(_fa_bwd_dq_kernel, d=d, block_q=block_q,
-                          block_k=block_k, plan=plan, sm_scale=sm_scale),
-        grid=(n, n_p, tq // block_q, tk // block_k),
+        functools.partial(_fa_bwd_dq_kernel, **sizes),
+        grid=(n, n_p, tq // block_q, band.n_k if band else tk // block_k),
         in_specs=[of_q, of_k, of_k, of_q, of_row, of_q] + [of_row] * len(dlse),
         out_specs=[of_q, of_row],
         out_shape=[jax.ShapeDtypeStruct((n, tq, c), q.dtype), row],
         scratch_shapes=[pltpu.VMEM((block_q, w), jnp.float32)],
         compiler_params=params,
         interpret=interpret,
-        name="flash_bwd_dq",
+        name="flash_win_bwd_dq" if band else "flash_bwd_dq",
     )(q, k, v, do, lse, out, *dlse)
 
-    # the grid's last two axes swap: k blocks outside, q blocks inside
-    of_q = pl.BlockSpec((1, block_q, w), lambda b, p, j, i: (b, i, p))
+    # the grid's last two axes swap: k blocks outside, q blocks inside (a
+    # band's: the k block's own q block and the n_k - 1 after it, held at
+    # the sequence's last where the band ends before it)
+    q_block = (lambda j, i: lax.min(j + i, band.blocks - 1)) if band \
+        else (lambda j, i: i)
+    of_q = pl.BlockSpec((1, block_q, w),
+                        lambda b, p, j, i: (b, q_block(j, i), p))
     of_k = pl.BlockSpec((1, block_k, w), lambda b, p, j, i: (b, j, p))
-    of_row = _row_spec(g, n_p, block_q, 3)
+    of_row = _row_spec(g, n_p, block_q, 3, q_block if band else None)
     dk, dv = pl.pallas_call(
-        functools.partial(_fa_bwd_dkv_kernel, d=d, block_q=block_q,
-                          block_k=block_k, plan=plan, sm_scale=sm_scale),
-        grid=(n, n_p, tk // block_k, tq // block_q),
+        functools.partial(_fa_bwd_dkv_kernel, **sizes),
+        grid=(n, n_p, tk // block_k, band.n_k if band else tq // block_q),
         in_specs=[of_k, of_k, of_q, of_q, of_row, of_row],
         out_specs=[of_k, of_k],
         out_shape=[jax.ShapeDtypeStruct((n, tk, c), k.dtype),
@@ -765,7 +948,7 @@ def _fa_backward(q, k, v, do, lse, out, dlse, d, causal, sm_scale, block_q,
                         pltpu.VMEM((block_k, w), jnp.float32)],
         compiler_params=params,
         interpret=interpret,
-        name="flash_bwd_dkv",
+        name="flash_win_bwd_dkv" if band else "flash_bwd_dkv",
     )(k, v, q, do, lse, delta)
     return dq, dk, dv
 
@@ -797,7 +980,8 @@ def _pick_block(t, preferred=1024):
 # to the O(T^2) XLA reference when no block fits; that must be a choice
 # somebody can see, not a silent one (chip_smoke.py asserts on it).
 _dispatch = {"pallas": 0, "reference": 0, "direct": 0, "transposed": 0,
-             "causal_subblocks_run": 0, "causal_subblocks_all": 0}
+             "causal_subblocks_run": 0, "causal_subblocks_all": 0,
+             "window_subblocks_run": 0, "window_subblocks_all": 0}
 _dispatch_lock = threading.Lock()
 
 
@@ -810,7 +994,9 @@ def dispatch_stats():
     "causal_subblocks_run" of "causal_subblocks_all": over the traced
     CAUSAL forward kernel calls, the score sub-blocks one head computes
     and those in its (Tq, Tk) square (_causal_plan; the backward pair
-    walks the same ones). All counted at trace time, nothing per step."""
+    walks the same ones); "window_subblocks_run" of "window_subblocks_all":
+    the same over the traced WINDOWED calls (_Band), which the causal pair
+    leaves out. All counted at trace time, nothing per step."""
     with _dispatch_lock:
         return dict(_dispatch)
 
@@ -840,12 +1026,12 @@ def _route(heads, d):
     return direct
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
-def _flash(q, k, v, causal, sm_scale):
-    return _flash_vjp_fwd(q, k, v, causal, sm_scale)[0]
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))
+def _flash(q, k, v, causal, sm_scale, window):
+    return _flash_vjp_fwd(q, k, v, causal, sm_scale, window)[0]
 
 
-def _flash_fwd_impl(q, k, v, causal, sm_scale):
+def _flash_fwd_impl(q, k, v, causal, sm_scale, window=None):
     """(out, lse) as the forward kernel leaves them, out (N, T, C) by the
     route _direct names; (out, None) from attention_reference, out in q's
     layout, where no block fits."""
@@ -860,12 +1046,12 @@ def _flash_fwd_impl(q, k, v, causal, sm_scale):
     blocks = _blocks_for(Tq, Tk, D)
     if blocks is None:
         return attention_reference(q, k, v, causal=causal,
-                                   sm_scale=sm_scale), None
+                                   sm_scale=sm_scale, window=window), None
     bq, bk = blocks
     direct = _route(H, D)
     return _fa_forward(_operand(q, direct), _operand(k, direct),
                        _operand(v, direct), D, causal, sm_scale, bq, bk,
-                       _interpret())
+                       _interpret(), window)
 
 
 # What the Pallas forward leaves for its backward beside q, k, v, by the names
@@ -879,8 +1065,8 @@ def _flash_fwd_impl(q, k, v, causal, sm_scale):
 RESIDUAL_NAMES = ("flash_out", "flash_lse")
 
 
-def _flash_vjp_fwd(q, k, v, causal, sm_scale):
-    out, lse = _flash_fwd_impl(q, k, v, causal, sm_scale)
+def _flash_vjp_fwd(q, k, v, causal, sm_scale, window):
+    out, lse = _flash_fwd_impl(q, k, v, causal, sm_scale, window)
     if lse is None:
         # the scan fallback recomputes everything from q/k/v — keeping `out`
         # alive would cost an activation-sized residual for nothing
@@ -889,7 +1075,7 @@ def _flash_vjp_fwd(q, k, v, causal, sm_scale):
     return _result(out, q, _direct(*q.shape[2:])), (q, k, v, out, lse)
 
 
-def _flash_vjp_bwd(causal, sm_scale, res, g):
+def _flash_vjp_bwd(causal, sm_scale, window, res, g):
     """Backward. With a Pallas forward (saved lse) the two flash backward
     KERNELS run (dq streams k/v blocks; dk/dv streams q blocks) — O(block
     * T) memory, bf16 matmuls, f32 accumulation. Fallback (no pallas /
@@ -908,16 +1094,17 @@ def _flash_vjp_bwd(causal, sm_scale, res, g):
         direct = _direct(H, D)
         dq, dk, dv = _fa_backward(
             *(_operand(x, direct) for x in (q, k, v, g)), lse, out, None, D,
-            causal, sm_scale, bq, bk, _interpret())
+            causal, sm_scale, bq, bk, _interpret(), window)
         return (_result(dq, q, direct), _result(dk, k, direct),
                 _result(dv, v, direct))
     bq = _pick_block(Tq, 256)
-    if bq is None or bq == Tq:
+    if bq is None or bq == Tq or window is not None:
         # tiny/ragged: dense vjp of the reference is fine at this size
         from .ring_attention import attention_reference
         _, vjp = jax.vjp(
             lambda q_, k_, v_: attention_reference(
-                q_, k_, v_, causal=causal, sm_scale=sm_scale), q, k, v)
+                q_, k_, v_, causal=causal, sm_scale=sm_scale, window=window),
+            q, k, v)
         return vjp(g)
 
     f32 = jnp.float32
@@ -960,9 +1147,13 @@ def _flash_vjp_bwd(causal, sm_scale, res, g):
 _flash.defvjp(_flash_vjp_fwd, _flash_vjp_bwd)
 
 
-def flash_attention(q, k, v, causal=False, sm_scale=None):
+def flash_attention(q, k, v, causal=False, sm_scale=None, window=None):
     """Blocked flash attention. q,k,v: (B, T, H, D) (the layout of
     attention_reference / the transformer flagship). Differentiable.
+    `window`: query i reads keys j with 0 <= i - j < window (causal
+    self-attention only); the kernels then run the band alone and are named
+    `flash_win_fwd`, `flash_win_bwd_dq`, `flash_win_bwd_dkv`, since the
+    benchmark counts a `flash_fwd` as a whole causal square.
     Grouped K/V heads: k and v may hold H / g heads, query head i reading
     head i // g; they are repeated over the group before the kernels, whose
     operands are then three arrays of q's shape (what the benchmark's count
@@ -974,7 +1165,10 @@ def flash_attention(q, k, v, causal=False, sm_scale=None):
     if k.shape[2] != q.shape[2]:
         k, v = (jnp.repeat(x, q.shape[2] // x.shape[2], axis=2)
                 for x in (k, v))
-    return _flash(q, k, v, bool(causal), float(sm_scale))
+    if window is not None and (not causal or q.shape[1] != k.shape[1]):
+        raise ValueError(f"window {window}: causal self-attention only")
+    return _flash(q, k, v, bool(causal), float(sm_scale),
+                  None if window is None else int(window))
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))

@@ -16,14 +16,18 @@ is 0), matching Switch-Transformer semantics.
 """
 from __future__ import annotations
 
+import functools
+
 import jax
 import jax.numpy as jnp
 from jax import lax
 
 from ._compat import shard_map
+from .flash_attention import _interpret
 
 __all__ = ["moe_gate", "moe_apply", "moe_apply_a2a", "moe_sharded",
-           "init_moe_params"]
+           "init_moe_params", "moe_route", "moe_dispatch", "moe_routed",
+           "grouped_matmul"]
 
 
 def moe_gate(x, wg, k=1, capacity_factor=1.25):
@@ -191,3 +195,168 @@ def moe_sharded(x, params, mesh, axis="ep", k=1, capacity_factor=1.25):
 
     return shard_map(inner, mesh, in_specs=(spec_p, P()),
                      out_specs=(P(), P()))(params, x)
+
+
+# -- the expert layer a chip of an expert-parallel deployment runs ----------
+# Told which experts it holds, it routes every token over ALL the experts the
+# router knows, and computes the part of the layer's result that its own
+# experts give: what the experts held elsewhere would add is left out (on
+# one chip the layer runs without its exchange). Token slots are sorted by
+# local expert and the first `rows` of them gathered into ONE buffer for the
+# chip: no capacity an expert, nothing dropped by expert; a step whose held
+# slots exceed `rows` is counted (`slots_over`), never silent. Shapes, `rows`
+# and the grouped products' cost do not depend on where the tokens went: the
+# buffer's spare rows go through the last held expert at weight zero.
+# docs/architecture/note_moe_layer.md has the contract; `moe_gate` above stays
+# as the one-hot oracle the tests hold this to.
+
+SCORES = ("sigmoid", "softmax")
+
+
+def _gmm_tiling(m, k, n):
+    """megablox tiles (rows, contraction, columns). 256 rows: a held
+    expert's few hundred rows straddle fewer tiles than at 512; 1,024 deep
+    and wide is the most the chip's scoped memory took (PERF.md section 6,
+    PR 36, has the race). A size that no such tile divides is one tile."""
+    def fit(size, most):
+        return next((t for t in (most, 512, 256, 128)
+                     if t <= most and size % t == 0), size)
+    return fit(m, 256), fit(k, 1024), fit(n, 1024)
+
+
+def _low(dtype):
+    """The context a Pallas product of bfloat16 operands is traced in:
+    Mosaic refuses the process's ambient `highest` (runtime.py) for them,
+    in the backward pass as in the forward."""
+    import contextlib
+    return jax.default_matmul_precision("default") \
+        if dtype == jnp.bfloat16 else contextlib.nullcontext()
+
+
+def _megablox_backend():
+    """The module of the kernels themselves (the package's `gmm` is its
+    own custom_vjp over them, whose backward is traced outside _low). Off
+    the chip they run in Pallas's interpreter, as the flash kernels do."""
+    import importlib
+    return importlib.import_module(
+        "jax.experimental.pallas.ops.tpu.megablox.gmm")
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
+def _megablox(lhs, rhs, group_sizes, tiling):
+    backend = _megablox_backend()
+    with _low(lhs.dtype):
+        return backend.gmm(lhs, rhs, group_sizes, lhs.dtype, tiling,
+                           interpret=_interpret())
+
+
+def _megablox_fwd(lhs, rhs, group_sizes, tiling):
+    return _megablox(lhs, rhs, group_sizes, tiling), (lhs, rhs, group_sizes)
+
+
+def _megablox_bwd(tiling, res, grad):
+    """jax's own rule (megablox/ops.py), traced under _low: d lhs is the
+    grouped product with each group's matrix transposed, d rhs the
+    grouped product of lhs^T and the cotangent, a matrix a group."""
+    backend = _megablox_backend()
+    lhs, rhs, group_sizes = res
+    interpret = _interpret()
+    with _low(lhs.dtype):
+        d_lhs = backend.gmm(grad, rhs, group_sizes, lhs.dtype, tiling,
+                            transpose_rhs=True, interpret=interpret)
+        d_rhs = backend.tgmm(lhs.swapaxes(0, 1), grad, group_sizes,
+                             rhs.dtype, tiling, interpret=interpret)
+    return d_lhs, d_rhs, None
+
+
+_megablox.defvjp(_megablox_fwd, _megablox_bwd)
+
+
+def grouped_matmul(lhs, rhs, group_sizes):
+    """lhs (R, k) rows sorted by group times rhs (G, k, n), row r by the
+    matrix of its group: (R, n) in lhs's dtype, float32 accumulation.
+    group_sizes (G,) int32 sum to R. jax's `megablox` Pallas kernels (`gmm`,
+    and `tgmm` for the matrices' gradient), which won the race on the chip
+    (benchmark/moe_race.py; PERF.md section 6, PR 36)."""
+    return _megablox(lhs, rhs, group_sizes,
+                     _gmm_tiling(lhs.shape[0], *rhs.shape[1:]))
+
+
+def moe_route(x, router, k, score="sigmoid", scaling=1.0, norm_topk=True):
+    """Scores over every expert the router knows, in float32; the k largest
+    a token. x (N, d), router (d, E). Returns (experts (N, k) int32, weights
+    (N, k) float32): weights = scaling * s_sel / sum(s_sel) with norm_topk,
+    else scaling * s_sel."""
+    if score not in SCORES:
+        raise ValueError(f"router score {score!r}: one of {SCORES}")
+    with jax.named_scope("moe_route"):
+        logits = jnp.matmul(x.astype(jnp.float32), router.astype(jnp.float32),
+                            precision=lax.Precision.HIGHEST)
+        s = jax.nn.sigmoid(logits) if score == "sigmoid" \
+            else jax.nn.softmax(logits, axis=-1)
+        top, experts = lax.top_k(s, k)
+        if norm_topk:
+            top = top / jnp.sum(top, axis=-1, keepdims=True)
+        return experts.astype(jnp.int32), top * scaling
+
+
+def moe_dispatch(experts, held, rows):
+    """Which token slots this chip computes, in the order its experts take
+    them. experts (N, k) int32; held = (first, count): the experts
+    [first, first + count) are here. Returns (slot (rows,) int32: indices
+    into the N * k slots, sorted by local expert, spare rows last; group
+    (count,) int32: rows a held expert takes, the spare ones with the last,
+    summing to `rows`; live (rows,) bool: rows that are a held slot;
+    counts {"held_slots", "slots_over"})."""
+    first, count = held
+    with jax.named_scope("moe_dispatch"):
+        flat = experts.reshape(-1)
+        n = flat.shape[0]
+        if rows > n or (count + 1) * n >= 2 ** 31:
+            raise ValueError(f"{rows} rows, {count} held experts for {n} "
+                             "token slots: more rows than slots, or a "
+                             "sort key past int32")
+        local = jnp.where((flat >= first) & (flat < first + count),
+                          flat - first, count)         # sentinel: elsewhere
+        # one sort of one array: the key in the high part, the slot in the low
+        order = jnp.sort(local * n + jnp.arange(n, dtype=jnp.int32))[:rows]
+        slot, key = order % n, order // n
+        sizes = jnp.sum(local[:, None] == jnp.arange(count)[None, :], axis=0,
+                        dtype=jnp.int32)
+        ends = jnp.minimum(jnp.cumsum(sizes), rows)
+        group = jnp.diff(ends, prepend=0)
+        group = group.at[count - 1].add(rows - ends[-1])
+        held_slots = jnp.sum(sizes)
+        return slot, group, key < count, {
+            "held_slots": held_slots,
+            "slots_over": jnp.maximum(held_slots - rows, 0)}
+
+
+def moe_routed(x, router, w_gate_in, w_out, *, held, k, rows,
+               score="sigmoid", scaling=1.0, norm_topk=True):
+    """The routed part of one expert layer as this chip computes it:
+    sum over a token's chosen AND held experts e of w_e SwiGLU_e(x).
+
+    x (N, d); router (d, E) over ALL experts; w_gate_in (count, d, 2 f): a
+    held expert's gate and up projections side by side; w_out (count, f, d).
+    Returns (y (N, d) in x's dtype, counts: moe_dispatch's, and "experts"
+    (N, k), what each token chose, for whoever compares routings)."""
+    N, d = x.shape
+    f = w_out.shape[1]
+    if w_gate_in.shape != (held[1], d, 2 * f):
+        raise ValueError(f"held {held}: gate and up projections "
+                         f"{w_gate_in.shape}, down {w_out.shape}")
+    experts, weights = moe_route(x, router, k, score, scaling, norm_topk)
+    slot, group, live, counts = moe_dispatch(experts, held, rows)
+    with jax.named_scope("moe_dispatch"):
+        token = slot // k
+        taken = x[token]                                    # (rows, d)
+        w = jnp.where(live, weights.reshape(-1)[slot], 0.0)
+    with jax.named_scope("moe_experts"):
+        h = grouped_matmul(taken, w_gate_in, group)
+        h = (jax.nn.silu(h[:, :f]) * h[:, f:]).astype(x.dtype)
+        out = grouped_matmul(h, w_out, group)
+    with jax.named_scope("moe_combine"):
+        y = jnp.zeros((N, d), jnp.float32).at[token].add(
+            out.astype(jnp.float32) * w[:, None])
+        return y.astype(x.dtype), dict(counts, experts=experts)

@@ -28,8 +28,9 @@ from jax import lax
 __all__ = ["ring_attention", "ring_attention_sharded", "attention_reference"]
 
 
-def attention_reference(q, k, v, causal=False, sm_scale=None):
+def attention_reference(q, k, v, causal=False, sm_scale=None, window=None):
     """Plain single-device attention, the numeric oracle for the ring version.
+    `window`: with `causal`, query i reads keys j with i - j < window.
     q,k,v: (B, T, H, D). f32 inputs run HIGHEST-precision einsums so the
     fallback matches the Pallas kernels' dtype-dependent precision (on
     TPU, DEFAULT would demote f32 operands to bf16)."""
@@ -40,6 +41,8 @@ def attention_reference(q, k, v, causal=False, sm_scale=None):
     scores = jnp.einsum("bqhd,bkhd->bhqk", q, k, precision=prec) * scale
     if causal:
         mask = jnp.tril(jnp.ones((T, k.shape[1]), bool))
+        if window is not None:
+            mask &= ~jnp.tril(jnp.ones((T, k.shape[1]), bool), -window)
         scores = jnp.where(mask[None, None], scores, -jnp.inf)
     w = jax.nn.softmax(scores, axis=-1)
     return jnp.einsum("bhqk,bkhd->bqhd", w, v, precision=prec)

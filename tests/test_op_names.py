@@ -110,7 +110,72 @@ def test_a_lm_step_runs_no_flash_forward_twice(lm_names):
                for n in lm_names)
 
 
-@pytest.mark.parametrize("which", ["trainstep_names", "lm_names"])
+@pytest.fixture(scope="module")
+def laguna_names():
+    """The step of a five-layer list with Laguna-S-2.1's pattern at tiny
+    widths: "gqa" layers full and windowed, a dense MLP and four expert
+    layers (the names perfbench/layer_metrics' moe_* and flash_win_* read)."""
+    from incubator_mxnet_tpu.models.transformer import (
+        GQA, Experts, Rotary, TransformerConfig, TransformerLM)
+    from incubator_mxnet_tpu.parallel import make_mesh
+    yarn = Rotary(500000.0, 0.5, (128, 8192, 32, 1, 1.4852))
+    model = TransformerLM(TransformerConfig(
+        vocab_size=64, d_model=32, n_heads=2, n_layers=5, d_ff=64,
+        max_len=64, remat=True, flash_attention=True, mixers=("gqa",) * 5,
+        norm="rmsnorm", mlp="swiglu", learned_positions=False,
+        tied_head=False, n_kv_heads=1, head_dim=16,
+        gqa=(GQA(2, None, yarn),) + (GQA(3, 16),) * 3 + (GQA(2, None, yarn),),
+        mlps=("dense",) + ("experts",) * 4,
+        experts=Experts(count=8, held=(2, 4), per_token=2, width=16,
+                        shared_width=16, scaling=2.5, rows=64)))
+    step, shard, init_opt = model.make_train_step(
+        make_mesh({"dp": 1}, jax.devices()[:1]), use_sp=False)
+    params = shard(model.init_params(jax.random.PRNGKey(0)))
+    tokens = jnp.zeros((1, 64), jnp.int32)
+    text = step.lower(params, init_opt(params), tokens, tokens,
+                      0).compile().as_text()
+    return _op_names(text)
+
+
+@pytest.mark.parametrize("pattern", [
+    r"/jvp\(forward\)/layer1/mlp/moe/moe_route/",
+    r"/jvp\(forward\)/layer2/mlp/moe/moe_dispatch/",
+    r"/jvp\(forward\)/layer3/mlp/moe/moe_experts/",
+    r"/jvp\(forward\)/layer4/mlp/moe/moe_shared/",
+    r"/jvp\(forward\)/layer1/mlp/moe/moe_combine/",
+    r"/transpose\(jvp\(forward\)\)/layer1/.*/mlp/moe/moe_experts/",
+    r"/rematted_computation/mlp/moe/moe_dispatch/",
+    r"/jvp\(forward\)/layer1/attn/window_attn/flash_win_fwd/",
+    r"/attn/window_attn/flash_win_bwd_dq/",
+    r"/attn/window_attn/flash_win_bwd_dkv/",
+    r"/jvp\(forward\)/layer0/attn/flash_fwd/",
+    r"/jvp\(forward\)/layer4/attn/flash_fwd/",
+    r"/jvp\(forward\)/layer0/attn/rope/",
+    r"/jvp\(forward\)/layer2/attn/rope/",
+    r"/jvp\(forward\)/layer2/attn/gate/",
+    r"/jvp\(forward\)/layer0/mlp/norm/",
+    r"^jit\(step\)/optimizer/",
+])
+def test_a_layer_list_with_experts_carries_the_programs_names(laguna_names,
+                                                              pattern):
+    assert any(re.search(pattern, n) for n in laguna_names), \
+        sorted(laguna_names)[:40]
+
+
+def test_a_windowed_layer_runs_no_flash_forward_twice(laguna_names):
+    """A block's checkpoint keeps a windowed call's output and lse as it
+    keeps a plain one's; the full layers' kernels stay outside
+    `window_attn`, where the plain readers count them."""
+    assert not [n for n in laguna_names if re.search(
+        r"/rematted_computation/.*flash_(win_)?fwd", n)]
+    assert not [n for n in laguna_names
+                if re.search(r"window_attn/flash_(fwd|bwd)", n)]
+    assert not [n for n in laguna_names
+                if re.search(r"layer[04]/.*window_attn", n)]
+
+
+@pytest.mark.parametrize("which", ["trainstep_names", "lm_names",
+                                   "laguna_names"])
 def test_a_step_leaves_no_operation_unnamed(which, request):
     """Every operation a step traces (`jit(..)/..`; the rest are labels of
     arguments) sits under one of the three top words: what a later edit
@@ -518,6 +583,82 @@ def test_a_layer_lists_blocks_keep_their_names_in_a_tpu_program(
     # 32 heads x 8192^2 x (128 + 128) under the mask, as (B H, T, D) counts
     assert op_scopes.flash_flops(1, 8192, 4096) == \
         op_scopes.flash_flops(32, 8192, 128) == 549_755_813_888
+
+
+def test_a_windowed_expert_layer_keeps_its_names_in_a_tpu_program(
+        one_chip, monkeypatch):
+    """Value and gradient of one remat block of `laguna-s-2.1.train-8k`
+    (layer 1: 72 heads inside a window of 512, routed and shared experts),
+    at its published widths and 1 x 8,192 tokens, compiled for the chip:
+    the three windowed kernels by their own names, each once, over K/V
+    repeated to the 72 query heads, so that `flash_win_*_roofline` reads
+    (1, 8192, 72 * 128) off every operand and counts the band alone; the
+    grouped products under `moe_experts`; no kernel named `flash_fwd`, which
+    the plain readers would count as a whole causal square."""
+    import importlib.util
+    import os
+    from perfbench import cells, op_scopes
+    from perfbench.families import laguna
+    from incubator_mxnet_tpu.models.transformer import (TransformerLM,
+                                                        _remat_policy)
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    cell = cells.resolve("laguna-s-2.1.train-8k")
+    model = TransformerLM(laguna.model_config(cell.config, cell.traffic))
+    shapes = jax.eval_shape(model.init_params, jax.random.PRNGKey(0))
+    params = {k: jax.ShapeDtypeStruct(v.shape, v.dtype, sharding=one_chip)
+              for k, v in shapes.items() if k.startswith("layer1_")}
+    x = jax.ShapeDtypeStruct((1, 8192, 3072), jnp.bfloat16,
+                             sharding=one_chip)
+    block = jax.checkpoint(lambda p, y: model._block(p, "layer1_", y, None),
+                           policy=_remat_policy(None))
+
+    def loss(p, y):
+        with jax.named_scope("forward"):
+            return block(p, y)[0].astype(jnp.float32).sum()
+    text = jax.jit(jax.value_and_grad(loss, argnums=(0, 1))).lower(
+        params, x).compile().as_text()
+    calls = [ln for ln in text.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in ln]
+    kernel = lambda ln: re.match(r"\s*(?:ROOT )?%(\w+?)(\.\d+)? = ",
+                                 ln).group(1)
+    flash = [ln for ln in calls if kernel(ln).startswith("flash")]
+    assert sorted(map(kernel, flash)) == [
+        "flash_win_bwd_dkv", "flash_win_bwd_dq", "flash_win_fwd"]
+    assert not [p for ln in flash for p in _padded(ln)]
+    names = set(re.findall(r'op_name="([^"]*)"', text))
+    under = lambda *words: any(all(f"/{w}/" in n for w in words)
+                               for n in names)
+    assert under("attn", "window_attn", "flash_win_fwd")
+    assert under("attn", "rope") and under("attn", "gate")
+    for scope in ("moe_route", "moe_dispatch", "moe_experts", "moe_shared",
+                  "moe_combine"):
+        assert under("mlp", "moe", scope), scope
+    assert under("rematted_computation", "moe_experts")
+    assert not under("rematted_computation", "flash_win_fwd")
+    # every other kernel of the block is a grouped product of the experts
+    rest = [ln for ln in calls if ln not in flash]
+    assert rest and all("/moe_experts/" in re.search(
+        r'op_name="([^"]*)"', ln).group(1) for ln in rest)
+    shaped = lambda text: [f"{m.group(1)}[{m.group(2)}]"
+                           for m in op_scopes.SHAPE.finditer(text)]
+    spec = importlib.util.spec_from_file_location("win", os.path.join(
+        cells.HERE, "layer_metrics", "flash_win_fwd_roofline.py"))
+    win = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(win)
+    peaks = {"bf16_flops_per_s": 197e12, "hbm_bytes_per_s": 819e9}
+    for ln in flash:
+        row = {"results": shaped(ln.split(" custom-call(")[0]),
+               "operands": shaped(ln.split(
+                   "operand_layout_constraints={")[1].split("}}")[0])}
+        assert row["operands"][:3] == ["bf16[1,8192,9216]"] * 3
+        if kernel(ln) == "flash_win_bwd_dkv":
+            continue
+        assert op_scopes.flash_dims(row) == (1, 8192, 9216, 9216)
+        backward = kernel(ln) == "flash_win_bwd_dq"
+        least, bound = win.least_seconds(row, 512, peaks, backward)
+        # 72 heads x (512 x 8192 - 512 x 511 / 2) pairs x 2 (128 + 128)
+        flops = (2 if backward else 1) * 149_796_421_632
+        assert (least, bound) == (flops / 197e12, "FLOPs")
 
 
 # -- BatchNorm's all-reduces on a dp mesh -------------------------------------
