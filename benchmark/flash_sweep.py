@@ -1,24 +1,31 @@
-"""Device time of the three flash kernels, causal, by sub-block edge.
+"""Device time of the flash kernels, causal, by sub-block edge.
 
     python benchmark/flash_sweep.py [--subs 0,128,256,512] [--calls 10]
         [--shapes 32x16x1024,16x16x2048,4x16x8192,1x32x8192x128,1x72x8192x128w512]
         [--out _chip/flash_sweep]
 
 One line of JSON per (shape B x H x T, or B x H x T x D, `w` and a window
-after it for a windowed call, whose kernels are `flash_win_*`; edge): microseconds
-a call of `flash_fwd`, `flash_bwd_dq` and `flash_bwd_dkv` on (B, T, H, D)
-bf16, D = 64 unless given, the layout the model hands `flash_attention`, and
-`other_us`: what else the device ran for one forward and one backward call,
-which is the copies that stand round the kernels (PR 33 took them away for
-shapes that pack two heads to a 128-lane block) and, before PR 35, the array
-of zeros in the place of lse's cotangent. All read from a device trace by
-kernel name (`perfbench/op_scopes.py`; host timing of a 2 ms kernel is
-noise). Edge 0 leaves the module as it is. The file calls nothing but the
-public entry, so an older tree runs it too: unpack the parent beside this
-tree, copy this file over its own, and run it there for the parent's column.
-Needs a TPU; exits 2 without one. The edge is set on the module for the sweep
-only: it is no option of the program (PERF.md section 6, PR 26, PR 33 and PR
-35, has the tables this printed).
+after it for a windowed call, whose kernels are `flash_win_*`; edge): under
+`us_a_call`, microseconds a call of each kernel the trace holds, by name, on
+(B, T, H, D) bf16, D = 64 unless given, the layout the model hands
+`flash_attention`; `bwd_us`, their sum over the backward kernels, a layer's
+backward whatever it is made of; and `other_us`: what else the device ran for
+one forward and one backward call, which is the copies that stand round the
+kernels (PR 33 took them away for shapes that pack two heads to a 128-lane
+block) and, before PR 35, the array of zeros in the place of lse's cotangent.
+Which kernels a shape's backward is (PR 37; `dispatch` has the counts): where
+a call is ONE grid block in q and in k, T <= 1,024 with no window, one call
+named `flash_bwd_dq` that emits dq, dk and dv; everywhere else (T 2,048 and
+8,192, any windowed call) the pair `flash_bwd_dq`, `flash_bwd_dkv`, as at
+every shape before PR 37: the parent's column has two rows where the
+change's has one, and `bwd_us` is what to read side by side. All read from a
+device trace by kernel name (`perfbench/op_scopes.py`; host timing of a 2 ms
+kernel is noise). Edge 0 leaves the module as it is. The file calls nothing
+but the public entry, so an older tree runs it too: unpack the parent beside
+this tree, copy this file over its own, and run it there for the parent's
+column. Needs a TPU; exits 2 without one. The edge is set on the module for
+the sweep only: it is no option of the program (PERF.md section 6, PR 26, PR
+33, PR 35 and PR 37, has the tables this printed).
 """
 import argparse
 import functools
@@ -117,6 +124,8 @@ def main():
                               "window": int(window) if window else None,
                               "block": fa._pick_block(t),
                               "check_rel_err": worst, "us_a_call": us,
+                              "bwd_us": sum(v for name, v in us.items()
+                                            if "_bwd_" in name),
                               "other_us": other,
                               "dispatch": fa.dispatch_stats()}),
                   flush=True)

@@ -32,7 +32,13 @@ Backward: REAL flash backward kernels (custom_vjp) — the forward also
 emits the per-row log-sum-exp; `_fa_bwd_dq_kernel` streams k/v blocks
 accumulating dq, `_fa_bwd_dkv_kernel` streams q blocks accumulating
 dk/dv, both recomputing p from the saved lse with bf16 matmuls and f32
-accumulation. lse and delta pass between the kernels as (heads, 1, T)
+accumulation. Where a call is ONE grid block in q and in k (both lengths
+up to 1,024, no window) nothing is accumulated across the grid, and one
+call of the dk/dv kernel gives dq as well, from the one p and ds a strip
+holds: five matrix products a head and one pass of exp where the pair
+makes seven and two, and delta never leaves the chip (_fa_backward; the
+call bears the dq kernel's name, which the benchmark leads a layer's
+backward from). lse and delta pass between the kernels as (heads, 1, T)
 f32, rows of lanes that no tile pads; the forward's output and lse carry
 names (RESIDUAL_NAMES) under which a caller's checkpoint keeps them, so
 that its backward runs no forward kernel again (TransformerLM's blocks
@@ -83,8 +89,8 @@ def pallas_available():
 # the grid's indices: a jnp function, an operator on a traced value among
 # them, is a jit of its own, and tracing one costs five times what binding the
 # primitive does.
-# A train step traces and lowers three kernels a layer in every process, and
-# a ref load is the dearest thing to lower, so each kernel loads its blocks
+# A train step traces and lowers two or three kernels a layer in every process,
+# and a ref load is the dearest thing to lower, so each kernel loads its blocks
 # once and cuts strips out of the values (PERF.md section 6, PR 26).
 
 def _keep(shape, q_off, k_off, transposed=False):
@@ -192,6 +198,17 @@ def _col(row):
     """A row of lanes (1, r) as a column (r, 1)."""
     from jax import lax
     return lax.expand_dims(lax.squeeze(row, (0,)), (1,))
+
+
+def _row_sums(x):
+    """The sums of x's rows, (r, w), as a row of lanes (1, r)."""
+    from jax import lax
+    return _row(lax.expand_dims(lax.reduce_sum(x, (1,)), (1,)))
+
+
+def _f32(x):
+    from jax import lax
+    return lax.convert_element_type(x, jnp.float32)
 
 
 def _dot(a, b, contract, prec):
@@ -735,16 +752,14 @@ def _fa_bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, out_ref, *rest,
     prec = _prec(q_ref.dtype)
     heads = q_ref.shape[2] // d
     *dlse_ref, dq_ref, delta_ref, acc_sc = rest
-    f32 = functools.partial(lax.convert_element_type,
-                            new_dtype=jnp.float32)
 
     @pl.when(lax.eq(j, 0))
     def _init():
         acc_sc[:] = lax.full(acc_sc.shape, 0.0, acc_sc.dtype)
 
         def head(h):
-            both = _kept(lax.mul(f32(do_ref[0]), f32(out_ref[0])), h, d)
-            delta = _row(lax.expand_dims(lax.reduce_sum(both, (1,)), (1,)))
+            delta = _row_sums(_kept(
+                lax.mul(_f32(do_ref[0]), _f32(out_ref[0])), h, d))
             delta_ref[h] = lax.sub(delta, dlse_ref[0][h]) if dlse_ref \
                 else delta
         _each_head(heads, head)
@@ -801,18 +816,36 @@ def _fa_bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, out_ref, *rest,
                                              dq_ref.dtype)
 
 
-def _fa_bwd_dkv_kernel(k_ref, v_ref, q_ref, do_ref, lse_ref, delta_ref,
-                       dk_ref, dv_ref, dk_sc, dv_sc, *, d, block_q, block_k,
-                       plan, sm_scale, band=None):
+def _fa_bwd_dkv_kernel(*refs, d, block_q, block_k, plan, sm_scale, band=None,
+                       alone=False):
     """dk/dv for one k block, streaming q blocks (innermost grid dim):
       p^T  = exp(s^T*scale - lse);     dv = sum_q p^T dO
       ds^T = p^T * (dp^T - delta);     dk = scale * sum_q ds^T Q
     `plan` and the refs as in _fa_kernel; the walk goes by k sub-block
     here, over the q sub-blocks at and below the diagonal. The scores are
-    transposed, so lse and delta are read as the rows of lanes they are."""
+    transposed, so lse and delta are read as the rows of lanes they are.
+    Refs: k, v, q, dO, lse, delta | dk, dv | scratch dk, dv (block_k, w).
+
+    `alone`: the call is ONE grid block in q and in k, so a strip's p^T and
+    ds^T are all that dq needs as well, and this kernel is the whole
+    backward: dq = scale * sum_k ds K too, and delta = rowsum(dO * O) - dlse
+    computed here, a head at a time, for no other kernel to read. The call
+    then bears the dq kernel's name and operand order (the benchmark's
+    contract, _fa_backward). Refs: q, k, v, dO, lse, O[, dlse] | dq, dk, dv |
+    scratch dk, dv, and dq (block_q, w). dq's product contracts over the
+    LEADING axis of a strip, ds^T's k rows: Mosaic turns the strip for it,
+    which by its schedule costs less than turning k once a head and dq once
+    a block round a product with ds^T on its right (PERF.md section 6, PR
+    37)."""
     from jax import lax
     from jax.experimental import pallas as pl
 
+    if alone:
+        (q_ref, k_ref, v_ref, do_ref, lse_ref, out_ref, *dlse_ref,
+         dq_ref, dk_ref, dv_ref, dk_sc, dv_sc, dq_sc) = refs
+    else:
+        (k_ref, v_ref, q_ref, do_ref, lse_ref, delta_ref,
+         dk_ref, dv_ref, dk_sc, dv_sc) = refs
     i = pl.program_id(3)
     n_q = pl.num_programs(3)
     k_off = lax.mul(pl.program_id(2), block_k)
@@ -824,22 +857,33 @@ def _fa_bwd_dkv_kernel(k_ref, v_ref, q_ref, do_ref, lse_ref, delta_ref,
     def _init():
         dk_sc[:] = lax.full(dk_sc.shape, 0.0, dk_sc.dtype)
         dv_sc[:] = lax.full(dv_sc.shape, 0.0, dv_sc.dtype)
+        if alone:
+            dq_sc[:] = lax.full(dq_sc.shape, 0.0, dq_sc.dtype)
 
     def add(strips):
         """Add to the dk, dv of k rows `cols` what q rows `rows`, masked by
         `keep`, give, for each (cols, rows, keep) of `strips`, in
         transposed scores (k rows, q rows), head by head; the score matmuls
         first, as in the dq kernel. Strips that leave k rows out leave
-        their dk, dv as they are."""
+        their dk, dv as they are. `alone`, the strips' q rows run to the
+        block's end, and each adds its k rows' part to their dq."""
         def head(h):
             q, k, v = q_ref[0], k_ref[0], v_ref[0]
             qs = _scaled(q, sm_scale, h, d)             # as the forward
             do = _scaled(do_ref[0], 1.0, h, d)
-            lse, delta = lse_ref[h], delta_ref[h]
+            lse = lse_ref[h]
+            if alone:
+                # dO has this head's lanes alone, and so has the product
+                delta = _row_sums(lax.mul(_f32(do), _f32(out_ref[0])))
+                if dlse_ref:
+                    delta = lax.sub(delta, dlse_ref[0][h])
+                kh = _scaled(k, 1.0, h, d)
+            else:
+                delta = delta_ref[h]
             first = [(_dot(_cut(k, cols), _cut(qs, rows), (1, 1), prec),
                       _dot(_cut(v, cols), _cut(do, rows), (1, 1), prec))
                      for cols, rows, _ in strips]
-            dks, dvs = [], []
+            dks, dvs, dq = [], [], None
             for (cols, rows, keep), (st, dpt) in zip(strips, first):
                 st = _apply(st, keep, lead=True)
                 pt = lax.exp(lax.sub(st, _under(lse, rows, st)))
@@ -847,15 +891,26 @@ def _fa_bwd_dkv_kernel(k_ref, v_ref, q_ref, do_ref, lse_ref, delta_ref,
                 # other heads' dv as it is; q has them all
                 dvs.append(_dot(lax.convert_element_type(pt, do.dtype),
                                 _cut(do, rows), (1, 0), prec))
-                dst = lax.mul(pt, lax.sub(dpt,
-                                          _under(delta, rows, dpt)))
-                dks.append(_dot(lax.convert_element_type(dst, q.dtype),
-                                _cut(q, rows), (1, 0), prec))
+                dst = lax.convert_element_type(
+                    lax.mul(pt, lax.sub(dpt, _under(delta, rows, dpt))),
+                    q.dtype)
+                dks.append(_dot(dst, _cut(q, rows), (1, 0), prec))
+                if alone:
+                    # over the strip's LEADING axis, its k rows; kh has this
+                    # head's lanes alone: the others' dq stays as it is
+                    part = _dot(dst, _cut(kh, cols), (0, 0), prec)
+                    if dq is not None:
+                        lead = rows[0] - strips[0][1][0]
+                        part = _stack([_cut(dq, (0, lead)), lax.add(
+                            _cut(dq, (lead, dq.shape[0])), part)])
+                    dq = part
             # the strips' k rows run from 0 on (a band's far block: up to
             # the block's end)
             at = slice(strips[0][0][0], strips[-1][0][1])
             dk_sc[at, :] = lax.add(dk_sc[at, :], _kept(_stack(dks), h, d))
             dv_sc[at, :] = lax.add(dv_sc[at, :], _stack(dvs))
+            if alone:
+                dq_sc[:] = lax.add(dq_sc[:], dq)
         _each_head(heads, head)
 
     def full(masked):
@@ -883,6 +938,9 @@ def _fa_bwd_dkv_kernel(k_ref, v_ref, q_ref, do_ref, lse_ref, delta_ref,
         dk_ref[0] = lax.convert_element_type(lax.mul(dk_sc[:], sm_scale),
                                              dk_ref.dtype)
         dv_ref[0] = lax.convert_element_type(dv_sc[:], dv_ref.dtype)
+        if alone:
+            dq_ref[0] = lax.convert_element_type(
+                lax.mul(dq_sc[:], sm_scale), dq_ref.dtype)
 
 
 def _fa_backward(q, k, v, do, lse, out, dlse, d, causal, sm_scale, block_q,
@@ -890,12 +948,18 @@ def _fa_backward(q, k, v, do, lse, out, dlse, d, causal, sm_scale, block_q,
     """q, k, v, do, out: (N, T, C), rows of C // d heads; lse, and dlse
     where the caller has a cotangent for lse (None: the dq kernel takes no
     such operand): (N * C // d, 1, Tq) f32. Returns (dq, dk, dv) via the
-    two flash backward kernels — O(block * T) memory, scores recomputed
-    from the saved lse. delta = rowsum(dO*O) is computed INSIDE the dq
-    kernel (per q block, at its first kv step) and handed to the dk/dv
-    kernel as an output shaped like lse — one fewer full pass over dO and O
-    than a separate XLA delta computation. With a `window` the kernels are
-    `flash_win_bwd_dq` and `flash_win_bwd_dkv`, over the band's grids."""
+    flash backward kernels — O(block * T) memory, scores recomputed from
+    the saved lse. Which, from the two lengths against the block sizes:
+
+    one grid block in q and in k, no `window`: ONE call, `flash_bwd_dq` by
+    name, of the dk/dv kernel `alone` (it gives dq too, and computes delta
+    for itself);
+    else the pair: delta = rowsum(dO*O) is computed INSIDE the dq kernel
+    (per q block, at its first kv step) and handed to the dk/dv kernel as
+    an output shaped like lse — one fewer full pass over dO and O than a
+    separate XLA delta computation. With a `window` the kernels are
+    `flash_win_bwd_dq` and `flash_win_bwd_dkv`, over the band's grids (a
+    band keeps the pair at every length)."""
     from jax import lax
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
@@ -916,17 +980,44 @@ def _fa_backward(q, k, v, do, lse, out, dlse, d, causal, sm_scale, block_q,
     of_k = _band_k_spec(block_k, w, band.n_k) if band else \
         pl.BlockSpec((1, block_k, w), lambda b, p, i, j: (b, j, p))
     of_row = _row_spec(g, n_p, block_q, 2)
+    # the dq kernel's operands: the one-block call's too
+    operands = (q, k, v, do, lse, out, *dlse)
+    specs = [of_q, of_k, of_k, of_q, of_row, of_q] + [of_row] * len(dlse)
+    alone = band is None and (tq, tk) == (block_q, block_k)
+    with _dispatch_lock:
+        _dispatch["bwd_fused" if alone else "bwd_pair"] += 1
+    if alone:
+        # one grid block in q and in k: nothing is accumulated across the
+        # grid, and the dk/dv kernel's strips give dq as well. The call is
+        # NAMED for the dq kernel and takes its operands in its order: the
+        # benchmark leads a layer's backward from that name and reads q,
+        # k, v off the first three operands (PERF.md section 3)
+        return pl.pallas_call(
+            functools.partial(_fa_bwd_dkv_kernel, alone=True, **sizes),
+            grid=(n, n_p, 1, 1),
+            in_specs=specs,
+            out_specs=[of_q, of_k, of_k],
+            out_shape=[jax.ShapeDtypeStruct((n, tq, c), q.dtype),
+                       jax.ShapeDtypeStruct((n, tk, c), k.dtype),
+                       jax.ShapeDtypeStruct((n, tk, c), v.dtype)],
+            scratch_shapes=[pltpu.VMEM((block_k, w), jnp.float32),
+                            pltpu.VMEM((block_k, w), jnp.float32),
+                            pltpu.VMEM((block_q, w), jnp.float32)],
+            compiler_params=params,
+            interpret=interpret,
+            name="flash_bwd_dq",
+        )(*operands)
     dq, delta = pl.pallas_call(
         functools.partial(_fa_bwd_dq_kernel, **sizes),
         grid=(n, n_p, tq // block_q, band.n_k if band else tk // block_k),
-        in_specs=[of_q, of_k, of_k, of_q, of_row, of_q] + [of_row] * len(dlse),
+        in_specs=specs,
         out_specs=[of_q, of_row],
         out_shape=[jax.ShapeDtypeStruct((n, tq, c), q.dtype), row],
         scratch_shapes=[pltpu.VMEM((block_q, w), jnp.float32)],
         compiler_params=params,
         interpret=interpret,
         name="flash_win_bwd_dq" if band else "flash_bwd_dq",
-    )(q, k, v, do, lse, out, *dlse)
+    )(*operands)
 
     # the grid's last two axes swap: k blocks outside, q blocks inside (a
     # band's: the k block's own q block and the n_k - 1 after it, held at
@@ -980,6 +1071,7 @@ def _pick_block(t, preferred=1024):
 # to the O(T^2) XLA reference when no block fits; that must be a choice
 # somebody can see, not a silent one (chip_smoke.py asserts on it).
 _dispatch = {"pallas": 0, "reference": 0, "direct": 0, "transposed": 0,
+             "bwd_fused": 0, "bwd_pair": 0,
              "causal_subblocks_run": 0, "causal_subblocks_all": 0,
              "window_subblocks_run": 0, "window_subblocks_all": 0}
 _dispatch_lock = threading.Lock()
@@ -990,7 +1082,10 @@ def dispatch_stats():
     calls served by the Pallas kernels vs dropped to attention_reference;
     of the former, "direct": those whose operands reached the kernels in
     the caller's layout, and "transposed": those whose operands were
-    transposed to (B*H, T, D) first (_direct);
+    transposed to (B*H, T, D) first (_direct); "bwd_fused" and
+    "bwd_pair": traced backward calls that were ONE kernel call (a call
+    that is one grid block in q and in k) and those that were the dq / dkv
+    pair (_fa_backward);
     "causal_subblocks_run" of "causal_subblocks_all": over the traced
     CAUSAL forward kernel calls, the score sub-blocks one head computes
     and those in its (Tq, Tk) square (_causal_plan; the backward pair
@@ -1076,8 +1171,9 @@ def _flash_vjp_fwd(q, k, v, causal, sm_scale, window):
 
 
 def _flash_vjp_bwd(causal, sm_scale, window, res, g):
-    """Backward. With a Pallas forward (saved lse) the two flash backward
-    KERNELS run (dq streams k/v blocks; dk/dv streams q blocks) — O(block
+    """Backward. With a Pallas forward (saved lse) the flash backward
+    KERNELS run (dq streams k/v blocks; dk/dv streams q blocks; one call
+    for both where a head is one grid block: _fa_backward) — O(block
     * T) memory, bf16 matmuls, f32 accumulation. Fallback (no pallas /
     untileable): an XLA lax.scan over q blocks with the same recompute
     math."""

@@ -332,6 +332,17 @@ _WALK_CASES = {
 }
 
 
+def _dense_with_lse(q, k, v, causal, sm):
+    """(out, lse) of dense attention in float32, (B, T, H, D) and (B, H,
+    T): what one ring hop returns."""
+    f32 = lambda x: x.astype(jnp.float32)
+    s = jnp.einsum("bqhd,bkhd->bhqk", f32(q), f32(k)) * sm
+    if causal:
+        s = jnp.where(jnp.tril(jnp.ones(s.shape[-2:], bool)), s, -jnp.inf)
+    return (jnp.einsum("bhqk,bkhd->bqhd", jax.nn.softmax(s, -1), f32(v)),
+            jax.scipy.special.logsumexp(s, axis=-1))
+
+
 def _losses(fa, entry, causal, tq, tk, sm):
     """(flash, dense): a scalar of the kernels' results and the same of the
     dense reference, as functions of q, k, v (B, T, H, D). `hop` is
@@ -343,14 +354,8 @@ def _losses(fa, entry, causal, tq, tk, sm):
             return jnp.sum(f32(out) ** 2) + 0.7 * jnp.sum(jnp.sin(lse))
 
         def dense(q_, k_, v_):
-            s = jnp.einsum("bqhd,bkhd->bhqk", f32(q_), f32(k_)) * sm
-            if causal:
-                s = jnp.where(jnp.tril(jnp.ones((tq, tk), bool)), s,
-                              -jnp.inf)
-            out = jnp.einsum("bhqk,bkhd->bqhd", jax.nn.softmax(s, -1),
-                             f32(v_))
-            return jnp.sum(out ** 2) + 0.7 * jnp.sum(jnp.sin(
-                jax.scipy.special.logsumexp(s, axis=-1)))
+            out, lse = _dense_with_lse(q_, k_, v_, causal, sm)
+            return jnp.sum(out ** 2) + 0.7 * jnp.sum(jnp.sin(lse))
     else:
         def flash(q_, k_, v_):
             return jnp.sum(f32(fa.flash_attention(
@@ -588,3 +593,105 @@ def test_a_block_that_keeps_the_flash_residuals_is_the_unkept_block(dtype):
                     jax.tree_util.tree_leaves(unkept)):
         np.testing.assert_array_equal(np.asarray(a, np.float32),
                                       np.asarray(b, np.float32))
+
+
+# -- one backward call where a call is one grid block (PR 37) -----------------
+# (B, H, D, Tq, Tk, causal, dlse): with blocks as long as the two lengths
+# `_fa_backward` makes ONE call, the dk/dv kernel's walk giving dq as well;
+# with blocks half as long, on the same operands, the pair it always made.
+# `dlse` is a ring hop's cotangent of lse; an odd head count and D = 80 take
+# the transposed route, one head a block.
+_ONE_BLOCK_CASES = {
+    "causal_T256": (2, 2, 64, 256, 256, True, False),
+    "full_T256": (1, 2, 64, 256, 256, False, False),
+    "causal_T512": (1, 4, 64, 512, 512, True, False),
+    "full_T512_D128": (1, 2, 128, 512, 512, False, False),
+    "causal_T1024": (1, 2, 64, 1024, 1024, True, False),
+    "full_T1024": (1, 2, 64, 1024, 1024, False, False),
+    "causal_T1024_D128": (1, 1, 128, 1024, 1024, True, False),
+    "causal_Tq512_Tk1024": (1, 2, 64, 512, 1024, True, False),
+    "causal_Tq1024_Tk512": (1, 2, 64, 1024, 512, True, False),
+    "full_Tq256_Tk512": (2, 2, 64, 256, 512, False, False),
+    "hop_causal_T1024": (1, 2, 64, 1024, 1024, True, True),
+    "hop_full_T512_D128": (1, 2, 128, 512, 512, False, True),
+    "hop_causal_Tq512_Tk1024": (1, 2, 64, 512, 1024, True, True),
+    "odd_H3_causal_T1024": (1, 3, 64, 1024, 1024, True, False),
+    "odd_H3_hop_full_T512": (1, 3, 64, 512, 512, False, True),
+    "D80_causal_T512": (1, 2, 80, 512, 512, True, False),
+}
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("case", list(_ONE_BLOCK_CASES))
+def test_one_block_backward_matches_the_pair_and_the_reference(case, dtype):
+    """dq, dk, dv of the one call against the pair's on the same operands
+    (no switch: the blocks are `_fa_backward`'s arguments) and against
+    `jax.vjp` of the dense float32 reference, lse's cotangent with it where
+    the case is a hop; dispatch_stats() says which path each took."""
+    import importlib
+    fa = importlib.import_module(
+        "incubator_mxnet_tpu.parallel.flash_attention")
+    B, H, D, tq, tk, causal, hop = _ONE_BLOCK_CASES[case]
+    rng = np.random.RandomState(37)
+    q, k, v, do = (jnp.asarray(rng.randn(B, t, H, D).astype(np.float32) * 0.5
+                               ).astype(dtype) for t in (tq, tk, tk, tq))
+    dlse = jnp.asarray(rng.randn(B, H, tq).astype(np.float32) * 0.3) \
+        if hop else None
+    sm = 1.0 / np.sqrt(D)
+    direct = fa._direct(H, D)
+    ops = [fa._operand(x, direct) for x in (q, k, v, do)]
+    out, lse = fa._fa_forward(*ops[:3], D, causal, sm, tq, tk,
+                              fa._interpret())
+    rows = None if dlse is None else dlse.reshape(B * H, 1, tq)
+
+    def backward(block_q, block_k):
+        before = fa.dispatch_stats()
+        got = fa._fa_backward(*ops, lse, out, rows, D, causal, sm, block_q,
+                              block_k, fa._interpret())
+        after = fa.dispatch_stats()
+        return [np.asarray(fa._result(g, like, direct), np.float32)
+                for g, like in zip(got, (q, k, v))], \
+            (after["bwd_fused"] - before["bwd_fused"],
+             after["bwd_pair"] - before["bwd_pair"])
+    one, took_one = backward(tq, tk)
+    pair, took_pair = backward(tq // 2, tk // 2)
+    assert (took_one, took_pair) == ((1, 0), (0, 1))
+    _, vjp = jax.vjp(lambda *a: _dense_with_lse(*a, causal, sm), q, k, v)
+    want = vjp((do.astype(jnp.float32),
+                jnp.zeros((B, H, tq)) if dlse is None else dlse))
+    # against the reference: this file's bounds for the pair; against the
+    # pair: the same products of the same bf16 probabilities, summed in
+    # another order
+    to_ref, to_pair = (2e-4, 2e-5) if dtype == "float32" else (5e-2, 1e-2)
+    for name, a, b, c in zip(("dq", "dk", "dv"), one, pair, want):
+        c = np.asarray(c, np.float32)
+        scale = max(1e-3, np.abs(c).max())
+        assert np.abs(a - b).max() / scale < to_pair, (name, "pair")
+        assert np.abs(a - c).max() / scale < to_ref, (name, "reference")
+
+
+@pytest.mark.parametrize("shape, kw, fused", [
+    ((2, 1024, 4, 64), {}, True),
+    ((1, 2048, 2, 64), {}, False),
+    ((1, 1024, 2, 64), {"window": 256}, False),
+    ((1, 512, 3, 64), {}, True)],
+    ids=["one_block_T1024", "grid_T2048", "window_T1024", "transposed_T512"])
+def test_the_backward_path_is_read_off_the_lengths(shape, kw, fused):
+    """Which backward a traced call took, by dispatch_stats(): one call
+    where the two lengths are one grid block each and there is no band, the
+    pair everywhere else. Counted at trace time: nothing runs here."""
+    import importlib
+    fa = importlib.import_module(
+        "incubator_mxnet_tpu.parallel.flash_attention")
+    q = jax.ShapeDtypeStruct(shape, jnp.bfloat16)
+    before = fa.dispatch_stats()
+    text = str(jax.make_jaxpr(jax.grad(
+        lambda *a: fa.flash_attention(*a, causal=True, **kw)
+        .astype(jnp.float32).sum(), argnums=(0, 1, 2)))(q, q, q))
+    after = fa.dispatch_stats()
+    assert (after["bwd_fused"] - before["bwd_fused"],
+            after["bwd_pair"] - before["bwd_pair"]) \
+        == ((1, 0) if fused else (0, 1))
+    win = "win_" if kw else ""
+    assert (f"name=flash_{win}bwd_dkv" in text) is not fused
+    assert f"name=flash_{win}bwd_dq" in text

@@ -93,11 +93,21 @@ def test_trainstep_operations_carry_the_programs_names(trainstep_names,
     r"/jvp\(forward\)/layer0/attn/flash_fwd/",
     r"/rematted_computation/mlp/",
     r"/attn/flash_bwd_dq/",
-    r"/attn/flash_bwd_dkv/",
+    r"/transpose\(jvp\(forward\)\)/layer0/.*/checkpoint/attn/flash_bwd_dq/",
 ])
 def test_lm_step_operations_carry_the_programs_names(lm_names, pattern):
     assert any(re.search(pattern, n) for n in lm_names), \
         sorted(lm_names)[:40]
+
+
+def test_a_lm_steps_backward_is_one_kernel_a_layer(lm_names):
+    """16 positions are one grid block in q and in k: the one backward call
+    bears the dq kernel's name, and no dk/dv kernel stands beside it (PR
+    37)."""
+    assert not [n for n in lm_names if "flash_bwd_dkv" in n]
+    for layer in ("layer0", "layer1"):
+        assert any(f"/{layer}/checkpoint/attn/flash_bwd_dq/" in n
+                   for n in lm_names), layer
 
 
 def test_a_lm_step_runs_no_flash_forward_twice(lm_names):
@@ -340,29 +350,33 @@ def test_the_padding_reader_tells_a_column_from_a_row():
                        "f32[512,1,1024]{2,1,0:T(1,128)}) custom-call(")
 
 
-@pytest.mark.parametrize("kernels, dlse", [
-    (("flash_fwd",), False),
-    (("flash_bwd_dq", "flash_bwd_dkv"), False),
-    (("flash_bwd_dq", "flash_bwd_dkv"), True)],
-    ids=["flash_fwd", "flash_bwd", "flash_bwd_hop"])
+@pytest.mark.parametrize("kernels, dlse, t", [
+    (("flash_fwd",), False, 1024),
+    (("flash_bwd_dq",), False, 1024),
+    (("flash_bwd_dq",), True, 1024),
+    (("flash_bwd_dq", "flash_bwd_dkv"), False, 2048)],
+    ids=["flash_fwd", "flash_bwd", "flash_bwd_hop", "flash_bwd_pair"])
 def test_flash_kernels_keep_their_names_in_a_tpu_program(one_chip, kernels,
-                                                         dlse):
+                                                         dlse, t):
     """GPT-2 medium's shapes, (B, T, H * D) = (32, 1024, 16 * 64) in 1024 x
     1024 blocks of two heads: each kernel is ONE custom call whose
     instruction and `op_name` carry the kernel's name under the caller's
     scopes. `flash_time_share` reads the opcode, `flash_*_roofline` the name
     and the operand shapes: q, k, v first, three dimensions each, from which
     the benchmark counts what it counted from (B * H, T, D) = (512, 1024,
-    64). The row vectors are (B * H, 1, T), rows of lanes that no tile pads;
-    plain attention's dq call has six operands, a ring hop's a seventh, the
-    cotangent of lse."""
+    64). The row vectors are (B * H, 1, T), rows of lanes that no tile pads.
+    At T = 1,024 a call is one grid block in q and in k, and the backward is
+    ONE call, named `flash_bwd_dq` with the dq kernel's operands in its
+    order (six, a ring hop's cotangent of lse a seventh) and dq, dk, dv for
+    results; delta is no array of the program. At T = 2,048, in the same
+    blocks, the backward is the pair it always was."""
     import importlib
     from perfbench import op_scopes
     fa = importlib.import_module(     # the package exports a function by
         "incubator_mxnet_tpu.parallel.flash_attention")     # the same name
-    big = jax.ShapeDtypeStruct((32, 1024, 1024), jnp.bfloat16,
+    big = jax.ShapeDtypeStruct((32, t, 1024), jnp.bfloat16,
                                sharding=one_chip)
-    vec = jax.ShapeDtypeStruct((512, 1, 1024), jnp.float32,
+    vec = jax.ShapeDtypeStruct((512, 1, t), jnp.float32,
                                sharding=one_chip)
 
     def fwd(q, k, v):
@@ -387,7 +401,7 @@ def test_flash_kernels_keep_their_names_in_a_tpu_program(one_chip, kernels,
         assert re.match(rf"\s*(ROOT )?%{name}(\.\d+)? = ", line), line[:200]
         assert re.search(rf'op_name="jit\(\w+\)/forward/attn/{name}/'
                          r'pallas_call"', line), line[-400:]
-        assert "bf16[32,1024,1024]" in line.split("custom-call(")[1]
+        assert f"bf16[32,{t},1024]" in line.split("custom-call(")[1]
         assert not _padded(line), _padded(line)
     # the benchmark's count of the first call, from the compiled call's own
     # operand and result shapes, as `op_scopes` reads them off a trace
@@ -397,8 +411,10 @@ def test_flash_kernels_keep_their_names_in_a_tpu_program(one_chip, kernels,
            "operands": shapes(calls[0].split(
                "operand_layout_constraints={")[1].split("}}")[0])}
     backward = kernels[0] == "flash_bwd_dq"
-    assert row["operands"][:3] == ["bf16[32,1024,1024]"] * 3
-    assert op_scopes.flash_dims(row) == (32, 1024, 1024, 1024)
+    assert row["operands"][:3] == [f"bf16[32,{t},1024]"] * 3
+    assert op_scopes.flash_dims(row) == (32, t, 1024, 1024)
+    if t != 1024:
+        return
     peaks = {"bf16_flops_per_s": 197e12, "hbm_bytes_per_s": 819e9}
     least, bound = op_scopes.flash_least_seconds(row, peaks, backward)
     old = (512, 1024, 64)
@@ -406,13 +422,26 @@ def test_flash_kernels_keep_their_names_in_a_tpu_program(one_chip, kernels,
                                   backward=backward)
     assert flops == op_scopes.flash_flops(*old, backward=backward) \
         == (2 if backward else 1) * 68_719_476_736
-    assert (least, bound) == (flops / 197e12, "FLOPs")
     moved = sum(map(op_scopes.shape_bytes, row["operands"] + row["results"]))
-    # forward: q, k, v, o at 64 MiB and lse, 2 MiB by its shape: 270 MB; dq:
-    # q, k, v, dO, O, dq, and lse, delta and a hop's dlse
-    assert len(row["operands"]) == (3 if not backward else 6 + dlse)
-    assert moved == (4 * 64 + 2 if not backward
-                     else 6 * 64 + (2 + dlse) * 2) * 2**20
+    if not backward:
+        # q, k, v, o at 64 MiB and lse, 2 MiB by its shape: 270 MB
+        assert len(row["operands"]) == 3 and moved == (4 * 64 + 2) * 2**20
+        assert (least, bound) == (flops / 197e12, "FLOPs")
+        return
+    # q, k, v, dO, O, and lse and a hop's dlse; dq, dk, dv: every array of a
+    # layer's backward once, 539 MB, under the 697.7 us of its FLOPs
+    assert len(row["operands"]) == 6 + dlse and len(row["results"]) == 3
+    assert row["results"] == ["bf16[32,1024,1024]"] * 3
+    assert moved == (8 * 64 + (1 + dlse) * 2) * 2**20
+    assert moved / 819e9 < flops / 197e12
+    # the benchmark's reader adds k's and v's bytes to a `flash_bwd_dq` row
+    # for the dk and dv of a dkv call beside it; this call lists them among
+    # its own results, so they are counted twice and `flash_bwd_roofline`
+    # over-reads by 1.18 at this shape: a `benchmark` PR's to correct
+    # (PERF.md section 3, ROADMAP S1)
+    assert bound == "bytes"
+    assert least == (moved + 2 * 64 * 2**20) / 819e9
+    assert 1.17 < least / (flops / 197e12) < 1.19
 
 
 _QKV_SHAPED = ("bf16[32,1024,16,64]", "bf16[32,16,1024,64]",
@@ -422,9 +451,10 @@ _QKV_SHAPED = ("bf16[32,1024,16,64]", "bf16[32,16,1024,64]",
 def test_no_copy_stands_round_a_flash_call_in_a_tpu_program(one_chip,
                                                             monkeypatch):
     """Value and gradient of one remat `TransformerLM` block at GPT-2
-    medium's widths, batch 32 x 1,024, compiled for the chip: the three
-    flash kernels by name, each once (the block's checkpoint keeps the
-    forward's output and lse, so it is not run again), no `transpose` or
+    medium's widths, batch 32 x 1,024, compiled for the chip: the forward
+    kernel and the one backward call by name, each once (the block's
+    checkpoint keeps the forward's output and lse, so it is not run again;
+    1,024 positions are one grid block, so no dk/dv call), no `transpose` or
     `copy`, alone or as a fusion, over an array shaped like q, k, v or O in
     either of the layouts the kernels took before PR 33, and no f32 operand
     or result of a kernel that its tiles pad to over twice its numbers."""
@@ -464,8 +494,7 @@ def test_no_copy_stands_round_a_flash_call_in_a_tpu_program(one_chip,
             opcode == "fusion" and re.search("transpose|copy", name))
         if moving and shape.startswith(_QKV_SHAPED):
             moves.append(ln.strip()[:200])
-    assert sorted(calls) == ["flash_bwd_dkv", "flash_bwd_dq",
-                             "flash_fwd"], calls
+    assert sorted(calls) == ["flash_bwd_dq", "flash_fwd"], calls
     assert not moves, moves
     assert not padded, padded
 
@@ -475,8 +504,9 @@ def test_keeping_the_flash_residuals_costs_no_memory_in_a_tpu_program(
     """Value and gradient of `TransformerLM.loss` at GPT-2 medium's sizes,
     24 layers at 32 x 1,024, compiled for the chip twice: as the model
     builds its blocks' checkpoints, and with checkpoints that keep nothing.
-    Kept: each of a layer's three kernels once (the unkept program runs the
-    forward twice), no row vector that its tiles pad, no array of zeros in
+    Kept: each of a layer's two kernel calls once (the unkept program runs
+    the forward twice; the backward is one call, PR 37), no row vector that
+    its tiles pad, no array of zeros in
     the cotangent's place, no copy of an activation that the unkept program
     does not make (the output kept as (B, T, H, D) was copied twice a layer:
     to the chip's tiles that is another array than the kernel's (N, T, C)),
@@ -506,8 +536,7 @@ def test_keeping_the_flash_residuals_costs_no_memory_in_a_tpu_program(
     copies = lambda text: len(re.findall(
         r"= \w+\[32,1024,(1024|16,64)\]\S* copy\(", text))
     counts, calls, kept, text = compiled()
-    assert counts == {"flash_fwd": 24, "flash_bwd_dq": 24,
-                      "flash_bwd_dkv": 24}
+    assert counts == {"flash_fwd": 24, "flash_bwd_dq": 24}
     assert not [p for ln in calls for p in _padded(ln)]
     assert not re.search(r"= f32\[512,1,1024\]\S* broadcast\(", text)
     monkeypatch.setattr(transformer, "_remat_policy", lambda name: None)
