@@ -14,6 +14,10 @@ Typical use:
 """
 from __future__ import annotations
 
+import time as _time
+
+_import_began = _time.time()
+
 __version__ = "0.1.0"
 
 
@@ -61,6 +65,9 @@ def _configure_jax():
 
 _configure_jax()
 
+# first of the package's modules: its listener keeps a row for every program
+# jax traces, lowers or builds from here on, this import's own included
+from . import profiler
 from . import base
 from .base import MXNetError, MXTPUError
 from . import context
@@ -109,7 +116,6 @@ def __getattr__(name):
         "callback": ".callback",
         "monitor": ".monitor",
         "mon": ".monitor",
-        "profiler": ".profiler",
         "compile_cache": ".compile_cache",
         "runtime": ".runtime",
         "parallel": ".parallel",
@@ -138,3 +144,6 @@ def __getattr__(name):
         globals()[name] = m
         return m
     raise AttributeError(f"module {__name__!r} has no attribute {name!r}")
+
+
+profiler.setup_row("import", __name__, _import_began, _time.time())

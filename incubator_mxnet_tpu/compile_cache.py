@@ -33,6 +33,10 @@ Telemetry: every lookup reports through `profiler.compile_event` (so the
 compile table distinguishes memory hits / disk deserialize-hits / true XLA
 retraces), and aggregate `exec_cache_{hits,misses,disk_hits,evictions,
 bytes}` counters surface in `profiler.dumps()` and `render_prometheus()`.
+A call is two spans in a recorded trace, `mx:exec_lookup` (the call's
+signature, the memo, the memory tier, the two locks; arg `kind`) and
+`mx:launch` (the loaded executable called); a load or a compile is also a
+row of `profiler.setup_stats()`.
 
 This cache is complementary to jax's own persistent *compilation* cache
 (`JAX_COMPILATION_CACHE_DIR`, else `<checkout>/.jax_cache` — see
@@ -453,6 +457,17 @@ class _CachedJit:
         except Exception:       # noqa: BLE001 — torn-down interpreter
             pass
 
+    def _note_lookup(self, kind, ms, began):
+        """One lookup's telemetry: the compile table, and for a load or a
+        compile (never a steady call) a row of the set-up table from
+        `began` (time.time()), the fingerprint's trace included."""
+        from . import profiler as _prof
+        _prof.compile_event(self._key, cache_hit=(kind != "miss"),
+                            compile_ms=ms, disk=(kind == "disk"))
+        if kind != "hit":
+            _prof.setup_row("exec_lookup", f"{kind}:{self._key}", began,
+                            time.time())
+
     def _note_fallback(self):
         with _lock:
             _stats["fallbacks"] += 1
@@ -466,15 +481,22 @@ class _CachedJit:
     # -- public surface -------------------------------------------------
     def __call__(self, *args, **kwargs):
         from . import profiler as _prof
-        try:
-            exe, kind, ms = self._ensure(args, kwargs)
-        except Exception:       # noqa: BLE001 — tracers/odd leaves
+        # trace-only spans: inside TrainStep's booked `compute`
+        with _prof.span("exec_lookup", book=False) as lookup:
+            began = time.time()
+            try:
+                exe, kind, ms = self._ensure(args, kwargs)
+            except Exception:       # noqa: BLE001 — tracers/odd leaves
+                exe = None
+            else:
+                lookup.set_metadata(kind=kind)
+                self._note_lookup(kind, ms, began)
+        if exe is None:
             self._note_fallback()
             return self._fallback(*args, **kwargs)
-        _prof.compile_event(self._key, cache_hit=(kind != "miss"),
-                            compile_ms=ms, disk=(kind == "disk"))
         try:
-            return exe(*args, **kwargs)
+            with _prof.span("launch", book=False):
+                return exe(*args, **kwargs)
         except Exception:       # noqa: BLE001 — aval/layout skew at call
             self._note_fallback()
             return self._fallback(*args, **kwargs)
@@ -492,11 +514,10 @@ class _CachedJit:
         """Materialize the executable for this signature WITHOUT running
         it: args may be concrete arrays or `jax.ShapeDtypeStruct` avals.
         Returns "hit" / "disk" / "miss" — a warm fleet sees "disk"."""
-        from . import profiler as _prof
+        began = time.time()
         exe, kind, ms = self._ensure(args, kwargs)
         del exe
-        _prof.compile_event(self._key, cache_hit=(kind != "miss"),
-                            compile_ms=ms, disk=(kind == "disk"))
+        self._note_lookup(kind, ms, began)
         return kind
 
     def __repr__(self):

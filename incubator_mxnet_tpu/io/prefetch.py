@@ -21,6 +21,14 @@ Telemetry (the data-stall diagnosis surface): every consumer get publishes
 
 through the profiler counter registry, so a stalled run is diagnosable
 from ``profiler.dumps()`` or the ``/metrics`` Prometheus scrape alone.
+
+In a recorded jax profiler trace the consumer's wait is the span
+``mx:input_wait`` on the consumer's thread and the worker's placement of a
+batch ``mx:prefetch_place`` on the worker's own line, beside the step: both
+are trace annotations, there whenever a session records and never booked.
+``MXNET_STEP_ATTRIBUTION`` adds nothing here; the booked ``input_wait`` of
+its phase table is ``TrainStep.run_epoch``'s, round this iterator's
+``next``.
 """
 from __future__ import annotations
 
@@ -108,6 +116,7 @@ class DevicePrefetcher:
 
     # -- producer ----------------------------------------------------------
     def _worker(self):
+        from .. import profiler as _prof
         src = self._src
         try:
             # resume fast-forward: burn the already-consumed prefix off the
@@ -126,7 +135,8 @@ class DevicePrefetcher:
                 except StopIteration:
                     self._offer(("done", None, 0))
                     return
-                placed, nbytes = self._place(batch)
+                with _prof.span("prefetch_place", book=False):
+                    placed, nbytes = self._place(batch)
                 if not self._offer(("ok", placed, nbytes)):
                     return                      # closed while queue full
         except BaseException as e:              # noqa: BLE001 — re-raised
@@ -202,18 +212,21 @@ class DevicePrefetcher:
         return self
 
     def __next__(self):
+        from .. import profiler
         if self._done:
             raise StopIteration
         t0 = time.perf_counter()
-        while True:
-            try:
-                kind, payload, nbytes = self._queue.get(timeout=1.0)
-                break
-            except _queue_mod.Empty:
-                if not self._thread.is_alive() and self._queue.empty():
-                    self._done = True
-                    raise MXNetError(
-                        "device prefetch worker died without a sentinel")
+        with profiler.span("input_wait", book=False):
+            while True:
+                try:
+                    kind, payload, nbytes = self._queue.get(timeout=1.0)
+                    break
+                except _queue_mod.Empty:
+                    if not self._thread.is_alive() and self._queue.empty():
+                        self._done = True
+                        raise MXNetError(
+                            "device prefetch worker died without a "
+                            "sentinel")
         wait_ms = (time.perf_counter() - t0) * 1e3
         if kind != "ok":
             self._done = True

@@ -16,6 +16,7 @@ import dataclasses
 import functools
 import itertools
 import math
+import time
 
 import jax
 import jax.numpy as jnp
@@ -622,9 +623,11 @@ class TransformerLM:
         loss, counts), counts the step's routing counts a layer
         ({"held_slots", "slots_over"}: TransformerLM.apply), which cost the
         step nothing it would not compute anyway."""
+        from .. import profiler as _prof
         from ..parallel._compat import shard_map
         from ..parallel.tensor_parallel import transformer_param_specs
 
+        began = time.time()
         axis_names = mesh.axis_names
         has = {a: a in axis_names for a in ("dp", "tp", "sp")}
         sp_axis = "sp" if (use_sp and has["sp"]) else None
@@ -728,22 +731,29 @@ class TransformerLM:
                            + (None,) * routed,
                            donate_argnums=(0, 1))
 
+        # the helpers are set-up: each call a row of profiler.setup_stats()
         def shard_params(params):
             # jnp.asarray copy first: device_put may alias the source buffer
             # (zero-copy on CPU), and the donated step would then delete the
             # caller's arrays with it
-            return {k: jax.device_put(jnp.asarray(v).copy(),
-                                      NamedSharding(mesh, pspec[k]))
-                    for k, v in params.items()}
+            with _prof.setup_span("shard_params", "TransformerLM"):
+                return {k: jax.device_put(jnp.asarray(v).copy(),
+                                          NamedSharding(mesh, pspec[k]))
+                        for k, v in params.items()}
 
         def init_opt(params):
             # born with the step's layout: unplaced zeros would all land
             # on device 0 first and give the first call a signature (and a
             # trace) of its own
-            return {k: (jnp.zeros(v.shape, jnp.float32, device=param_sh[k]),
-                        jnp.zeros(v.shape, jnp.float32, device=param_sh[k]))
-                    for k, v in params.items()}
+            with _prof.setup_span("init_opt", "TransformerLM"):
+                return {k: (jnp.zeros(v.shape, jnp.float32,
+                                      device=param_sh[k]),
+                            jnp.zeros(v.shape, jnp.float32,
+                                      device=param_sh[k]))
+                        for k, v in params.items()}
 
+        _prof.setup_row("make_train_step", "TransformerLM", began,
+                        time.time())
         return jit_step, shard_params, init_opt
 
 

@@ -11,6 +11,7 @@ kvstore priority=-key).
 """
 from __future__ import annotations
 
+import time
 from collections import OrderedDict
 
 import jax
@@ -149,8 +150,10 @@ class TrainStep:
                  mesh=None, example_inputs=None, param_spec_fn=None,
                  param_rules=None, data_axis="dp", dtype=None, donate=True):
         from jax.sharding import NamedSharding, PartitionSpec as P
+        from .. import profiler as _prof
         from .. import shardlint as _sl
 
+        began = time.time()
         if example_inputs is None:
             raise MXNetError("TrainStep needs example_inputs")
         self.net = net
@@ -294,6 +297,7 @@ class TrainStep:
             donate_argnums=(0, 1) if self._donate else (),
             compiler_options=self._copts)
         self._jit_multi = {}
+        _prof.setup_row("train_step_init", self._jit_key, began, time.time())
 
     def _to_device(self, batch):
         import jax
@@ -360,8 +364,9 @@ class TrainStep:
         try:
             while True:
                 # manual next() so the host-side wait on the input
-                # pipeline is attributable (span is a shared no-op with
-                # MXNET_STEP_ATTRIBUTION off — zero bookkeeping)
+                # pipeline is attributable (with MXNET_STEP_ATTRIBUTION
+                # off the span books nothing: a trace annotation while a
+                # profiler session records, else a shared no-op)
                 with _prof.span("input_wait"):
                     batch = next(src, _end)
                 if batch is _end:
@@ -451,22 +456,28 @@ class TrainStep:
         from ..ndarray import random as _rnd
         from .. import fault as _fault
         from .. import profiler as _prof
-        _fault.inject("step")       # MXNET_FAULT_INJECT test hook
-        attr = _prof.attribution_enabled()
-        with _prof.span("h2d"):
-            arrs = self._to_device(batch)
-        rng = _rnd.next_key()
-        with _prof.span("compute"):
-            self.params, self.opt_state, loss = self._jit_step(
-                self.params, self.opt_state, rng, self._step_count, *arrs)
-            if attr:
-                # dispatch is async: the compute span is only real wall
-                # time if we sync on the result. Gated on attribution so
-                # the un-attributed hot path keeps XLA's pipelining.
-                _block = getattr(loss, "block_until_ready", None)
-                if _block is not None:
-                    _block()
-        self._step_count += 1
+        # train_step and rng are trace-only: under the gate the step's
+        # books are h2d + compute as before (profiler.py, above `span`)
+        with _prof.span("train_step", book=False):
+            _fault.inject("step")       # MXNET_FAULT_INJECT test hook
+            attr = _prof.attribution_enabled()
+            with _prof.span("h2d"):
+                arrs = self._to_device(batch)
+            with _prof.span("rng", book=False):
+                rng = _rnd.next_key()
+            with _prof.span("compute"):
+                self.params, self.opt_state, loss = self._jit_step(
+                    self.params, self.opt_state, rng, self._step_count,
+                    *arrs)
+                if attr:
+                    # dispatch is async: the compute span is only real
+                    # wall time if we sync on the result. Gated on
+                    # attribution so the un-attributed hot path keeps
+                    # XLA's pipelining.
+                    _block = getattr(loss, "block_until_ready", None)
+                    if _block is not None:
+                        _block()
+            self._step_count += 1
         return loss
 
     def run_steps(self, n, *batch):

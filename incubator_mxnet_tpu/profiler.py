@@ -35,7 +35,7 @@ BatchNorm epilogue is one `Convolution` entry. Eager dispatch enters no
 scope. `perfbench/op_scopes.py` reduces the same names to shares of device
 time.
 
-Three telemetry layers beyond the reference:
+Four telemetry layers beyond the reference:
 
 - **Memory profiler** (`profile_memory=True`): NDArray construction and the
   fused-step donation path report device buffers here; live/peak bytes are
@@ -50,6 +50,15 @@ Three telemetry layers beyond the reference:
   it through `compile_event(key, cache_hit, compile_ms)`. A cache key
   recompiling more than MXNET_COMPILE_WARN_THRESHOLD times logs a warning —
   the classic leaked-python-scalar / unbucketed-shape bug.
+- **Host spans on the trace's clock, set-up rows**: `span(phase)` is a
+  `jax.profiler.TraceAnnotation` named `mx:<phase>` whenever a jax profiler
+  session records (its books stay behind MXNET_STEP_ATTRIBUTION), so a
+  device trace shows what the host did in each idle gap of the chip; and
+  an always-on, bounded table of set-up rows `(phase, name, t0, t1)`
+  (jax's trace / lower / build time spans and the program's own builders)
+  says where the seconds before the first step go: `setup_stats()`. The
+  span names and the row format are set out above `span` and above
+  `setup_row`; `perfbench/host_spans.py` reads both.
 - **Scrape surface**: `render_prometheus()` serializes the counter/gauge
   registry in Prometheus text exposition format (served at GET /metrics by
   serve/server.py), and `continuous_dump`/`dump_period` run a daemon thread
@@ -57,6 +66,7 @@ Three telemetry layers beyond the reference:
 """
 from __future__ import annotations
 
+import itertools
 import json
 import logging
 import os
@@ -65,6 +75,8 @@ import threading
 import time
 import weakref
 from collections import defaultdict
+
+import jax
 
 from .base import MXNetError
 from . import mxsan as _mxsan
@@ -75,12 +87,11 @@ __all__ = ["set_config", "set_state", "start", "stop", "dump", "dumps",
            "compile_totals", "track_jit", "memory_event", "memory_stats",
            "memory_enabled", "render_prometheus",
            "span", "observe_phase", "request_phase", "attribution_enabled",
-           "attribution_enable",
-           "attribution_reset", "phase_stats", "phase_step_end",
+           "attribution_enable", "phase_stats", "phase_step_end",
            "last_step_phases", "span_records", "next_span_id", "trace_id",
            "clock_sync_event", "cost_event", "cost_stats",
            "cost_from_executable", "DEVICE_PEAKS", "device_peaks",
-           "device_peak_flops", "mfu_stats"]
+           "mfu_stats", "setup_row", "setup_span", "setup_stats"]
 
 _lock = _mxsan.lock("profiler.py", "_lock")
 _state = {
@@ -150,7 +161,6 @@ def start(profile_process="worker"):
     if _state["continuous"]:
         _start_dump_thread()
     if _state["tb_dir"]:
-        import jax
         os.makedirs(_state["tb_dir"], exist_ok=True)
         jax.profiler.start_trace(_state["tb_dir"])
         _state["tb_active"] = True
@@ -166,7 +176,6 @@ def stop(profile_process="worker"):
     _ndmod.MEMORY_HOOK = None
     _stop_dump_thread()
     if _state.get("tb_active"):
-        import jax
         jax.profiler.stop_trace()
         _state["tb_active"] = False
 
@@ -404,11 +413,41 @@ def track_jit(key, fn):
 # ---------------------------------------------------------------------------
 # step-time attribution (StepTimeline): profiler.span(phase) attributes every
 # train step / serve request into named phases — input_wait, h2d, compute,
-# collective, optimizer, ckpt_snapshot, queue_wait. Gated on
-# MXNET_STEP_ATTRIBUTION with the shardlint cached-boolean pattern: off (the
-# default) the hot paths take the gate branch and nothing else — span()
-# is never even called, and _span_records stays 0 (counter-asserted).
+# collective, optimizer, ckpt_snapshot, queue_wait.
+#
+# ONE span API, two sinks. (1) The trace's clock, always: while a jax
+# profiler session records (the benchmark's traced slice, start() with a
+# tensorboard_dir, anyone's jax.profiler.start_trace) a span is a
+# jax.profiler.TraceAnnotation named "mx:<phase>" on its thread's line of
+# the host plane, on the same clock as the device's "XLA Ops" lines, so an
+# idle gap of the chip can be laid against what the host was doing. With no
+# session recording that costs one read of TraceMe's flag. (2) The
+# aggregates (phase table, histograms, span ids, the chrome-trace event of
+# this module's own dump, the step vector of heartbeats): only under
+# MXNET_STEP_ATTRIBUTION, the shardlint cached-boolean pattern; off (the
+# default) _span_records stays 0 (counter-asserted). Spans whose time an
+# enclosing or enclosed booked span already holds are trace-only
+# (`book=False`), so the table under the gate is what it was.
+#
+# Names on the training path, parent to child (perfbench/host_spans.py reads
+# them; the names are the contract, as `forward`/`loss`/`optimizer` are for
+# the device's scopes):
+#   mx:train_step        TrainStep.__call__, whole (trace-only)
+#     mx:h2d             _to_device: the eager dtype cast, any device_put
+#     mx:rng             the eager key split (trace-only)
+#     mx:compute         round the jitted step: lookup + launch (+ the wait
+#                        for the loss, under the gate only)
+#       mx:exec_lookup   _CachedJit: call signature, memo, memory tier,
+#                        compile_event; arg kind = hit | disk | miss
+#       mx:launch        _CachedJit: the loaded executable called
+#   mx:input_wait        run_epoch's and DevicePrefetcher.__next__'s wait
+#   mx:prefetch_place    the prefetcher's worker thread, round its device_put
+#   mx:ckpt_snapshot, mx:collective, mx:optimizer, mx:pushpull, mx:server:<op>
+#                        the older sites, unchanged
 # ---------------------------------------------------------------------------
+
+_Annotation = jax.profiler.TraceAnnotation
+_recording = _Annotation.is_enabled     # TraceMe's flag: a session is on
 
 _attr_enabled = None        # cached MXNET_STEP_ATTRIBUTION read
 # log-spaced ms histogram bounds shared by every phase (floor 10us, x1.6):
@@ -442,15 +481,6 @@ def attribution_enable(on=True):
     prev = attribution_enabled()
     _attr_enabled = bool(on)
     return prev
-
-
-def attribution_reset():
-    """Forget the cached MXNET_STEP_ATTRIBUTION read and drop all phase
-    state — the next attribution_enabled() consults the environment."""
-    global _attr_enabled
-    _attr_enabled = None
-    with _lock:
-        _reset_phases_locked()
 
 
 def _reset_phases_locked():
@@ -498,8 +528,9 @@ def current_span_id():
 
 
 class _NullSpan:
-    """Shared no-op returned while attribution is off: no allocation, no
-    lock, no counter — the off path must cost one boolean check."""
+    """Shared no-op returned while attribution is off and no profiler
+    session records: no allocation, no lock, no counter — the off path
+    costs two boolean checks."""
     __slots__ = ()
 
     def __enter__(self):
@@ -508,17 +539,26 @@ class _NullSpan:
     def __exit__(self, *exc):
         return False
 
+    def set_metadata(self, **kwargs):
+        pass
+
 
 _NULL_SPAN = _NullSpan()
 
 
+def _annotation(phase, args):
+    return _Annotation("mx:" + phase, **args) if args \
+        else _Annotation("mx:" + phase)
+
+
 class _Span:
-    __slots__ = ("_phase", "_args", "_t0", "span_id", "parent_id")
+    __slots__ = ("_phase", "_args", "_t0", "_note", "span_id", "parent_id")
 
     def __init__(self, phase, args):
         self._phase = phase
         self._args = args
         self._t0 = None
+        self._note = None
         self.span_id = None
         self.parent_id = None
 
@@ -529,12 +569,17 @@ class _Span:
         self.parent_id = stack[-1][1] if stack else None
         self.span_id = next_span_id()
         stack.append((self._phase, self.span_id))
+        if _recording():
+            self._note = _annotation(self._phase, self._args)
+            self._note.__enter__()
         self._t0 = time.perf_counter()
         return self
 
     def __exit__(self, *exc):
         t1 = time.perf_counter()
         dur_ms = (t1 - self._t0) * 1e3
+        if self._note is not None:
+            self._note.__exit__(*exc)
         stack = getattr(_span_tls, "stack", None)
         if stack and stack[-1][1] == self.span_id:
             stack.pop()
@@ -542,16 +587,29 @@ class _Span:
                     self.span_id, self.parent_id, self._args)
         return False
 
+    def set_metadata(self, **kwargs):
+        """Args learned inside the span (a cache lookup's outcome)."""
+        self._args = dict(self._args or {}, **kwargs)
+        if self._note is not None:
+            self._note.set_metadata(**kwargs)
 
-def span(phase, args=None):
+
+def span(phase, args=None, book=True):
     """Context manager attributing the enclosed wall time to `phase`.
-    While MXNET_STEP_ATTRIBUTION is off this returns a shared no-op; on,
-    it books per-phase aggregates + histogram and (while the profiler is
-    running) a nested chrome-trace X span carrying span_id/parent/trace
-    linkage args."""
-    if not attribution_enabled():
-        return _NULL_SPAN
-    return _Span(str(phase), args)
+
+    While a jax profiler session records, the span is a TraceAnnotation
+    "mx:<phase>" in that session's trace, whatever the gate says. Under
+    MXNET_STEP_ATTRIBUTION it also books per-phase aggregates + histogram
+    and (while this profiler is running) a nested chrome-trace X span
+    carrying span_id/parent/trace linkage args; `book=False` keeps a span
+    out of those books (its time is inside a booked span, or holds one).
+    With neither, this returns a shared no-op. The object entered has
+    `set_metadata(**kwargs)` for args known only inside the span."""
+    if book and attribution_enabled():
+        return _Span(str(phase), args)
+    if _recording():
+        return _annotation(str(phase), args)
+    return _NULL_SPAN
 
 
 def observe_phase(phase, dur_ms, t0=None, args=None):
@@ -687,6 +745,140 @@ def clock_sync_event(peer, offset_us, rtt_us):
 
 
 # ---------------------------------------------------------------------------
+# set-up rows: where a process's seconds before its first step go. Always on,
+# a few hundred rows a process: nothing here runs in a steady step (jax's
+# listener fires only when jax traces, lowers or builds a program).
+#
+# A row is (phase, name, t0, t1), seconds on time.time() (jax's own clock
+# for these events), so a reader can cut the table at any instant. Phases:
+#   trace   jax traced a function to a jaxpr       (name: the function's;
+#   lower   jax turned the jaxpr into MLIR          from jax.monitoring's
+#   build   XLA compiled it, or a cache loaded it   time spans of >= 1 ms)
+#   import  incubator_mxnet_tpu/__init__.py, top to bottom (once)
+#   train_step_init, make_train_step, shard_params, init_opt
+#           the program's own builders (setup_span)
+#   exec_lookup   a load or a compile through compile_cache (name
+#           "<disk|miss>:<key>"): holds that call's trace/lower/build rows
+# jax reports a jit traced inside another function's trace as a row of its
+# own, so a phase's seconds are the UNION of its rows' intervals and a
+# name's are its rows' self time: never plain sums. perfbench/host_spans.py
+# reads the rows (setup_trace_s, setup_lower_s, setup_import_s).
+# ---------------------------------------------------------------------------
+
+_SETUP_EVENTS = {
+    "/jax/core/compile/jaxpr_trace_duration": "trace",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": "lower",
+    "/jax/core/compile/backend_compile_duration": "build",
+}
+_SETUP_MAX = 4096           # the FIRST rows are the set-up: later ones drop
+# Of jax's time spans only those of a millisecond or more become rows. The
+# rest are counted: a GPT-2 medium or ResNet-50 start fills 4,096 rows with
+# them before its step is traced (the `add`, `bitwise_xor`, ... that jax
+# traces inside another trace, 15 us each at the median, 81 us on average
+# on the chip's host), nearly all inside a longer row of their phase, whose
+# seconds the union holds anyway.
+_SETUP_MIN_S = 1e-3
+_setup_rows = []            # no lock: jax calls in under anyone's, and
+_setup_seq = itertools.count()      # append and next() are atomic
+_setup_short_seq = itertools.count()
+_setup_seen = [0, 0]        # rows offered; jax spans too short to be rows
+
+
+def setup_row(phase, name, t0, t1):
+    """Keep one set-up row (seconds on time.time())."""
+    n = next(_setup_seq)
+    _setup_seen[0] = n + 1
+    if n < _SETUP_MAX:
+        _setup_rows.append((phase, str(name), float(t0), float(t1)))
+
+
+class setup_span:
+    """`with setup_span(phase, name):` keeps the enclosed wall time as one
+    set-up row."""
+    __slots__ = ("_phase", "_name", "_t0")
+
+    def __init__(self, phase, name=""):
+        self._phase, self._name = phase, name
+
+    def __enter__(self):
+        self._t0 = time.time()
+        return self
+
+    def __exit__(self, *exc):
+        setup_row(self._phase, self._name, self._t0, time.time())
+        return False
+
+
+def _reset_setup():
+    global _setup_seq, _setup_short_seq
+    _setup_rows.clear()
+    _setup_seq, _setup_short_seq = itertools.count(), itertools.count()
+    _setup_seen[:] = [0, 0]
+
+
+def _on_jax_time_span(event, start_time, end_time, fun_name="", **_):
+    phase = _SETUP_EVENTS.get(event)
+    if phase is None:
+        return
+    if end_time - start_time < _SETUP_MIN_S:
+        _setup_seen[1] = next(_setup_short_seq) + 1
+    else:
+        setup_row(phase, fun_name, start_time, end_time)
+
+
+jax.monitoring.register_event_time_span_listener(_on_jax_time_span)
+
+
+def _self_seconds(rows):
+    """{(phase, name): seconds}: each row's duration less what the rows of
+    its own phase nested inside it cover."""
+    out = {}
+    for phase in {r[0] for r in rows}:
+        stack = []              # [name, start, end, seconds of children]
+
+        def close(until):
+            while stack and stack[-1][2] <= until:
+                name, start, end, child = stack.pop()
+                out[phase, name] = out.get((phase, name), 0.0) \
+                    + (end - start) - child
+                if stack:
+                    stack[-1][3] += end - start
+        for t0, neg_t1, name in sorted((r[2], -r[3], r[1]) for r in rows
+                                       if r[0] == phase):
+            close(t0)
+            t1 = min(-neg_t1, stack[-1][2]) if stack else -neg_t1
+            if t1 > t0:
+                stack.append([name, t0, t1, 0.0])
+        close(float("inf"))
+    return out
+
+
+def setup_stats(until=None, top=10):
+    """The set-up table: {"rows": [(phase, name, t0, t1)], "kept", "seen"
+    (rows offered: the first _SETUP_MAX are kept), "short" (jax's spans
+    under a millisecond, counted and not kept), "phases": {phase: seconds,
+    the union of its rows}, "top": [(phase, name, self seconds)] the `top`
+    costliest names}. `until` (time.time() seconds) keeps the rows that
+    ended by then: the instant of a first step makes this a start's
+    set-up. What dumps() and render_prometheus() show; dumps(reset=True)
+    empties the table like every other family."""
+    rows = [r for r in list(_setup_rows) if until is None or r[3] <= until]
+    phases = {}
+    for phase in {r[0] for r in rows}:
+        end, total = float("-inf"), 0.0
+        for t0, t1 in sorted((r[2], r[3]) for r in rows if r[0] == phase):
+            if t1 > end:
+                total += t1 - max(t0, end)
+                end = t1
+        phases[phase] = total
+    ranked = sorted(_self_seconds(rows).items(), key=lambda kv: -kv[1])
+    return {"rows": rows, "kept": len(_setup_rows),
+            "seen": max(len(_setup_rows), _setup_seen[0]),
+            "short": _setup_seen[1], "phases": phases,
+            "top": [(ph, name, sec) for (ph, name), sec in ranked[:top]]}
+
+
+# ---------------------------------------------------------------------------
 # compiler cost accounting: flops / bytes-accessed / peak memory per cached
 # executable, recorded at the cached_jit choke points from XLA's own
 # cost_analysis()/memory_analysis() — the compiler, not an analytic formula,
@@ -779,24 +971,12 @@ def cost_stats():
 
 def device_peaks():
     """The DEVICE_PEAKS row of device 0; an unlisted kind raises."""
-    import jax
-    from .base import MXNetError
     kind = jax.devices()[0].device_kind
     if kind not in DEVICE_PEAKS:
         raise MXNetError(
             f"no published peaks for device kind {kind!r}; add its row to "
             f"profiler.DEVICE_PEAKS (known: {sorted(DEVICE_PEAKS)})")
     return DEVICE_PEAKS[kind]
-
-
-def device_peak_flops():
-    """bf16 matmul peak FLOP/s of device 0. None on the CPU backend (no
-    trustworthy peak — MFU is then null rather than a made-up number);
-    an accelerator kind without a DEVICE_PEAKS row raises."""
-    import jax
-    if jax.default_backend() == "cpu":
-        return None
-    return device_peaks()["bf16_flops"]
 
 
 def mfu_stats():
@@ -838,7 +1018,10 @@ def mfu_stats():
            "pp_bubble_fraction": None,
            "d2h_bytes": offl.get("d2h_bytes"),
            "offload_wait_ms_per_step": offl.get("offload_wait_ms_per_step"),
-           "peak_flops": device_peak_flops(),
+           # no trustworthy peak on the CPU backend: MFU is then null
+           # rather than a made-up number; an unlisted accelerator raises
+           "peak_flops": None if jax.default_backend() == "cpu"
+           else device_peaks()["bf16_flops"],
            "flops_per_sec": None, "mfu": None}
     if compute_ms:
         out["flops_per_sec"] = rec["flops"] / (compute_ms / 1e3)
@@ -992,7 +1175,6 @@ def memory_stats():
             "free_events": _mem["frees"],
         }
     try:
-        import jax
         snap["jax_live_bytes"] = int(sum(
             getattr(a, "nbytes", 0) for a in jax.live_arrays()))
         dev_stats = {}
@@ -1245,6 +1427,7 @@ def dumps(reset=False, format="table", sort_by="total", ascending=False):
     attr = phase_stats()
     costs = cost_stats()
     mfu = mfu_stats()
+    setup = setup_stats()
     exec_cache = _exec_cache_stats()
     tune_snap = _tune_stats()
     fault_snap = _fault_stats()
@@ -1262,6 +1445,7 @@ def dumps(reset=False, format="table", sort_by="total", ascending=False):
             _costs.clear()
         with _lock:
             _reset_phases_locked()
+        _reset_setup()
         _reset_memory_locked()
         try:
             from . import compile_cache as _cc
@@ -1311,6 +1495,9 @@ def dumps(reset=False, format="table", sort_by="total", ascending=False):
             out["cost"] = costs
         if mfu is not None:
             out["mfu"] = {k: _finite(v) for k, v in mfu.items()}
+        if setup["rows"]:
+            out["setup"] = {k: setup[k] for k in ("phases", "top", "kept",
+                                                  "seen", "short")}
         if exec_cache is not None:
             out["exec_cache"] = exec_cache
         if tune_snap is not None:
@@ -1385,6 +1572,17 @@ def dumps(reset=False, format="table", sort_by="total", ascending=False):
                         if mfu["compute_ms_per_step"] else "")
                      + (f"  MFU={mfu['mfu'] * 100:.1f}%"
                         if mfu["mfu"] is not None else "  MFU=n/a"))
+    if setup["rows"]:
+        lines += ["", f"{'Set-up (phase; costliest names)':<64}"
+                      f"{'Seconds':>12}",
+                  "-" * 76]
+        for ph, sec in sorted(setup["phases"].items(), key=lambda kv: -kv[1]):
+            lines.append(f"{ph:<64}{sec:>12.3f}")
+        for ph, name, sec in setup["top"]:
+            lines.append(f"{'  ' + ph + ' ' + name[:56]:<64}{sec:>12.3f}")
+        lines.append(f"{'(rows kept / seen; spans under 1 ms, not kept)':<56}"
+                     f"{setup['kept']:>6} /{setup['seen']:>6};"
+                     f"{setup['short']:>6}")
     if exec_cache is not None:
         lines += ["", f"{'Executable cache (two-tier)':<34}{'Value':>12}",
                   "-" * 46]
@@ -1543,6 +1741,22 @@ def render_prometheus():
                          f'{total:.3f}')
             lines.append(f'mxnet_step_phase_ms_count{{phase="{lbl}"}} '
                          f'{cnt}')
+
+    setup = setup_stats()
+    if setup["rows"]:
+        family("mxnet_setup_phase_seconds", "gauge",
+               "seconds this process spent in each set-up phase (union of "
+               "the phase's rows: trace, lower, build, import, ...)")
+        for ph in sorted(setup["phases"]):
+            lines.append(f'mxnet_setup_phase_seconds'
+                         f'{{phase="{_prom_label(ph)}"}} '
+                         f'{setup["phases"][ph]:.6f}')
+        family("mxnet_setup_name_seconds", "gauge",
+               "self seconds of the ten costliest names of the set-up table")
+        for ph, name, sec in setup["top"]:
+            lines.append(f'mxnet_setup_name_seconds'
+                         f'{{phase="{_prom_label(ph)}",'
+                         f'name="{_prom_label(name)}"}} {sec:.6f}')
 
     costs = cost_stats()
     if costs:

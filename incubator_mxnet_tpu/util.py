@@ -255,12 +255,16 @@ ENV_VARS = collections.OrderedDict([
      "Test-suite only: base RNG seed for the randomized operator tests.")),
     ("MXNET_STEP_ATTRIBUTION", EnvSpec(False, "bool",
      "Enable step-time attribution: profiler.span(phase) wired into "
-     "TrainStep.run_epoch / Trainer.step / the serve batcher records "
+     "TrainStep.run_epoch / Trainer.step / the serve batcher books "
      "per-phase ms/step (input_wait, h2d, compute, collective, "
      "optimizer, ckpt_snapshot, queue_wait) into dumps(), nested "
-     "chrome-trace spans, and mxnet_step_phase_ms histograms. Off (the "
-     "default), the span API returns a shared no-op and the hot paths "
-     "do zero bookkeeping.")),
+     "chrome-trace spans, and mxnet_step_phase_ms histograms, and "
+     "TrainStep's compute span WAITS for the loss so that its time is "
+     "real. Always there, gate or no gate: while a jax profiler session "
+     "records, every span is a trace annotation mx:<phase> on the "
+     "device trace's clock. Off (the default) and with no session, the "
+     "span API returns a shared no-op and the hot paths do zero "
+     "bookkeeping.")),
     ("MXNET_FLIGHT_RECORDER", EnvSpec("", "str",
      "Directory for the crash flight recorder. When set, fault.py keeps "
      "a bounded ring of recent step records/events and dumps it "
