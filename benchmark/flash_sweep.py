@@ -13,19 +13,23 @@ backward whatever it is made of; and `other_us`: what else the device ran for
 one forward and one backward call, which is the copies that stand round the
 kernels (PR 33 took them away for shapes that pack two heads to a 128-lane
 block) and, before PR 35, the array of zeros in the place of lse's cotangent.
-Which kernels a shape's backward is (PR 37; `dispatch` has the counts): where
-a call is ONE grid block in q and in k, T <= 1,024 with no window, one call
-named `flash_bwd_dq` that emits dq, dk and dv; everywhere else (T 2,048 and
-8,192, any windowed call) the pair `flash_bwd_dq`, `flash_bwd_dkv`, as at
-every shape before PR 37: the parent's column has two rows where the
-change's has one, and `bwd_us` is what to read side by side. All read from a
+Which kernels a shape's backward is (`dispatch` has the counts): ONE call
+named `flash_bwd_dq` (`flash_win_bwd_dq` with a window) that emits dq, dk and
+dv, where a call is one grid block in q and in k (T <= 1,024: PR 37) and, since
+PR 39, at every causal length whose dq fits the chip's VMEM, 2,048 and 8,192
+among them, plain or windowed; the pair `flash_bwd_dq`, `flash_bwd_dkv` on a
+tree before that, as at every shape before PR 37: a parent's column has two
+rows where the change's has one, and `bwd_us` is what to read side by side.
+`pair_rel_err`, on a tree that keeps the pair as a function of its own
+(`_fa_backward_pair`, PR 39): the worst difference of the one call's dq, dk, dv
+from the pair's on the same operands, over the pair's largest. All read from a
 device trace by kernel name (`perfbench/op_scopes.py`; host timing of a 2 ms
-kernel is noise). Edge 0 leaves the module as it is. The file calls nothing
-but the public entry, so an older tree runs it too: unpack the parent beside
+kernel is noise). Edge 0 leaves the module as it is. That comparison apart,
+the file calls nothing but the public entry, so an older tree runs it too: unpack the parent beside
 this tree, copy this file over its own, and run it there for the parent's
 column. Needs a TPU; exits 2 without one. The edge is set on the module for
 the sweep only: it is no option of the program (PERF.md section 6, PR 26, PR
-33, PR 35 and PR 37, has the tables this printed).
+33, PR 35, PR 37 and PR 39, has the tables this printed).
 """
 import argparse
 import functools
@@ -77,6 +81,32 @@ def check(fa, jax, jnp, np, t):
     return worst
 
 
+def against_pair(fa, jax, np, q, k, v, do, window):
+    """The worst difference of _fa_backward's dq, dk, dv from the pair's on
+    the kernels' own operands, over the pair's largest; None on a tree whose
+    backward is the pair, or where the call takes it."""
+    if not hasattr(fa, "_fa_backward_pair"):
+        return None
+    (B, T, H, D), direct = q.shape, fa._direct(*q.shape[2:])
+    ops = [fa._operand(x, direct) for x in (q, k, v, do)]
+    block = fa._pick_block(T)
+    static = (D, True, D ** -0.5, block, block, False, window)
+    out, lse = jax.jit(lambda *a: fa._fa_forward(*a, *static))(*ops[:3])
+    before = fa.dispatch_stats()["bwd_fused"]
+    one = jax.jit(lambda *a: fa._fa_backward(*a, None, *static))(
+        *ops, lse, out)
+    if fa.dispatch_stats()["bwd_fused"] == before:
+        return None
+    pair = jax.jit(lambda *a: fa._fa_backward_pair(*a, None, *static))(
+        *ops, lse, out)
+    worst = 0.0
+    for a, b in zip(one, pair):
+        b = np.asarray(b, np.float32)
+        worst = max(worst, float(np.abs(np.asarray(a, np.float32) - b).max()
+                                 / max(1e-3, np.abs(b).max())))
+    return worst
+
+
 def main():
     ap = argparse.ArgumentParser()
     ap.add_argument("--subs", default="0")
@@ -123,7 +153,11 @@ def main():
             print(json.dumps({"b": b, "h": h, "t": t, "d": d, "sub": sub,
                               "window": int(window) if window else None,
                               "block": fa._pick_block(t),
-                              "check_rel_err": worst, "us_a_call": us,
+                              "check_rel_err": worst,
+                              "pair_rel_err": against_pair(
+                                  fa, jax, np, q, k, v, do,
+                                  int(window) if window else None),
+                              "us_a_call": us,
                               "bwd_us": sum(v for name, v in us.items()
                                             if "_bwd_" in name),
                               "other_us": other,

@@ -383,14 +383,14 @@ def leg_lm(cfg, rehearse, result_path):
     # skip; below that the whole square is one masked pass
     assert 0 < run <= square and (run < square or cfg["seq"] < 256), \
         "the causal walk inside the kernels did not engage"
-    # a layer's backward is ONE kernel call where its sequence is one grid
-    # block in q and in k (up to 1,024 positions), the dq / dkv pair beyond
+    # a causal layer's backward is ONE kernel call at every length whose dq
+    # a head fits the chip's VMEM (PR 39; up to 1,024 positions since PR
+    # 37); the dq / dkv pair only past that
     fused, pair = took["bwd_fused"], took["bwd_pair"]
     print(f"[smoke:lm] flash backward calls traced: {fused} alone, {pair} "
           "pairs", flush=True)
-    assert (fused, pair) == ((cfg["layers"], 0) if cfg["seq"] <= 1024
-                             else (0, cfg["layers"])), \
-        "the backward's path does not follow the sequence length"
+    assert (fused, pair) == (cfg["layers"], 0), \
+        "a causal layer's backward is not the one call"
     # a block's checkpoint keeps the forward kernel's output and lse, so the
     # step holds the kernel once a layer, not once more in each backward
     fwds = str(jax.make_jaxpr(step)(params, opt, tokens, targets, 0)).count(

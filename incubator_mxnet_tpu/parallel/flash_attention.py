@@ -32,11 +32,17 @@ Backward: REAL flash backward kernels (custom_vjp) — the forward also
 emits the per-row log-sum-exp; `_fa_bwd_dq_kernel` streams k/v blocks
 accumulating dq, `_fa_bwd_dkv_kernel` streams q blocks accumulating
 dk/dv, both recomputing p from the saved lse with bf16 matmuls and f32
-accumulation. Where a call is ONE grid block in q and in k (both lengths
-up to 1,024, no window) nothing is accumulated across the grid, and one
-call of the dk/dv kernel gives dq as well, from the one p and ds a strip
-holds: five matrix products a head and one pass of exp where the pair
-makes seven and two, and delta never leaves the chip (_fa_backward; the
+accumulation. That pair computes every score block twice. A call whose dq
+need not cross the grid's outer axis, or can be carried across it, is ONE
+call of the dk/dv kernel instead, which gives dq as well from the one p and
+ds a strip holds: five matrix products a head and one pass of exp where the
+pair makes seven and two, and delta never leaves the chip. That is a call of
+one grid block in q and in k (both lengths up to 1,024, no window), and
+every causal self-attention call of several, plain or windowed, whose head's
+dq (T, 128) f32 fits the chip's VMEM beside a step's buffers: the kernel
+walks k blocks outside and q blocks inside, adds each step's part to its q
+block's rows of the head's dq, and writes a q block's dq at its own k
+block's diagonal step, after which no k block meets it (_fa_backward; the
 call bears the dq kernel's name, which the benchmark leads a layer's
 backward from). lse and delta pass between the kernels as (heads, 1, T)
 f32, rows of lanes that no tile pads; the forward's output and lse carry
@@ -816,8 +822,30 @@ def _fa_bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, out_ref, *rest,
                                              dq_ref.dtype)
 
 
+def _join_rows(acc, rows, part):
+    """acc + part, as (first row, last, value): acc, None or such a triple,
+    is the sum so far; `part` holds `rows`, which start and end no earlier
+    than acc's and leave no gap after them (a walk's strips go down the
+    diagonal)."""
+    from jax import lax
+    if acc is None:
+        return (*rows, part)
+    lo, hi, val = acc
+    a, b = rows
+    assert lo <= a <= hi <= b, (acc[:2], rows)
+    pieces = []
+    if a > lo:
+        pieces.append(_cut(val, (0, a - lo)))
+    if hi > a:
+        pieces.append(lax.add(_cut(val, (a - lo, hi - lo)),
+                              _cut(part, (0, hi - a))))
+    if b > hi:
+        pieces.append(_cut(part, (hi - a, b - a)))
+    return lo, b, _stack(pieces)
+
+
 def _fa_bwd_dkv_kernel(*refs, d, block_q, block_k, plan, sm_scale, band=None,
-                       alone=False):
+                       alone=False, carried=False):
     """dk/dv for one k block, streaming q blocks (innermost grid dim):
       p^T  = exp(s^T*scale - lse);     dv = sum_q p^T dO
       ds^T = p^T * (dp^T - delta);     dk = scale * sum_q ds^T Q
@@ -826,21 +854,31 @@ def _fa_bwd_dkv_kernel(*refs, d, block_q, block_k, plan, sm_scale, band=None,
     transposed, so lse and delta are read as the rows of lanes they are.
     Refs: k, v, q, dO, lse, delta | dk, dv | scratch dk, dv (block_k, w).
 
-    `alone`: the call is ONE grid block in q and in k, so a strip's p^T and
-    ds^T are all that dq needs as well, and this kernel is the whole
-    backward: dq = scale * sum_k ds K too, and delta = rowsum(dO * O) - dlse
-    computed here, a head at a time, for no other kernel to read. The call
-    then bears the dq kernel's name and operand order (the benchmark's
-    contract, _fa_backward). Refs: q, k, v, dO, lse, O[, dlse] | dq, dk, dv |
-    scratch dk, dv, and dq (block_q, w). dq's product contracts over the
-    LEADING axis of a strip, ds^T's k rows: Mosaic turns the strip for it,
-    which by its schedule costs less than turning k once a head and dq once
-    a block round a product with ds^T on its right (PERF.md section 6, PR
-    37)."""
+    `alone` or `carried`, this kernel is the WHOLE backward: a strip's p^T
+    and ds^T are all that dq needs as well, dq = scale * sum_k ds K, and
+    delta = rowsum(dO * O) - dlse is computed here, a head at a time, for no
+    other kernel to read. The call then bears the dq kernel's name and
+    operand order (the benchmark's contract, _fa_backward). Refs: q, k, v,
+    dO, lse, O[, dlse] | dq, dk, dv | scratch dk, dv, and dq. dq's product
+    contracts over the LEADING axis of a strip, ds^T's k rows: Mosaic turns
+    the strip for it, which by its schedule costs less than turning k once a
+    head and dq once a block round a product with ds^T on its right
+    (PERF.md section 6, PR 37).
+    `alone`: the call is ONE grid block in q and in k, and the dq scratch is
+    that block's (block_q, w).
+    `carried`: causal self-attention over several grid blocks, plain or a
+    band. dq of a q block is summed over the k blocks that meet it, which
+    are this kernel's OUTER axis, so the scratch holds the whole head's dq
+    (Tq, w) across them, each grid step adding to its q block's rows; a q
+    block's first k block zeroes them, and its last is its OWN (no later k
+    block meets a causal q block), at whose first step, the diagonal's, they
+    are final: dq's output block goes by the outer axis and is written
+    there, once."""
     from jax import lax
     from jax.experimental import pallas as pl
 
-    if alone:
+    whole = alone or carried
+    if whole:
         (q_ref, k_ref, v_ref, do_ref, lse_ref, out_ref, *dlse_ref,
          dq_ref, dk_ref, dv_ref, dk_sc, dv_sc, dq_sc) = refs
     else:
@@ -860,19 +898,36 @@ def _fa_bwd_dkv_kernel(*refs, d, block_q, block_k, plan, sm_scale, band=None,
         if alone:
             dq_sc[:] = lax.full(dq_sc.shape, 0.0, dq_sc.dtype)
 
+    if carried:
+        # the q block this step meets (a band's step i: the i-th after the
+        # k block's own) and its rows' place in the head's dq
+        j = pl.program_id(2)
+        q_at = lax.add(j, i) if band else i
+        base = pl.multiple_of(lax.mul(q_at, block_q), block_q)
+        begins = lax.eq(j, 0)
+        if band:    # and no step past the sequence's edge: its q block is
+            begins = lax.bitwise_and(     # the last one's, held (q_block)
+                lax.bitwise_or(begins, lax.eq(i, band.n_k - 1)),
+                lax.le(q_at, band.blocks - 1))
+
+        @pl.when(begins)
+        def _begin():
+            dq_sc[pl.ds(base, block_q), :] = lax.full(
+                (block_q, dq_sc.shape[1]), 0.0, dq_sc.dtype)
+
     def add(strips):
         """Add to the dk, dv of k rows `cols` what q rows `rows`, masked by
         `keep`, give, for each (cols, rows, keep) of `strips`, in
         transposed scores (k rows, q rows), head by head; the score matmuls
         first, as in the dq kernel. Strips that leave k rows out leave
-        their dk, dv as they are. `alone`, the strips' q rows run to the
-        block's end, and each adds its k rows' part to their dq."""
+        their dk, dv as they are. With dq to give, each strip adds its k
+        rows' part to the dq of its q rows."""
         def head(h):
             q, k, v = q_ref[0], k_ref[0], v_ref[0]
             qs = _scaled(q, sm_scale, h, d)             # as the forward
             do = _scaled(do_ref[0], 1.0, h, d)
             lse = lse_ref[h]
-            if alone:
+            if whole:
                 # dO has this head's lanes alone, and so has the product
                 delta = _row_sums(lax.mul(_f32(do), _f32(out_ref[0])))
                 if dlse_ref:
@@ -895,22 +950,22 @@ def _fa_bwd_dkv_kernel(*refs, d, block_q, block_k, plan, sm_scale, band=None,
                     lax.mul(pt, lax.sub(dpt, _under(delta, rows, dpt))),
                     q.dtype)
                 dks.append(_dot(dst, _cut(q, rows), (1, 0), prec))
-                if alone:
+                if whole:
                     # over the strip's LEADING axis, its k rows; kh has this
                     # head's lanes alone: the others' dq stays as it is
-                    part = _dot(dst, _cut(kh, cols), (0, 0), prec)
-                    if dq is not None:
-                        lead = rows[0] - strips[0][1][0]
-                        part = _stack([_cut(dq, (0, lead)), lax.add(
-                            _cut(dq, (lead, dq.shape[0])), part)])
-                    dq = part
+                    dq = _join_rows(dq, rows, _dot(dst, _cut(kh, cols),
+                                                   (0, 0), prec))
             # the strips' k rows run from 0 on (a band's far block: up to
             # the block's end)
             at = slice(strips[0][0][0], strips[-1][0][1])
             dk_sc[at, :] = lax.add(dk_sc[at, :], _kept(_stack(dks), h, d))
             dv_sc[at, :] = lax.add(dv_sc[at, :], _stack(dvs))
-            if alone:
-                dq_sc[:] = lax.add(dq_sc[:], dq)
+            if alone:       # the strips' q rows are the block's
+                dq_sc[:] = lax.add(dq_sc[:], dq[2])
+            elif carried:   # a band's far block: a leading run of them
+                at = pl.ds(lax.add(base, dq[0]) if dq[0] else base,
+                           dq[1] - dq[0])
+                dq_sc[at, :] = lax.add(dq_sc[at, :], dq[2])
         _each_head(heads, head)
 
     def full(masked):
@@ -933,6 +988,13 @@ def _fa_bwd_dkv_kernel(*refs, d, block_q, block_k, plan, sm_scale, band=None,
     else:
         _causal_branches(plan, q_off, k_off, block_q, block_k, full, walk)
 
+    if carried:
+        @pl.when(lax.eq(q_at, j))
+        def _dq_done():
+            dq_ref[0] = lax.convert_element_type(
+                lax.mul(dq_sc[pl.ds(base, block_q), :], sm_scale),
+                dq_ref.dtype)
+
     @pl.when(lax.eq(i, lax.sub(n_q, 1)))
     def _finish():
         dk_ref[0] = lax.convert_element_type(lax.mul(dk_sc[:], sm_scale),
@@ -943,70 +1005,183 @@ def _fa_bwd_dkv_kernel(*refs, d, block_q, block_k, plan, sm_scale, band=None,
                 lax.mul(dq_sc[:], sm_scale), dq_ref.dtype)
 
 
+# Mosaic's own limit on a kernel's VMEM where a call names none (v5e).
+_SCOPED_VMEM = 16 * 2**20
+
+
+def _vmem_bytes():
+    """A TensorCore's VMEM on the chip the process runs on; off a TPU
+    (interpret mode, a compile for a described chip) a v5e's 128 MiB, the
+    chip the blocks were tuned on."""
+    try:
+        from jax.experimental.pallas import tpu as pltpu
+        return pltpu.get_tpu_info().vmem_capacity_bytes
+    except Exception:
+        return 128 * 2**20
+
+
+def _one_call_vmem(tq, block, w, g, itemsize, n_rows):
+    """Bytes of VMEM the one backward call holds at a grid step, `carried`:
+    what Pallas buffers twice (the blocks of q, k, v, dO, O in and dq, dk, dv
+    out, the `n_rows` row vectors a head), the three f32 scratches (dk and dv
+    of a block, dq of the whole head: tq rows) and two score blocks in f32
+    (five with f32 operands), which bounds what Mosaic keeps of a step's
+    strips (s^T turning into p^T, dp^T into ds^T; by its own count 1.77
+    blocks at 1,024 rows, 4.85 for f32, less below: PERF.md section 6, PR
+    39); lanes padded to a tile."""
+    lanes = -(-w // 128) * 128
+    piped = 2 * (8 * block * lanes * itemsize + n_rows * g * 8 * block * 4)
+    scratch = (2 * block + tq) * lanes * 4
+    return piped + scratch + (2 if itemsize <= 2 else 5) * block * block * 4
+
+
+def _carried_vmem(q, d, block, dlse):
+    """_one_call_vmem of the call over q (N, T, C), rows of C // d heads, in
+    blocks of `block` rows."""
+    w = _lane_block(q.shape[2], d)
+    return _one_call_vmem(q.shape[1], block, w, w // d, q.dtype.itemsize,
+                          1 if dlse is None else 2)
+
+
 def _fa_backward(q, k, v, do, lse, out, dlse, d, causal, sm_scale, block_q,
                  block_k, interpret, window=None):
     """q, k, v, do, out: (N, T, C), rows of C // d heads; lse, and dlse
-    where the caller has a cotangent for lse (None: the dq kernel takes no
+    where the caller has a cotangent for lse (None: the kernels take no
     such operand): (N * C // d, 1, Tq) f32. Returns (dq, dk, dv) via the
     flash backward kernels — O(block * T) memory, scores recomputed from
-    the saved lse. Which, from the two lengths against the block sizes:
+    the saved lse. Which, from what the call can see (causal or not, the two
+    lengths against the blocks, the window, the lane block):
 
-    one grid block in q and in k, no `window`: ONE call, `flash_bwd_dq` by
-    name, of the dk/dv kernel `alone` (it gives dq too, and computes delta
-    for itself);
-    else the pair: delta = rowsum(dO*O) is computed INSIDE the dq kernel
-    (per q block, at its first kv step) and handed to the dk/dv kernel as
-    an output shaped like lse — one fewer full pass over dO and O than a
-    separate XLA delta computation. With a `window` the kernels are
-    `flash_win_bwd_dq` and `flash_win_bwd_dkv`, over the band's grids (a
-    band keeps the pair at every length)."""
+    ONE call, `flash_bwd_dq` by name (`flash_win_bwd_dq` with a `window`),
+    of the dk/dv kernel, which gives dq too and computes delta for itself
+    (_fa_backward_one), where dq need not be summed across the grid's outer
+    axis: one grid block in q and in k; or where it can be carried across
+    it: causal self-attention in square blocks, plain or a band, with the
+    head's dq (Tq, w) f32 and the step's buffers inside half the chip's
+    VMEM (_one_call_vmem);
+    else the pair (_fa_backward_pair): a non-causal call of several blocks
+    (dq of a q block is final only at the LAST k block), unequal lengths, a
+    sequence too long for the scratch (a 128k ring shard)."""
+    tq, tk = q.shape[1], k.shape[1]
+    alone = window is None and (tq, tk) == (block_q, block_k)
+    carried = not alone and causal and (tq, block_q) == (tk, block_k) \
+        and _carried_vmem(q, d, block_q, dlse) <= _vmem_bytes() // 2
+    with _dispatch_lock:
+        _dispatch["bwd_fused" if alone or carried else "bwd_pair"] += 1
+    return (_fa_backward_one if alone or carried else _fa_backward_pair)(
+        q, k, v, do, lse, out, dlse, d, causal, sm_scale, block_q, block_k,
+        interpret, window)
+
+
+def _bwd_call(q, k, dlse, d, causal, sm_scale, block_q, block_k, window):
+    """What the backward calls share: the lane block w, heads a block g,
+    lane blocks a row n_p, the kernels' keyword arguments, the band, and
+    the dq kernel's operand specs (the one call's too) with a q block's."""
+    from jax.experimental import pallas as pl
+
+    n, tq, c = q.shape
+    tk = k.shape[1]
+    w = _lane_block(c, d)
+    g, n_p = w // d, c // w
+    plan = _causal_plan(tq, tk, block_q, block_k) if causal else None
+    band = _band_of(window, causal, tq, tk, block_q, block_k)
+    sizes = dict(d=d, block_q=block_q, block_k=block_k, plan=plan,
+                 sm_scale=sm_scale, **({"band": band} if band else {}))
+    of_q = pl.BlockSpec((1, block_q, w), lambda b, p, i, j: (b, i, p))
+    of_k = _band_k_spec(block_k, w, band.n_k) if band else \
+        pl.BlockSpec((1, block_k, w), lambda b, p, i, j: (b, j, p))
+    of_row = _row_spec(g, n_p, block_q, 2)
+    specs = [of_q, of_k, of_k, of_q, of_row, of_q] \
+        + [of_row] * (dlse is not None)
+    return w, g, n_p, sizes, band, specs, of_q, of_k
+
+
+def _fa_backward_one(q, k, v, do, lse, out, dlse, d, causal, sm_scale,
+                     block_q, block_k, interpret, window=None):
+    """_fa_backward's one call: the dk/dv kernel, giving dq as a third
+    result. It is NAMED for the dq kernel and takes its operands in its
+    order: the benchmark leads a layer's backward from that name and reads
+    q, k, v off the first three operands (PERF.md section 3)."""
     from jax import lax
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
     n, tq, c = q.shape
     tk = k.shape[1]
-    w = _lane_block(c, d)
-    g, n_p = w // d, c // w
-    params = _compiler_params()
-    plan = _causal_plan(tq, tk, block_q, block_k) if causal else None
-    band = _band_of(window, causal, tq, tk, block_q, block_k)
-    sizes = dict(d=d, block_q=block_q, block_k=block_k, plan=plan,
-                 sm_scale=sm_scale, **({"band": band} if band else {}))
-    row = jax.ShapeDtypeStruct((n * c // d, 1, tq), jnp.float32)
-    dlse = [] if dlse is None else [dlse]
-
-    of_q = pl.BlockSpec((1, block_q, w), lambda b, p, i, j: (b, i, p))
-    of_k = _band_k_spec(block_k, w, band.n_k) if band else \
-        pl.BlockSpec((1, block_k, w), lambda b, p, i, j: (b, j, p))
-    of_row = _row_spec(g, n_p, block_q, 2)
-    # the dq kernel's operands: the one-block call's too
-    operands = (q, k, v, do, lse, out, *dlse)
-    specs = [of_q, of_k, of_k, of_q, of_row, of_q] + [of_row] * len(dlse)
-    alone = band is None and (tq, tk) == (block_q, block_k)
-    with _dispatch_lock:
-        _dispatch["bwd_fused" if alone else "bwd_pair"] += 1
-    if alone:
+    w, g, n_p, sizes, band, specs, of_q, of_k = _bwd_call(
+        q, k, dlse, d, causal, sm_scale, block_q, block_k, window)
+    operands = (q, k, v, do, lse, out, *([] if dlse is None else [dlse]))
+    out_shape = [jax.ShapeDtypeStruct((n, tq, c), q.dtype),
+                 jax.ShapeDtypeStruct((n, tk, c), k.dtype),
+                 jax.ShapeDtypeStruct((n, tk, c), v.dtype)]
+    scratch = [pltpu.VMEM((block_k, w), jnp.float32),
+               pltpu.VMEM((block_k, w), jnp.float32)]
+    if band is None and (tq, tk) == (block_q, block_k):
         # one grid block in q and in k: nothing is accumulated across the
-        # grid, and the dk/dv kernel's strips give dq as well. The call is
-        # NAMED for the dq kernel and takes its operands in its order: the
-        # benchmark leads a layer's backward from that name and reads q,
-        # k, v off the first three operands (PERF.md section 3)
+        # grid, and the dk/dv kernel's strips give dq as well
         return pl.pallas_call(
             functools.partial(_fa_bwd_dkv_kernel, alone=True, **sizes),
             grid=(n, n_p, 1, 1),
             in_specs=specs,
             out_specs=[of_q, of_k, of_k],
-            out_shape=[jax.ShapeDtypeStruct((n, tq, c), q.dtype),
-                       jax.ShapeDtypeStruct((n, tk, c), k.dtype),
-                       jax.ShapeDtypeStruct((n, tk, c), v.dtype)],
-            scratch_shapes=[pltpu.VMEM((block_k, w), jnp.float32),
-                            pltpu.VMEM((block_k, w), jnp.float32),
-                            pltpu.VMEM((block_q, w), jnp.float32)],
-            compiler_params=params,
+            out_shape=out_shape,
+            scratch_shapes=scratch + [pltpu.VMEM((block_q, w), jnp.float32)],
+            compiler_params=_compiler_params(),
             interpret=interpret,
             name="flash_bwd_dq",
         )(*operands)
+    # k blocks outside, q blocks inside, as the dk/dv kernel walks them: a
+    # band's k block meets its own q block and the n_k - 1 after it (held at
+    # the sequence's last where the band ends before it), a plain one every
+    # q block from its own on (held at its own above the diagonal, where
+    # nothing runs: no block is fetched for it). dq's block goes by the k
+    # block: it is written at the diagonal, the outer step's first
+    q_block = (lambda j, i: lax.min(j + i, band.blocks - 1)) if band \
+        else (lambda j, i: lax.max(i, j))
+    of_q = pl.BlockSpec((1, block_q, w),
+                        lambda b, p, j, i: (b, q_block(j, i), p))
+    of_k = pl.BlockSpec((1, block_k, w), lambda b, p, j, i: (b, j, p))
+    of_row = _row_spec(g, n_p, block_q, 3, q_block)
+    return pl.pallas_call(
+        functools.partial(_fa_bwd_dkv_kernel, carried=True, **sizes),
+        grid=(n, n_p, tk // block_k, band.n_k if band else tq // block_q),
+        in_specs=[of_q, of_k, of_k, of_q, of_row, of_q]
+        + [of_row] * (dlse is not None),
+        out_specs=[of_k, of_k, of_k],
+        out_shape=out_shape,
+        scratch_shapes=scratch + [pltpu.VMEM((tq, w), jnp.float32)],
+        # the k blocks carry dq from one to the next: in order
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary",
+                                 "arbitrary"),
+            vmem_limit_bytes=max(_SCOPED_VMEM,
+                                 _carried_vmem(q, d, block_q, dlse))),
+        interpret=interpret,
+        name="flash_win_bwd_dq" if band else "flash_bwd_dq",
+    )(*operands)
+
+
+def _fa_backward_pair(q, k, v, do, lse, out, dlse, d, causal, sm_scale,
+                      block_q, block_k, interpret, window=None):
+    """_fa_backward's pair of calls, at any shape the kernels take:
+    `flash_bwd_dq` streams k/v blocks for dq, and computes delta =
+    rowsum(dO*O) - dlse INSIDE (per q block, at its first kv step), handing
+    it to `flash_bwd_dkv` as an output shaped like lse — one fewer full
+    pass over dO and O than a separate XLA delta computation. With a
+    `window` the kernels are `flash_win_bwd_dq` and `flash_win_bwd_dkv`,
+    over the band's grids. Every score block is computed twice: seven
+    matrix products a head and two passes of exp."""
+    from jax import lax
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    n, tq, c = q.shape
+    tk = k.shape[1]
+    w, g, n_p, sizes, band, specs, of_q, of_k = _bwd_call(
+        q, k, dlse, d, causal, sm_scale, block_q, block_k, window)
+    params = _compiler_params()
+    row = jax.ShapeDtypeStruct((n * c // d, 1, tq), jnp.float32)
+    of_row = _row_spec(g, n_p, block_q, 2)
     dq, delta = pl.pallas_call(
         functools.partial(_fa_bwd_dq_kernel, **sizes),
         grid=(n, n_p, tq // block_q, band.n_k if band else tk // block_k),
@@ -1017,7 +1192,7 @@ def _fa_backward(q, k, v, do, lse, out, dlse, d, causal, sm_scale, block_q,
         compiler_params=params,
         interpret=interpret,
         name="flash_win_bwd_dq" if band else "flash_bwd_dq",
-    )(*operands)
+    )(q, k, v, do, lse, out, *([] if dlse is None else [dlse]))
 
     # the grid's last two axes swap: k blocks outside, q blocks inside (a
     # band's: the k block's own q block and the n_k - 1 after it, held at
@@ -1083,9 +1258,9 @@ def dispatch_stats():
     of the former, "direct": those whose operands reached the kernels in
     the caller's layout, and "transposed": those whose operands were
     transposed to (B*H, T, D) first (_direct); "bwd_fused" and
-    "bwd_pair": traced backward calls that were ONE kernel call (a call
-    that is one grid block in q and in k) and those that were the dq / dkv
-    pair (_fa_backward);
+    "bwd_pair": traced backward calls that were ONE kernel call (one grid
+    block in q and in k, or causal self-attention whose dq fits the chip's
+    VMEM) and those that were the dq / dkv pair (_fa_backward);
     "causal_subblocks_run" of "causal_subblocks_all": over the traced
     CAUSAL forward kernel calls, the score sub-blocks one head computes
     and those in its (Tq, Tk) square (_causal_plan; the backward pair
@@ -1172,9 +1347,10 @@ def _flash_vjp_fwd(q, k, v, causal, sm_scale, window):
 
 def _flash_vjp_bwd(causal, sm_scale, window, res, g):
     """Backward. With a Pallas forward (saved lse) the flash backward
-    KERNELS run (dq streams k/v blocks; dk/dv streams q blocks; one call
-    for both where a head is one grid block: _fa_backward) — O(block
-    * T) memory, bf16 matmuls, f32 accumulation. Fallback (no pallas /
+    KERNELS run (one call of the dk/dv kernel that gives dq too, for a
+    causal call; dq streaming k/v blocks and dk/dv streaming q blocks where
+    it does not apply: _fa_backward) — O(block * T) memory (and a head's dq
+    in VMEM), bf16 matmuls, f32 accumulation. Fallback (no pallas /
     untileable): an XLA lax.scan over q blocks with the same recompute
     math."""
     from jax import lax
@@ -1183,7 +1359,7 @@ def _flash_vjp_bwd(causal, sm_scale, window, res, g):
     Tk = k.shape[1]
     if lse is not None:
         # v5e block sweep at T=2048 (docs/perf_notes.md round 4):
-        # (1024,1024) runs the backward pair at 34.3 TF/s vs 28.9 at the
+        # (1024,1024) ran the backward pair at 34.3 TF/s vs 28.9 at the
         # old (512,512); below T=2048 see _pick_block
         bq = _pick_block(Tq)
         bk = _pick_block(Tk)
@@ -1248,8 +1424,9 @@ def flash_attention(q, k, v, causal=False, sm_scale=None, window=None):
     attention_reference / the transformer flagship). Differentiable.
     `window`: query i reads keys j with 0 <= i - j < window (causal
     self-attention only); the kernels then run the band alone and are named
-    `flash_win_fwd`, `flash_win_bwd_dq`, `flash_win_bwd_dkv`, since the
-    benchmark counts a `flash_fwd` as a whole causal square.
+    `flash_win_fwd`, `flash_win_bwd_dq` (and `flash_win_bwd_dkv` where the
+    backward is the pair), since the benchmark counts a `flash_fwd` as a
+    whole causal square.
     Grouped K/V heads: k and v may hold H / g heads, query head i reading
     head i // g; they are repeated over the group before the kernels, whose
     operands are then three arrays of q's shape (what the benchmark's count

@@ -1,6 +1,8 @@
 """Pallas flash attention vs the attention_reference oracle. On the CPU
 mesh the kernel runs in Pallas interpret mode — the same kernel code path
 that compiles via Mosaic on TPU."""
+import re
+
 import numpy as np
 import pytest
 
@@ -332,13 +334,17 @@ _WALK_CASES = {
 }
 
 
-def _dense_with_lse(q, k, v, causal, sm):
+def _dense_with_lse(q, k, v, causal, sm, window=None):
     """(out, lse) of dense attention in float32, (B, T, H, D) and (B, H,
-    T): what one ring hop returns."""
+    T): what one ring hop returns. `window`: query i reads keys j with
+    0 <= i - j < window."""
     f32 = lambda x: x.astype(jnp.float32)
     s = jnp.einsum("bqhd,bkhd->bhqk", f32(q), f32(k)) * sm
     if causal:
         s = jnp.where(jnp.tril(jnp.ones(s.shape[-2:], bool)), s, -jnp.inf)
+    if window is not None:
+        gap = jnp.arange(s.shape[-2])[:, None] - jnp.arange(s.shape[-1])
+        s = jnp.where(gap < window, s, -jnp.inf)
     return (jnp.einsum("bhqk,bkhd->bqhd", jax.nn.softmax(s, -1), f32(v)),
             jax.scipy.special.logsumexp(s, axis=-1))
 
@@ -595,12 +601,16 @@ def test_a_block_that_keeps_the_flash_residuals_is_the_unkept_block(dtype):
                                       np.asarray(b, np.float32))
 
 
-# -- one backward call where a call is one grid block (PR 37) -----------------
-# (B, H, D, Tq, Tk, causal, dlse): with blocks as long as the two lengths
-# `_fa_backward` makes ONE call, the dk/dv kernel's walk giving dq as well;
-# with blocks half as long, on the same operands, the pair it always made.
-# `dlse` is a ring hop's cotangent of lse; an odd head count and D = 80 take
-# the transposed route, one head a block.
+# -- one backward call (PR 37: one grid block; PR 39: every causal grid) -------
+# (B, H, D, Tq, Tk, causal, dlse[, blocks a side, window]): `_fa_backward`
+# makes ONE call, the dk/dv kernel's walk giving dq as well, where the call is
+# one grid block in q and in k (blocks as long as the two lengths), and where
+# it is causal self-attention over several, plain or a band, the head's dq
+# carried across the k blocks in VMEM; `_fa_backward_pair` is the pair it
+# always made, on the same operands in the same blocks. `dlse` is a ring
+# hop's cotangent of lse; an odd head count and D = 80 take the transposed
+# route, one head a block. A band of `n_k` steps a k block has, at the
+# sequence's last n_k - 1 k blocks, steps past its edge, which run nothing.
 _ONE_BLOCK_CASES = {
     "causal_T256": (2, 2, 64, 256, 256, True, False),
     "full_T256": (1, 2, 64, 256, 256, False, False),
@@ -618,6 +628,27 @@ _ONE_BLOCK_CASES = {
     "odd_H3_causal_T1024": (1, 3, 64, 1024, 1024, True, False),
     "odd_H3_hop_full_T512": (1, 3, 64, 512, 512, False, True),
     "D80_causal_T512": (1, 2, 80, 512, 512, True, False),
+    # several grid blocks: 2 x 2 and 4 x 4, sub-blocks of 128 inside them
+    "grid2_causal_T512": (2, 2, 64, 512, 512, True, False, 2, None),
+    "grid2_causal_T512_D128": (1, 2, 128, 512, 512, True, False, 2, None),
+    "grid4_causal_T1024": (1, 2, 64, 1024, 1024, True, False, 4, None),
+    "grid4_causal_T1024_D128": (1, 1, 128, 1024, 1024, True, False, 4, None),
+    "grid4_causal_T512_no_sub_blocks": (1, 2, 64, 512, 512, True, False, 4,
+                                        None),
+    "grid2_hop_causal_T512": (1, 2, 64, 512, 512, True, True, 2, None),
+    "grid4_hop_causal_T1024_D128": (1, 1, 128, 1024, 1024, True, True, 4,
+                                    None),
+    "grid2_odd_H3_causal_T512": (1, 3, 64, 512, 512, True, False, 2, None),
+    "grid2_D80_hop_causal_T512": (1, 2, 80, 512, 512, True, True, 2, None),
+    # bands: n_k 2 (window 200 in blocks of 256), n_k 3 = every block of
+    # T 768 (window 600), the narrowest that has a gradient, one grid block
+    "band_nk2_T1024": (1, 2, 64, 1024, 1024, True, False, 4, 200),
+    "band_nk2_T1024_D128": (1, 1, 128, 1024, 1024, True, False, 4, 200),
+    "band_nk3_T768_D128": (2, 1, 128, 768, 768, True, False, 3, 600),
+    "band_nk2_T512_no_sub_blocks": (1, 2, 64, 512, 512, True, False, 4, 130),
+    "band_of_two_keys_T512": (1, 2, 64, 512, 512, True, False, 2, 2),
+    "band_one_block_T512": (1, 2, 64, 512, 512, True, False, 1, 300),
+    "band_nk2_odd_H3_T512": (1, 3, 64, 512, 512, True, False, 2, 256),
 }
 
 
@@ -625,13 +656,14 @@ _ONE_BLOCK_CASES = {
 @pytest.mark.parametrize("case", list(_ONE_BLOCK_CASES))
 def test_one_block_backward_matches_the_pair_and_the_reference(case, dtype):
     """dq, dk, dv of the one call against the pair's on the same operands
-    (no switch: the blocks are `_fa_backward`'s arguments) and against
-    `jax.vjp` of the dense float32 reference, lse's cotangent with it where
-    the case is a hop; dispatch_stats() says which path each took."""
+    (no switch: the pair is a function of its own) and against `jax.vjp` of
+    the dense float32 reference, lse's cotangent with it where the case is
+    a hop; dispatch_stats() says that `_fa_backward` took the one call."""
     import importlib
     fa = importlib.import_module(
         "incubator_mxnet_tpu.parallel.flash_attention")
-    B, H, D, tq, tk, causal, hop = _ONE_BLOCK_CASES[case]
+    B, H, D, tq, tk, causal, hop, grid, window = \
+        (_ONE_BLOCK_CASES[case] + (1, None))[:9]
     rng = np.random.RandomState(37)
     q, k, v, do = (jnp.asarray(rng.randn(B, t, H, D).astype(np.float32) * 0.5
                                ).astype(dtype) for t in (tq, tk, tk, tq))
@@ -640,23 +672,24 @@ def test_one_block_backward_matches_the_pair_and_the_reference(case, dtype):
     sm = 1.0 / np.sqrt(D)
     direct = fa._direct(H, D)
     ops = [fa._operand(x, direct) for x in (q, k, v, do)]
-    out, lse = fa._fa_forward(*ops[:3], D, causal, sm, tq, tk,
-                              fa._interpret())
+    static = (D, causal, sm, tq // grid, tk // grid, fa._interpret(), window)
+    out, lse = fa._fa_forward(*ops[:3], *static)
     rows = None if dlse is None else dlse.reshape(B * H, 1, tq)
 
-    def backward(block_q, block_k):
+    def backward(which):
         before = fa.dispatch_stats()
-        got = fa._fa_backward(*ops, lse, out, rows, D, causal, sm, block_q,
-                              block_k, fa._interpret())
+        got = which(*ops, lse, out, rows, *static)
         after = fa.dispatch_stats()
         return [np.asarray(fa._result(g, like, direct), np.float32)
                 for g, like in zip(got, (q, k, v))], \
             (after["bwd_fused"] - before["bwd_fused"],
              after["bwd_pair"] - before["bwd_pair"])
-    one, took_one = backward(tq, tk)
-    pair, took_pair = backward(tq // 2, tk // 2)
-    assert (took_one, took_pair) == ((1, 0), (0, 1))
-    _, vjp = jax.vjp(lambda *a: _dense_with_lse(*a, causal, sm), q, k, v)
+    one, took_one = backward(fa._fa_backward)
+    pair, took_pair = backward(fa._fa_backward_pair)
+    # the router counts; the pair called by name is no routed call
+    assert (took_one, took_pair) == ((1, 0), (0, 0))
+    _, vjp = jax.vjp(lambda *a: _dense_with_lse(*a, causal, sm, window),
+                     q, k, v)
     want = vjp((do.astype(jnp.float32),
                 jnp.zeros((B, H, tq)) if dlse is None else dlse))
     # against the reference: this file's bounds for the pair; against the
@@ -670,28 +703,91 @@ def test_one_block_backward_matches_the_pair_and_the_reference(case, dtype):
         assert np.abs(a - c).max() / scale < to_ref, (name, "reference")
 
 
+def test_the_pair_is_still_what_a_call_past_the_one_call_gets():
+    """A non-causal call of several grid blocks through `_fa_backward` is the
+    pair, bit for bit: dq of a q block is final only at the last k block."""
+    import importlib
+    fa = importlib.import_module(
+        "incubator_mxnet_tpu.parallel.flash_attention")
+    rng = np.random.RandomState(39)
+    ops = [jnp.asarray(rng.randn(1, 512, 128).astype(np.float32) * 0.5)
+           for _ in range(4)]
+    static = (64, False, 0.125, 256, 256, fa._interpret())
+    out, lse = fa._fa_forward(*ops[:3], *static)
+    before = fa.dispatch_stats()
+    routed = fa._fa_backward(*ops, lse, out, None, *static)
+    after = fa.dispatch_stats()
+    assert (after["bwd_fused"], after["bwd_pair"]) == \
+        (before["bwd_fused"], before["bwd_pair"] + 1)
+    for a, b in zip(routed, fa._fa_backward_pair(*ops, lse, out, None,
+                                                 *static)):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+
 @pytest.mark.parametrize("shape, kw, fused", [
     ((2, 1024, 4, 64), {}, True),
-    ((1, 2048, 2, 64), {}, False),
-    ((1, 1024, 2, 64), {"window": 256}, False),
-    ((1, 512, 3, 64), {}, True)],
-    ids=["one_block_T1024", "grid_T2048", "window_T1024", "transposed_T512"])
+    ((1, 2048, 2, 64), {}, True),
+    ((1, 1024, 2, 64), {"window": 256}, True),
+    ((1, 512, 3, 64), {}, True),
+    ((1, 8192, 2, 128), {"window": 512}, True),
+    ((1, 65536, 1, 128), {}, True),
+    ((1, 131072, 1, 128), {}, False),
+    ((1, 2048, 2, 64), {"causal": False}, False)],
+    ids=["one_block_T1024", "grid_T2048", "window_T1024", "transposed_T512",
+         "window_T8192", "grid_T65536", "past_the_vmem_budget_T131072",
+         "not_causal_T2048"])
 def test_the_backward_path_is_read_off_the_lengths(shape, kw, fused):
     """Which backward a traced call took, by dispatch_stats(): one call
-    where the two lengths are one grid block each and there is no band, the
-    pair everywhere else. Counted at trace time: nothing runs here."""
+    where the two lengths are one grid block each, and where the call is
+    causal self-attention, plain or a band, whose dq (T, 128) f32 fits half
+    the chip's VMEM with the step's buffers; the pair past that (a 128k ring
+    shard: 64 MiB of dq) and for a non-causal grid. Counted at trace time:
+    nothing runs here."""
     import importlib
     fa = importlib.import_module(
         "incubator_mxnet_tpu.parallel.flash_attention")
     q = jax.ShapeDtypeStruct(shape, jnp.bfloat16)
+    kw = {"causal": True, **kw}
     before = fa.dispatch_stats()
     text = str(jax.make_jaxpr(jax.grad(
-        lambda *a: fa.flash_attention(*a, causal=True, **kw)
+        lambda *a: fa.flash_attention(*a, **kw)
         .astype(jnp.float32).sum(), argnums=(0, 1, 2)))(q, q, q))
     after = fa.dispatch_stats()
     assert (after["bwd_fused"] - before["bwd_fused"],
             after["bwd_pair"] - before["bwd_pair"]) \
         == ((1, 0) if fused else (0, 1))
-    win = "win_" if kw else ""
+    win = "win_" if "window" in kw else ""
     assert (f"name=flash_{win}bwd_dkv" in text) is not fused
     assert f"name=flash_{win}bwd_dq" in text
+    if fused and shape[1] > 1024:
+        # the k blocks carry dq, so they run in order; the call names its
+        # VMEM, the forward kernel's none
+        assert "('parallel', 'parallel', 'arbitrary', 'arbitrary')" in text
+        assert re.findall(r"vmem_limit_bytes=(\w+)", text) == [
+            "None", str(max(16 * 2**20, fa._carried_vmem(
+                jax.ShapeDtypeStruct((shape[0], shape[1], shape[2] * shape[3]),
+                                     jnp.bfloat16), shape[3], 1024, None)))]
+
+
+def test_the_one_calls_vmem_is_reckoned_from_its_buffers():
+    """`_one_call_vmem` at the cells' shapes against the least limit the TPU
+    compiler accepted there (AOT for a described v5e, PERF.md section 6, PR
+    39): above it, by under a tenth; and the route's budget is half the
+    chip's VMEM."""
+    import importlib
+    fa = importlib.import_module(
+        "incubator_mxnet_tpu.parallel.flash_attention")
+    mib = 2 ** 20
+    # (tq, block, w, g, itemsize, row vectors) -> the compiler's need, MiB
+    for args, need in [((8192, 1024, 128, 1, 2, 1), 15.99),
+                       ((2048, 1024, 128, 2, 2, 1), 13.25),
+                       ((32768, 1024, 128, 1, 2, 1), 28.06),
+                       ((8192, 512, 128, 1, 2, 1), 7.875),
+                       ((2048, 1024, 128, 1, 4, 1), 29.56),
+                       ((2048, 1024, 128, 2, 4, 1), 27.69)]:
+        got = fa._one_call_vmem(*args) / mib
+        assert need <= got < 1.25 * need, (args, got)
+    assert fa._one_call_vmem(8192, 1024, 128, 1, 2, 1) == 17891328
+    assert fa._vmem_bytes() == 128 * mib        # no TPU here: a v5e's
+    assert fa._one_call_vmem(65536, 1024, 128, 1, 2, 1) <= 64 * mib \
+        < fa._one_call_vmem(131072, 1024, 128, 1, 2, 1)

@@ -136,15 +136,16 @@ def _jaxpr(shape, **kw):
 
 
 # sha256 of the jaxpr (addresses taken out) of value and gradient of a
-# causal call, recorded on the tree before a window existed (PR 35's):
-# MiniCPM-SALA's call and a transposed-route shape, whose backward is the
-# pair of kernels it was. GPT-2 medium's call is one grid block in q and in k,
-# and since PR 37 its backward is one call under the dq kernel's name: its
-# jaxpr is no longer that tree's, and is held to its kernels' names alone.
+# causal call. GPT-2 medium's call is one grid block in q and in k: its
+# backward is one call under the dq kernel's name since PR 37, and its jaxpr
+# is that tree's still (recorded on PR 38's commit). MiniCPM-SALA's call and
+# a transposed-route shape are several blocks a side: since PR 39 their
+# backward is one call too, no longer the pair of the tree before a window
+# existed, and they are held to their kernels' names alone.
 BEFORE_A_WINDOW = {
-    (32, 1024, 16, 64): None,
-    (1, 8192, 32, 128): "5fcc271b14d4ae28",
-    (2, 2048, 3, 80): "00ed065539914c6c",
+    (32, 1024, 16, 64): "9c3e49e673407ff1",
+    (1, 8192, 32, 128): None,
+    (2, 2048, 3, 80): None,
 }
 
 
@@ -157,17 +158,48 @@ def test_a_call_with_no_window_traces_to_the_kernels_it_always_did(shape):
     assert _jaxpr(shape, window=None) == plain
     kernels = lambda text: set(re.findall(r"name=(flash_\w+)", text)) \
         - set(fa.RESIDUAL_NAMES)
-    if shape[1] > 1024:
-        assert kernels(plain) == {"flash_fwd", "flash_bwd_dq",
-                                  "flash_bwd_dkv"}
-        windowed = _jaxpr(shape, window=512)
-        assert kernels(windowed) == {
-            "flash_win_fwd", "flash_win_bwd_dq", "flash_win_bwd_dkv"}
-    else:
-        assert kernels(plain) == {"flash_fwd", "flash_bwd_dq"}
-        # a band keeps the pair at every length
-        assert kernels(_jaxpr(shape, window=512)) == {
-            "flash_win_fwd", "flash_win_bwd_dq", "flash_win_bwd_dkv"}
+    # one backward call at every length, with a band as without
+    assert kernels(plain) == {"flash_fwd", "flash_bwd_dq"}
+    assert kernels(_jaxpr(shape, window=512)) == {
+        "flash_win_fwd", "flash_win_bwd_dq"}
+
+
+# (T, heads, D, window), in blocks of 1,024 (the whole of a shorter T): the
+# band's steps a k block, and whether one of them falls past the last block
+BANDS = {
+    "one_block": (1024, 2, 64, 300),
+    "two_steps_one_past_the_edge": (2048, 1, 128, 512),
+    "three_steps_two_past_the_edge_d64": (3072, 2, 64, 1536),
+    "window_over_the_sequence": (2048, 1, 128, 4000),
+}
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("case", sorted(BANDS))
+def test_a_bands_one_backward_call_is_the_pair(case, dtype):
+    """dq, dk, dv of a windowed call's one backward call (the dk/dv kernel's
+    walk carrying dq across the band's k blocks) against the pair's on the
+    same operands: dk and dv the same bits, dq the same products summed in
+    f32 in the same order."""
+    t, h, d, window = BANDS[case]
+    ks = jax.random.split(jax.random.PRNGKey(t + window), 4)
+    q, k, v, do = (jax.random.normal(key, (1, t, h * d), jnp.float32)
+                   .astype(dtype) for key in ks)
+    block = fa._pick_block(t)
+    static = (d, True, d ** -0.5, block, block, fa._interpret(), window)
+    out, lse = fa._fa_forward(q, k, v, *static)
+    before = fa.dispatch_stats()
+    one = fa._fa_backward(q, k, v, do, lse, out, None, *static)
+    after = fa.dispatch_stats()
+    assert (after["bwd_fused"], after["bwd_pair"]) == \
+        (before["bwd_fused"] + 1, before["bwd_pair"])
+    pair = fa._fa_backward_pair(q, k, v, do, lse, out, None, *static)
+    f32 = lambda x: np.asarray(x, np.float32)
+    for got, want in zip(one[1:], pair[1:]):
+        np.testing.assert_array_equal(f32(got), f32(want))
+    bound = 2e-5 if dtype == "float32" else 1e-2
+    assert np.abs(f32(one[0]) - f32(pair[0])).max() \
+        / np.abs(f32(pair[0])).max() < bound
 
 
 def test_a_block_keeps_a_windowed_calls_output_and_lse():
