@@ -537,6 +537,16 @@ class TransformerLM:
         `counts`, (logits, the expert layers' routing: {"held_slots",
         "slots_over"}, a number an expert layer, and "experts" (layers, B *
         T, per_token), what each token chose)."""
+        logits, routed = self._head(params, tokens, sp_axis, positions,
+                                    tp_axis, mesh, counts)
+        with jax.named_scope("logits"):
+            logits = logits.astype(jnp.float32)
+        return (logits, routed) if counts else logits
+
+    def _head(self, params, tokens, sp_axis, positions, tp_axis, mesh,
+              counts):
+        """`apply`'s logits in the head's own dtype, and the expert layers'
+        routing where `counts` asks for it (else None)."""
         cfg = self.cfg
         with jax.named_scope("embed"):
             x = _scaled(params["embed"][tokens], cfg.embed_scale)
@@ -563,10 +573,9 @@ class TransformerLM:
             x = self._norm(x, params, "lnf")
         with jax.named_scope("logits"):
             head = params["embed" if cfg.tied_head else "head"]
-            logits = (_scaled(x, cfg.logit_scale) @ head.T).astype(
-                jnp.float32)
+            logits = _scaled(x, cfg.logit_scale) @ head.T
         if not counts:
-            return logits
+            return logits, None
         return logits, {k: jnp.stack([seen[k] for seen in routed])
                         for k in ("held_slots", "slots_over", "experts")}
 
@@ -578,15 +587,19 @@ class TransformerLM:
         # `forward`, `loss` and (in the train step) `optimizer` are the top
         # words a device trace is read by (PERF.md section 3)
         with jax.named_scope("forward"):
-            logits = self.apply(params, tokens, sp_axis, positions, tp_axis,
-                                mesh, counts)
+            logits, routed = self._head(params, tokens, sp_axis, positions,
+                                        tp_axis, mesh, counts)
             if counts:      # the step's counters: two numbers a layer
-                logits, routed = logits
                 routed = {k: routed[k] for k in ("held_slots", "slots_over")}
         with jax.named_scope("loss"):
-            logp = jax.nn.log_softmax(logits, axis=-1)
-            nll = -jnp.take_along_axis(logp, targets[..., None],
-                                       axis=-1)[..., 0]
+            # logsumexp less the target's logit, read off the head's own
+            # logits: no log-softmax over the vocabulary, which XLA writes
+            # whole as f32 (B, T, V) to gather one number a token from. A
+            # bf16 logit is exact in f32, so the value is the same.
+            lse = jax.nn.logsumexp(logits.astype(jnp.float32), axis=-1)
+            picked = jnp.take_along_axis(logits, targets[..., None],
+                                         axis=-1)[..., 0].astype(jnp.float32)
+            nll = lse - picked
             return (jnp.mean(nll), routed) if counts else jnp.mean(nll)
 
     # -- sharded training ---------------------------------------------------
