@@ -216,6 +216,28 @@ def _mem_put(fp, exe):
 # disk tier
 # ---------------------------------------------------------------------------
 
+def _compile(traced):
+    """XLA-compile `traced`. On XLA:CPU a process that has loaded a program
+    from jax's persistent cache serializes that program without its
+    kernels, even after compiling it again: a fresh process that loads such
+    an entry from the disk tier fails at its first run ("Function ... not
+    found"). So where the tier will write the executable, on the CPU, jax's
+    cache is left out of the compile."""
+    lowered = traced.lower()
+    if not _cache_dir() or _backend() != "cpu":
+        return lowered.compile()
+    import jax
+    from jax.experimental.compilation_cache import compilation_cache
+    on = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    try:
+        return lowered.compile()
+    finally:
+        jax.config.update("jax_enable_compilation_cache", on)
+        compilation_cache.reset_cache()
+
+
 def _entry_path(d, fp):
     return os.path.join(d, fp + _SUFFIX)
 
@@ -431,7 +453,7 @@ class _CachedJit:
                 return exe, "disk", (time.perf_counter() - t0) * 1e3
             if traced is None:
                 traced = self._jfn.trace(*args, **kwargs)
-            exe = traced.lower().compile()
+            exe = _compile(traced)
             ms = (time.perf_counter() - t0) * 1e3
             with _lock:
                 _stats["misses"] += 1

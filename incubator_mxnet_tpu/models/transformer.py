@@ -71,11 +71,15 @@ class Rotary:
 class GQA:
     """One "gqa" layer: `heads` query heads over the model's n_kv_heads K/V
     heads of head_dim; causal, and with a `window` query i reads keys j with
-    i - j < window; a sigmoid output gate A HEAD (a (d_model, heads)
-    projection of the block's input) before the output projection."""
+    i - j < window; with `qk_norm` an RMSNorm of head_dim (a learned weight)
+    on each head of q and of k before rotary; with `gate` a sigmoid output
+    gate A HEAD (a (d_model, heads) projection of the block's input) before
+    the output projection."""
     heads: int
     window: int | None = None
     rotary: Rotary = Rotary()
+    qk_norm: bool = False
+    gate: bool = True
 
 
 @dataclasses.dataclass(frozen=True)
@@ -86,7 +90,11 @@ class Experts:
     chosen, this chip computes the experts [held[0], held[0] + held[1]),
     each a SwiGLU of `width` (parallel/moe.moe_routed: `rows` the one
     buffer's rows); beside them one ungated shared SwiGLU of `shared_width`
-    (0: none)."""
+    (0: none). With `bias_rate` the layer
+    carries a bias over all `count` experts: a token's experts are the
+    `per_token` largest of s + bias, weighted by s alone, and after each
+    step of `make_train_step` the bias moves by bias_rate towards an even
+    load (parallel/moe.moe_balance)."""
     count: int
     held: tuple
     per_token: int
@@ -96,6 +104,7 @@ class Experts:
     scaling: float = 1.0
     norm_topk: bool = True
     rows: int = 0
+    bias_rate: float | None = None
 
 
 @dataclasses.dataclass(frozen=True)
@@ -110,7 +119,8 @@ class TransformerConfig:
     | "mha" | causal softmax attention, n_heads heads of d_model / n_heads; ring attention over `sp`, Megatron heads over `tp` | n_heads, flash_attention |
     | "sparse" | grouped-query causal softmax attention, n_heads query heads over n_kv_heads K/V heads of d_model / n_heads, no rotary, sigmoid output gate; up to select.dense_len positions dense (the flash kernels), beyond that over the blocks InfLLM-v2 selection picks (parallel/sparse_attention.py) | n_heads, n_kv_heads, select, flash_attention |
     | "lightning" | decayed linear attention in chunks (parallel/linear_attention.py): n_heads heads of d_model / n_heads, RMSNorm on each head of q and k, rotary over the whole head, decay exp(-2^(-8 (h + 1) / n_heads)), RMSNorm over all heads of the result, sigmoid output gate | n_heads, rope_theta |
-    | "gqa" | grouped-query causal softmax attention, the layer's own count of query heads over n_kv_heads K/V heads of head_dim, rotary over a share of the head (YaRN's frequencies where given), full or windowed (the flash kernels; the windowed ones run the band alone), a sigmoid output gate a head | gqa[layer] (GQA), n_kv_heads, head_dim, flash_attention |
+    | "gqa" | grouped-query causal softmax attention, the layer's own count of query heads over n_kv_heads K/V heads of head_dim, QK-norm where the layer asks, rotary over a share of the head (YaRN's frequencies where given), full or windowed (the flash kernels; the windowed ones run the band alone), a sigmoid output gate a head unless the layer drops it | gqa[layer] (GQA), n_kv_heads, head_dim, flash_attention |
+    | "conv" | LFM2's gated short convolution: B, C, x~ = split(h W_bcx), y = (C * causalconv(B * x~)) W_o, the convolution depthwise over the 3 taps of `conv_w` along T (short_conv), no bias | d_model |
 
     `mlps` names each layer's MLP, one word a layer (empty: "dense" in
     every layer): "dense" is `mlp` at d_ff, "experts" the routed and shared
@@ -159,7 +169,7 @@ class TransformerConfig:
     experts: Experts | None = None  # what an "experts" layer holds
 
 
-MIXERS = ("mha", "sparse", "lightning", "gqa")
+MIXERS = ("mha", "sparse", "lightning", "gqa", "conv")
 MLPS = ("dense", "experts")
 
 
@@ -181,6 +191,26 @@ def _scaled(x, scale):
     if scale == 1.0:
         return x
     return (x.astype(jnp.float32) * scale).astype(x.dtype)
+
+
+def _is_state(name):
+    """Model state that a step moves by a rule of its own, not by its
+    gradient (an expert layer's selection bias): float32, no gradient, no
+    optimizer state."""
+    return name.endswith("_e_bias")
+
+
+def short_conv(u, w):
+    """LFM2's causal depthwise convolution along T: v_t = sum over j of
+    w_j * u_(t - L + 1 + j), zeros before t = 0. u (B, T, C), w (L, C):
+    the last tap reads position t itself. L shifted multiply-adds,
+    accumulated in float32 and rounded to u's dtype once (bfloat16 sums
+    would round at each tap); elementwise work that XLA fuses with the
+    gating products around it."""
+    L, T = w.shape[0], u.shape[1]
+    padded = jnp.pad(u.astype(jnp.float32), ((0, 0), (L - 1, 0), (0, 0)))
+    w = w.astype(jnp.float32)
+    return sum(padded[:, j:j + T] * w[j] for j in range(L)).astype(u.dtype)
 
 
 def _rope(x, positions, theta):
@@ -268,7 +298,8 @@ class TransformerLM:
     def _shapes(self):
         """[(name, shape, fan_in)] in the order the seed is spent: a matrix
         is drawn normal / sqrt(fan_in); fan_in None is a norm's weight
-        (ones), 0 a bias (zeros). Matrices are stored (in, out)."""
+        (ones), 0 a bias (zeros). Matrices are stored (in, out); a "conv"
+        layer's taps (3, d) have a fan-in of 3."""
         cfg = self.cfg
         d, f, hd = cfg.d_model, cfg.d_ff, self.head_dim
 
@@ -284,12 +315,20 @@ class TransformerLM:
             if kind == "mha":
                 out += [(p + w, (d, d), d) for w in ("wq", "wk", "wv", "wo")]
             elif kind == "gqa":
-                wide = cfg.gqa[i].heads * hd
+                layer = cfg.gqa[i]
+                wide = layer.heads * hd
                 kv = (cfg.n_kv_heads or cfg.n_heads) * hd
                 out += [(p + "wq", (d, wide), d), (p + "wk", (d, kv), d),
-                        (p + "wv", (d, kv), d),
-                        (p + "wg", (d, cfg.gqa[i].heads), d),
-                        (p + "wo", (wide, d), wide)]
+                        (p + "wv", (d, kv), d)]
+                if layer.gate:
+                    out.append((p + "wg", (d, layer.heads), d))
+                out.append((p + "wo", (wide, d), wide))
+                if layer.qk_norm:
+                    out += [(p + "q_norm_g", (hd,), None),
+                            (p + "k_norm_g", (hd,), None)]
+            elif kind == "conv":
+                out += [(p + "w_bcx", (d, 3 * d), d),
+                        (p + "conv_w", (3, d), 3), (p + "wo", (d, d), d)]
             else:
                 kv = d if kind == "lightning" else \
                     (cfg.n_kv_heads or cfg.n_heads) * hd
@@ -309,6 +348,8 @@ class TransformerLM:
                 out += [(p + "router", (d, ex.count), d),
                         (p + "e_gate_in", (n, d, 2 * fe), d),
                         (p + "e_out", (n, fe, d), fe)]
+                if ex.bias_rate is not None:
+                    out.append((p + "e_bias", (ex.count,), 0))
                 if fs:
                     out += [(p + "s_gate", (d, fs), d),
                             (p + "s_in", (d, fs), d),
@@ -336,8 +377,8 @@ class TransformerLM:
                 params[name] = (jax.random.normal(next(k), shape, jnp.float32)
                                 / math.sqrt(fan_in)).astype(dt)
             else:
-                params[name] = (jnp.ones if fan_in is None
-                                else jnp.zeros)(shape, dt)
+                params[name] = (jnp.ones if fan_in is None else jnp.zeros)(
+                    shape, jnp.float32 if _is_state(name) else dt)
         return params
 
     # -- forward ------------------------------------------------------------
@@ -404,7 +445,8 @@ class TransformerLM:
                 params[prefix + "e_gate_in"], params[prefix + "e_out"],
                 held=tuple(ex.held), k=ex.per_token,
                 rows=ex.rows or B * T * ex.per_token, score=ex.score,
-                scaling=ex.scaling, norm_topk=ex.norm_topk)
+                scaling=ex.scaling, norm_topk=ex.norm_topk,
+                bias=params.get(prefix + "e_bias"))
             y = y.reshape(B, T, d)
             if ex.shared_width:
                 with jax.named_scope("moe_shared"):
@@ -425,15 +467,18 @@ class TransformerLM:
             raise NotImplementedError(
                 f"a {kind!r} layer inside shard_map over sp / tp")
         h = self._norm(x, params, prefix + "ln1")
+        if kind == "conv":
+            return checkpoint_name(self._conv(params, prefix, h), "attn_out")
         # head counts are read off the LOCAL weight shapes (D/tp columns
         # inside shard_map with TP)
         q, kk, v = ((h @ params[prefix + w]).reshape(B, T, -1, hd)
                     for w in ("wq", "wk", "wv"))
+        layer = self.cfg.gqa[_layer_of(prefix)] if kind == "gqa" else None
         if kind == "lightning":
             attn = self._lightning(params, prefix, q, kk, v, positions)
         elif kind == "gqa":
-            attn = self._gqa(self.cfg.gqa[_layer_of(prefix)],
-                             q, kk, v, mesh, positions)
+            attn = self._gqa(params, prefix, layer, q, kk, v, mesh,
+                             positions)
         elif kind == "sparse":
             with jax.named_scope("sparse_attn"):
                 if T > self.cfg.select.dense_len:
@@ -442,7 +487,7 @@ class TransformerLM:
                     attn = self._softmax_attention(q, kk, v, sp_axis, mesh)
         else:
             attn = self._softmax_attention(q, kk, v, sp_axis, mesh)
-        if kind == "gqa":       # a gate a head
+        if kind == "gqa" and layer.gate:       # a gate a head
             gate = h @ params[prefix + "wg"]
             with jax.named_scope("gate"):
                 attn = attn * jax.nn.sigmoid(gate)[..., None]
@@ -477,10 +522,23 @@ class TransformerLM:
             return _rms(out.reshape(B, T, H * hd),
                         params[prefix + "o_norm_g"], cfg.norm_eps)
 
-    def _gqa(self, layer, q, k, v, mesh, positions):
-        """Rotary as the layer's Rotary says, then causal softmax attention
-        at 1 / sqrt(head_dim), inside the layer's window where it has
-        one."""
+    def _conv(self, params, prefix, h):
+        """LFM2's gated short convolution on h (B, T, d): B, C, x~ =
+        split(h W_bcx), y = (C * short_conv(B * x~)) W_o."""
+        with jax.named_scope("short_conv"):
+            b, c, xt = jnp.split(h @ params[prefix + "w_bcx"], 3, axis=-1)
+            with jax.named_scope("short_conv_taps"):
+                y = c * short_conv(b * xt, params[prefix + "conv_w"])
+            return y @ params[prefix + "wo"]
+
+    def _gqa(self, params, prefix, layer, q, k, v, mesh, positions):
+        """QK-norm where the layer asks for it, rotary as its Rotary says,
+        then causal softmax attention at 1 / sqrt(head_dim), inside the
+        layer's window where it has one."""
+        if layer.qk_norm:
+            with jax.named_scope("norm"):
+                q = _rms(q, params[prefix + "q_norm_g"], self.cfg.norm_eps)
+                k = _rms(k, params[prefix + "k_norm_g"], self.cfg.norm_eps)
         with jax.named_scope("rope"):
             if positions is None:
                 positions = jnp.arange(q.shape[1])
@@ -536,7 +594,8 @@ class TransformerLM:
         the mesh when tracing a pure-jit program over several devices. With
         `counts`, (logits, the expert layers' routing: {"held_slots",
         "slots_over"}, a number an expert layer, and "experts" (layers, B *
-        T, per_token), what each token chose)."""
+        T, per_token), what each token chose; with an expert bias "load"
+        (layers, count), the slots each expert drew)."""
         logits, routed = self._head(params, tokens, sp_axis, positions,
                                     tp_axis, mesh, counts)
         with jax.named_scope("logits"):
@@ -577,20 +636,19 @@ class TransformerLM:
         if not counts:
             return logits, None
         return logits, {k: jnp.stack([seen[k] for seen in routed])
-                        for k in ("held_slots", "slots_over", "experts")}
+                        for k in routed[0]}
 
     def loss(self, params, tokens, targets, sp_axis=None, positions=None,
              tp_axis=None, mesh=None, counts=False):
         """Mean next-token negative log-likelihood; with `counts` (a model
-        with an expert layer), (loss, apply's "held_slots" and
-        "slots_over")."""
+        with an expert layer), (loss, apply's counts but "experts")."""
         # `forward`, `loss` and (in the train step) `optimizer` are the top
         # words a device trace is read by (PERF.md section 3)
         with jax.named_scope("forward"):
             logits, routed = self._head(params, tokens, sp_axis, positions,
                                         tp_axis, mesh, counts)
-            if counts:      # the step's counters: two numbers a layer
-                routed = {k: routed[k] for k in ("held_slots", "slots_over")}
+            if counts:      # the step's counters: a few numbers a layer
+                routed = {k: v for k, v in routed.items() if k != "experts"}
         with jax.named_scope("loss"):
             # logsumexp less the target's logit, read off the head's own
             # logits: no log-softmax over the vocabulary, which XLA writes
@@ -635,7 +693,10 @@ class TransformerLM:
         A model with an expert layer: step_fn returns (params, opt_state,
         loss, counts), counts the step's routing counts a layer
         ({"held_slots", "slots_over"}: TransformerLM.apply), which cost the
-        step nothing it would not compute anyway."""
+        step nothing it would not compute anyway. An expert layer with a
+        bias (Experts.bias_rate) adds its "load": the step moves the bias
+        by moe_balance from it, under `moe_balance`; the bias takes no
+        gradient and has no optimizer state."""
         from .. import profiler as _prof
         from ..parallel._compat import shard_map
         from ..parallel.tensor_parallel import transformer_param_specs
@@ -668,6 +729,7 @@ class TransformerLM:
 
         model = self
         routed = bool(self.expert_layers)
+        state = [n for n in pspec if _is_state(n)]
         if routed and (n_steps or sp_axis is not None):
             raise NotImplementedError("an expert layer's counts through a "
                                       "scan of steps or a shard_map over sp")
@@ -700,8 +762,11 @@ class TransformerLM:
         _, adam_rule = _make_update_rule("adam", lr, 0.0, 0.0, {})
 
         def step(params, opt_state, tokens, targets, step_i):
-            loss, grads = jax.value_and_grad(loss_fn, has_aux=routed)(
-                params, tokens, targets)
+            fixed = {k: params[k] for k in state}
+            loss, grads = jax.value_and_grad(
+                lambda p, *xy: loss_fn({**p, **fixed}, *xy), has_aux=routed)(
+                {k: v for k, v in params.items() if k not in fixed},
+                tokens, targets)
             new_params, new_opt = {}, {}
             with jax.named_scope("optimizer"):
                 t = step_i + 1
@@ -713,6 +778,15 @@ class TransformerLM:
                     new_params[k] = w32.astype(params[k].dtype)
             if routed:
                 loss, counts = loss
+                if state:
+                    from ..parallel.moe import moe_balance
+                    with jax.named_scope("optimizer"), \
+                            jax.named_scope("moe_balance"):
+                        for j, layer in enumerate(self.expert_layers):
+                            k = f"layer{layer}_e_bias"
+                            new_params[k] = moe_balance(
+                                fixed[k], counts["load"][j],
+                                self.cfg.experts.bias_rate)
                 return new_params, new_opt, loss, counts
             return new_params, new_opt, loss
 
@@ -732,7 +806,8 @@ class TransformerLM:
             step = multi
 
         param_sh = {n: NamedSharding(mesh, s) for n, s in pspec.items()}
-        opt_sh = {n: (param_sh[n], param_sh[n]) for n in pspec}
+        opt_sh = {n: (param_sh[n], param_sh[n]) for n in pspec
+                  if n not in state}
         data_sh = NamedSharding(mesh, data_spec)
         # outputs pinned to the input layout: left to the compiler, a
         # replicated leaf can come back sharded and the next (donating)
@@ -763,7 +838,7 @@ class TransformerLM:
                                       device=param_sh[k]),
                             jnp.zeros(v.shape, jnp.float32,
                                       device=param_sh[k]))
-                        for k, v in params.items()}
+                        for k, v in params.items() if k not in state}
 
         _prof.setup_row("make_train_step", "TransformerLM", began,
                         time.time())

@@ -27,7 +27,7 @@ from .flash_attention import _interpret
 
 __all__ = ["moe_gate", "moe_apply", "moe_apply_a2a", "moe_sharded",
            "init_moe_params", "moe_route", "moe_dispatch", "moe_routed",
-           "grouped_matmul"]
+           "moe_balance", "grouped_matmul"]
 
 
 def moe_gate(x, wg, k=1, capacity_factor=1.25):
@@ -282,11 +282,14 @@ def grouped_matmul(lhs, rhs, group_sizes):
                      _gmm_tiling(lhs.shape[0], *rhs.shape[1:]))
 
 
-def moe_route(x, router, k, score="sigmoid", scaling=1.0, norm_topk=True):
+def moe_route(x, router, k, score="sigmoid", scaling=1.0, norm_topk=True,
+              bias=None):
     """Scores over every expert the router knows, in float32; the k largest
     a token. x (N, d), router (d, E). Returns (experts (N, k) int32, weights
     (N, k) float32): weights = scaling * s_sel / sum(s_sel) with norm_topk,
-    else scaling * s_sel."""
+    else scaling * s_sel. `bias` (E,): the k largest of s + bias are
+    chosen, and weighted by s alone (an expert bias balanced without a
+    loss: `moe_balance`)."""
     if score not in SCORES:
         raise ValueError(f"router score {score!r}: one of {SCORES}")
     with jax.named_scope("moe_route"):
@@ -294,20 +297,26 @@ def moe_route(x, router, k, score="sigmoid", scaling=1.0, norm_topk=True):
                             precision=lax.Precision.HIGHEST)
         s = jax.nn.sigmoid(logits) if score == "sigmoid" \
             else jax.nn.softmax(logits, axis=-1)
-        top, experts = lax.top_k(s, k)
+        if bias is None:
+            top, experts = lax.top_k(s, k)
+        else:
+            _, experts = lax.top_k(s + bias.astype(jnp.float32), k)
+            top = jnp.take_along_axis(s, experts, axis=-1)
         if norm_topk:
             top = top / jnp.sum(top, axis=-1, keepdims=True)
         return experts.astype(jnp.int32), top * scaling
 
 
-def moe_dispatch(experts, held, rows):
+def moe_dispatch(experts, held, rows, total=None):
     """Which token slots this chip computes, in the order its experts take
     them. experts (N, k) int32; held = (first, count): the experts
     [first, first + count) are here. Returns (slot (rows,) int32: indices
     into the N * k slots, sorted by local expert, spare rows last; group
     (count,) int32: rows a held expert takes, the spare ones with the last,
     summing to `rows`; live (rows,) bool: rows that are a held slot;
-    counts {"held_slots", "slots_over"})."""
+    counts {"held_slots", "slots_over"}). With `total`, the router's count
+    of experts, the slots are counted for all of them and counts also holds
+    that "load" (total,) int32; the held experts' sizes are its slice."""
     first, count = held
     with jax.named_scope("moe_dispatch"):
         flat = experts.reshape(-1)
@@ -321,33 +330,46 @@ def moe_dispatch(experts, held, rows):
         # one sort of one array: the key in the high part, the slot in the low
         order = jnp.sort(local * n + jnp.arange(n, dtype=jnp.int32))[:rows]
         slot, key = order % n, order // n
-        sizes = jnp.sum(local[:, None] == jnp.arange(count)[None, :], axis=0,
-                        dtype=jnp.int32)
+        if total is None:
+            sizes = jnp.sum(local[:, None] == jnp.arange(count)[None, :],
+                            axis=0, dtype=jnp.int32)
+        else:
+            load = jnp.sum(flat[:, None] == jnp.arange(total)[None, :],
+                           axis=0, dtype=jnp.int32)
+            sizes = load[first:first + count]
         ends = jnp.minimum(jnp.cumsum(sizes), rows)
         group = jnp.diff(ends, prepend=0)
         group = group.at[count - 1].add(rows - ends[-1])
         held_slots = jnp.sum(sizes)
-        return slot, group, key < count, {
-            "held_slots": held_slots,
-            "slots_over": jnp.maximum(held_slots - rows, 0)}
+        live = key < count
+        counts = {"held_slots": held_slots,
+                  "slots_over": jnp.maximum(held_slots - rows, 0)}
+        if total is not None:
+            counts["load"] = load
+        return slot, group, live, counts
 
 
 def moe_routed(x, router, w_gate_in, w_out, *, held, k, rows,
-               score="sigmoid", scaling=1.0, norm_topk=True):
+               score="sigmoid", scaling=1.0, norm_topk=True, bias=None):
     """The routed part of one expert layer as this chip computes it:
     sum over a token's chosen AND held experts e of w_e SwiGLU_e(x).
 
     x (N, d); router (d, E) over ALL experts; w_gate_in (count, d, 2 f): a
-    held expert's gate and up projections side by side; w_out (count, f, d).
+    held expert's gate and up projections side by side; w_out (count, f, d);
+    bias (E,) or None: moe_route's selection bias.
     Returns (y (N, d) in x's dtype, counts: moe_dispatch's, and "experts"
-    (N, k), what each token chose, for whoever compares routings)."""
+    (N, k), what each token chose, for whoever compares routings; with a
+    bias also "load" (E,) int32, the token slots each of the E experts
+    drew, which `moe_balance` reads)."""
     N, d = x.shape
     f = w_out.shape[1]
     if w_gate_in.shape != (held[1], d, 2 * f):
         raise ValueError(f"held {held}: gate and up projections "
                          f"{w_gate_in.shape}, down {w_out.shape}")
-    experts, weights = moe_route(x, router, k, score, scaling, norm_topk)
-    slot, group, live, counts = moe_dispatch(experts, held, rows)
+    experts, weights = moe_route(x, router, k, score, scaling, norm_topk,
+                                 bias)
+    slot, group, live, counts = moe_dispatch(
+        experts, held, rows, None if bias is None else bias.shape[0])
     with jax.named_scope("moe_dispatch"):
         token = slot // k
         taken = x[token]                                    # (rows, d)
@@ -360,3 +382,14 @@ def moe_routed(x, router, w_gate_in, w_out, *, held, k, rows,
         y = jnp.zeros((N, d), jnp.float32).at[token].add(
             out.astype(jnp.float32) * w[:, None])
         return y.astype(x.dtype), dict(counts, experts=experts)
+
+
+def moe_balance(bias, load, rate):
+    """The expert bias after one step of balancing without an auxiliary
+    loss (DeepSeek-V3, arXiv:2412.19437 section 2.1.2): b + rate *
+    sign(mean(c) - c), c (E,) the token slots each expert drew this step
+    (moe_routed's "load"; their mean is N k / E). An expert drawn more than
+    the mean is chosen less next step, one drawn less is chosen more; no
+    gradient, no optimizer state."""
+    load = load.astype(jnp.float32)
+    return bias + rate * jnp.sign(jnp.mean(load) - load)

@@ -15,6 +15,7 @@ Acceptance criteria from the cold-start milestone:
   * exec_cache_* telemetry surfaces in dumps() and render_prometheus().
 """
 import hashlib
+import json
 import os
 import pickle
 import subprocess
@@ -266,6 +267,56 @@ def test_concurrent_two_process_write_last_writer_wins(cache_dir):
          "\nassert cc.stats()['misses'] == 0"],
         env=env, capture_output=True, text=True, timeout=300)
     assert third.returncode == 0, third.stderr
+
+
+_JAX_CACHED_SCRIPT = """
+import json, sys
+sys.path.insert(0, {repo!r})
+import jax
+from incubator_mxnet_tpu import compile_cache as cc
+from incubator_mxnet_tpu.serve import DecodePredictor, DecodeScheduler
+jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
+pred = DecodePredictor.toy(slots=2, page_size=4, num_pages=16,
+                           max_pages_per_seq=4, prompt_buckets=(4,))
+warm = pred.warmup()
+sched = DecodeScheduler(pred, max_queue=4, name="jaxcached")
+sched.start()
+try:
+    toks = sched.submit([1, 2, 3], max_new_tokens=3).result(timeout=60)
+finally:
+    sched.stop()
+print("SAID " + json.dumps([warm, toks]))
+"""
+
+
+@pytest.mark.timeout(420)
+def test_the_tier_writes_no_executable_of_a_program_jax_s_cache_loaded(
+        tmp_path):
+    """A first process fills jax's own persistent cache alone; a second,
+    with the disk tier on, finds the same programs there and writes the
+    tier's entries; a third loads those and serves a stream. On XLA:CPU a
+    process that loaded a program from jax's cache serializes it without
+    its kernels, so the tier compiles what it writes with jax's cache left
+    out, and the third process computes what the first did."""
+    jax_dir, tier = str(tmp_path / "jax"), str(tmp_path / "tier")
+    env = {k: v for k, v in os.environ.items()
+           if k not in ("XLA_FLAGS", "MXNET_EXEC_CACHE_DIR")}
+    # the legacy runtime, as the warm-boot tests of tests/test_decode.py
+    env.update(JAX_PLATFORMS="cpu", JAX_COMPILATION_CACHE_DIR=jax_dir,
+               XLA_FLAGS="--xla_cpu_use_thunk_runtime=false")
+    script = _JAX_CACHED_SCRIPT.format(repo=REPO)
+    said = []
+    for extra in ({}, {"MXNET_EXEC_CACHE_DIR": tier},
+                  {"MXNET_EXEC_CACHE_DIR": tier}):
+        r = subprocess.run([sys.executable, "-c", script],
+                           env=dict(env, **extra), capture_output=True,
+                           text=True, timeout=300)
+        assert r.returncode == 0, r.stderr[-2000:]
+        said.append(json.loads(r.stdout.split("SAID ")[-1]))
+    assert os.listdir(jax_dir)
+    assert [w for w, _ in said] == [{"prefill:4": "miss", "decode": "miss"}] \
+        * 2 + [{"prefill:4": "disk", "decode": "disk"}]
+    assert said[2][1] == said[0][1]
 
 
 # ---------------------------------------------------------------------------

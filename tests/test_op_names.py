@@ -785,6 +785,78 @@ def test_a_windowed_expert_layer_keeps_its_names_in_a_tpu_program(
         assert 1.20 < least / (flops / 197e12) < 1.23
 
 
+@pytest.mark.parametrize("layer", [0, 2], ids=["conv", "attention"])
+def test_an_lfm2_block_keeps_its_names_in_a_tpu_program(one_chip,
+                                                       monkeypatch, layer):
+    """Value and gradient of one remat block of `lfm2-8b-a1b.train-4x8k-moe`
+    at its published widths and 4 x 8,192 tokens, compiled for the chip.
+    Layer 0 is a gated short convolution (and the dense SwiGLU): plain XLA
+    under `short_conv` and `short_conv_taps`, recomputed by the remat, no
+    kernel. Layer 2 is QK-normed, ungated GQA over experts: `flash_fwd` and
+    the one backward call over K/V repeated to the 32 query heads, so that
+    the benchmark's count reads (4, 8192, 32 * 64) off every operand, every
+    other kernel a grouped product of the experts. Neither holds a (T, T)
+    array."""
+    from perfbench import cells, op_scopes
+    from perfbench.families import lfm2_moe
+    from incubator_mxnet_tpu.models.transformer import (TransformerLM,
+                                                        _remat_policy)
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    cell = cells.resolve("lfm2-8b-a1b.train-4x8k-moe")
+    model = TransformerLM(lfm2_moe.model_config(cell.config, cell.traffic))
+    shapes = jax.eval_shape(model.init_params, jax.random.PRNGKey(0))
+    prefix = f"layer{layer}_"
+    params = {k: jax.ShapeDtypeStruct(v.shape, v.dtype, sharding=one_chip)
+              for k, v in shapes.items() if k.startswith(prefix)}
+    x = jax.ShapeDtypeStruct((4, 8192, 2048), jnp.bfloat16,
+                             sharding=one_chip)
+    block = jax.checkpoint(lambda p, y: model._block(p, prefix, y, None),
+                           policy=_remat_policy(None))
+
+    def loss(p, y):
+        with jax.named_scope("forward"):
+            out = block(p, y)
+            out = out[0] if layer else out
+            return out.astype(jnp.float32).sum()
+    text = jax.jit(jax.value_and_grad(loss, argnums=(0, 1))).lower(
+        params, x).compile().as_text()
+    calls = [ln for ln in text.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in ln]
+    names = set(re.findall(r'op_name="([^"]*)"', text))
+    under = lambda *words: any(all(f"/{w}/" in n for w in words)
+                               for n in names)
+    assert not re.search(r"\[(\d+,)*8192,8192\]", text)
+    assert under("attn", "norm") and under("mlp", "norm")
+    if layer == 0:
+        assert not calls
+        assert under("attn", "short_conv", "short_conv_taps")
+        assert under("rematted_computation", "short_conv")
+        assert not under("attn", "rope") and not under("moe")
+        return
+    assert not under("short_conv") and not under("attn", "gate")
+    assert under("attn", "rope")
+    for scope in ("moe_route", "moe_dispatch", "moe_experts", "moe_combine"):
+        assert under("mlp", "moe", scope), scope
+    kernel = lambda ln: re.match(r"\s*(?:ROOT )?%(\w+?)(\.\d+)? = ",
+                                 ln).group(1)
+    flash = [ln for ln in calls if kernel(ln).startswith("flash")]
+    assert sorted(map(kernel, flash)) == ["flash_bwd_dq", "flash_fwd"]
+    rest = [ln for ln in calls if ln not in flash]
+    assert rest and all("/moe_experts/" in re.search(
+        r'op_name="([^"]*)"', ln).group(1) for ln in rest)
+    shaped = lambda text: [f"{m.group(1)}[{m.group(2)}]"
+                           for m in op_scopes.SHAPE.finditer(text)]
+    for ln in flash:
+        row = {"results": shaped(ln.split(" custom-call(")[0]),
+               "operands": shaped(ln.split(
+                   "operand_layout_constraints={")[1].split("}}")[0])}
+        assert row["operands"][:3] == ["bf16[4,8192,2048]"] * 3
+        assert op_scopes.flash_dims(row) == (4, 8192, 2048, 2048)
+    # 32 heads x 4 x 8192^2 x (64 + 64) under the mask
+    assert op_scopes.flash_flops(4, 8192, 2048) == \
+        op_scopes.flash_flops(128, 8192, 64) == 1_099_511_627_776
+
+
 # -- BatchNorm's all-reduces on a dp mesh -------------------------------------
 # Under GSPMD a BatchNorm over a batch sharded on `dp` takes the statistics of
 # the global batch, and every reduction over the batch becomes an all-reduce.
