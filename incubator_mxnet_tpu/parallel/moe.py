@@ -16,7 +16,8 @@ is 0), matching Switch-Transformer semantics.
 """
 from __future__ import annotations
 
-import functools
+import collections
+import threading
 
 import jax
 import jax.numpy as jnp
@@ -27,7 +28,7 @@ from .flash_attention import _interpret
 
 __all__ = ["moe_gate", "moe_apply", "moe_apply_a2a", "moe_sharded",
            "init_moe_params", "moe_route", "moe_dispatch", "moe_routed",
-           "moe_balance", "grouped_matmul"]
+           "moe_balance", "grouped_matmul", "grouped_stats"]
 
 
 def moe_gate(x, wg, k=1, capacity_factor=1.25):
@@ -213,15 +214,58 @@ def moe_sharded(x, params, mesh, axis="ep", k=1, capacity_factor=1.25):
 SCORES = ("sigmoid", "softmax")
 
 
-def _gmm_tiling(m, k, n):
-    """megablox tiles (rows, contraction, columns). 256 rows: a held
-    expert's few hundred rows straddle fewer tiles than at 512; 1,024 deep
-    and wide is the most the chip's scoped memory took (PERF.md section 6,
-    PR 36, has the race). A size that no such tile divides is one tile."""
-    def fit(size, most):
-        return next((t for t in (most, 512, 256, 128)
-                     if t <= most and size % t == 0), size)
-    return fit(m, 256), fit(k, 1024), fit(n, 1024)
+# The rows a group averages in the buffer from which 512-row tiles beat 256:
+# they fetch each matrix tile half as often, and cost at most one partly
+# masked tile more a group. The race on the chip (benchmark/moe_race.py;
+# PERF.md section 6) had 256 ahead by 4% at 512 rows a group, 512
+# ahead by 6% at 1,536 and by 21% at 9,216.
+_ROWS_512 = 1024
+
+
+def _gmm_tiling(m, k, n, groups):
+    """megablox tiles (rows, contraction, columns) for ONE call of m rows, a
+    k-deep contraction and n columns over `groups` matrices, from that
+    call's own shape. A width takes the largest multiple of 128, at most
+    1,024, that divides it (1,792 and 3,584: 896), so no tile is masked; a
+    width no such tile divides is one tile. Rows: 512 where a group
+    averages `_ROWS_512` rows or more, else 256. The chip's scoped memory
+    refuses matrix tiles beyond 1,024 x 1,024, and 1,024-row tiles wherever
+    the contraction takes more than one step (benchmark/moe_race.py)."""
+    most = 512 if m >= _ROWS_512 * groups else 256
+    rows = next((t for t in (most, 256, 128) if m % t == 0), m)
+    return rows, _width(k), _width(n)
+
+
+def _width(size):
+    """The largest multiple of 128, at most 1,024, that divides `size`;
+    `size` itself where none does."""
+    return next((t for t in range(1024, 0, -128) if size % t == 0), size)
+
+
+# Grouped-product calls traced, by tiling, and the grid steps each takes a
+# row tile of its buffer (contraction tiles x column tiles): the grid runs
+# them for every row tile a group touches. Counted at trace time.
+_grouped = {"row_tile_steps": 0, "tiles": collections.Counter()}
+_grouped_lock = threading.Lock()
+
+
+def grouped_stats():
+    """{"calls": n, "row_tile_steps": n, "tiles": {(rows, k, n) tiles:
+    calls}}: the megablox `gmm` and `tgmm` calls traced (forward, the
+    matrices' transposes for the rows' gradient, and `tgmm` for the
+    matrices'), and the sum of their grid steps a row tile."""
+    with _grouped_lock:
+        return {"calls": sum(_grouped["tiles"].values()),
+                "row_tile_steps": _grouped["row_tile_steps"],
+                "tiles": dict(_grouped["tiles"])}
+
+
+def _tiled(m, k, n, groups):
+    tiling = _gmm_tiling(m, k, n, groups)
+    with _grouped_lock:
+        _grouped["tiles"][tiling] += 1
+        _grouped["row_tile_steps"] += -(-k // tiling[1]) * -(-n // tiling[2])
+    return tiling
 
 
 def _low(dtype):
@@ -242,30 +286,39 @@ def _megablox_backend():
         "jax.experimental.pallas.ops.tpu.megablox.gmm")
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
-def _megablox(lhs, rhs, group_sizes, tiling):
-    backend = _megablox_backend()
+def _gmm(lhs, rhs, group_sizes, transpose_rhs=False):
+    """lhs (m, k) times the matrix of each row's group, rhs (G, k, n), or
+    (G, n, k) transposed: (m, n) in lhs's dtype, tiled from this shape."""
+    (m, k), (groups, *kn) = lhs.shape, rhs.shape
+    n = kn[0] if transpose_rhs else kn[1]
     with _low(lhs.dtype):
-        return backend.gmm(lhs, rhs, group_sizes, lhs.dtype, tiling,
-                           interpret=_interpret())
+        return _megablox_backend().gmm(
+            lhs, rhs, group_sizes, lhs.dtype, _tiled(m, k, n, groups),
+            transpose_rhs=transpose_rhs, interpret=_interpret())
 
 
-def _megablox_fwd(lhs, rhs, group_sizes, tiling):
-    return _megablox(lhs, rhs, group_sizes, tiling), (lhs, rhs, group_sizes)
+@jax.custom_vjp
+def _megablox(lhs, rhs, group_sizes):
+    return _gmm(lhs, rhs, group_sizes)
 
 
-def _megablox_bwd(tiling, res, grad):
-    """jax's own rule (megablox/ops.py), traced under _low: d lhs is the
-    grouped product with each group's matrix transposed, d rhs the
-    grouped product of lhs^T and the cotangent, a matrix a group."""
-    backend = _megablox_backend()
+def _megablox_fwd(lhs, rhs, group_sizes):
+    return _megablox(lhs, rhs, group_sizes), (lhs, rhs, group_sizes)
+
+
+def _megablox_bwd(res, grad):
+    """jax's own rule (megablox/ops.py), traced under _low, each call
+    tiled from its own shape: d lhs is the grouped product with each
+    group's matrix transposed (it contracts the forward's columns and
+    gives its contraction's), d rhs the grouped product of lhs^T and the
+    cotangent, a (k, n) matrix a group, tiled as the forward."""
     lhs, rhs, group_sizes = res
-    interpret = _interpret()
+    (m, k), (groups, _, n) = lhs.shape, rhs.shape
+    d_lhs = _gmm(grad, rhs, group_sizes, transpose_rhs=True)
     with _low(lhs.dtype):
-        d_lhs = backend.gmm(grad, rhs, group_sizes, lhs.dtype, tiling,
-                            transpose_rhs=True, interpret=interpret)
-        d_rhs = backend.tgmm(lhs.swapaxes(0, 1), grad, group_sizes,
-                             rhs.dtype, tiling, interpret=interpret)
+        d_rhs = _megablox_backend().tgmm(
+            lhs.swapaxes(0, 1), grad, group_sizes, rhs.dtype,
+            _tiled(m, k, n, groups), interpret=_interpret())
     return d_lhs, d_rhs, None
 
 
@@ -277,9 +330,9 @@ def grouped_matmul(lhs, rhs, group_sizes):
     matrix of its group: (R, n) in lhs's dtype, float32 accumulation.
     group_sizes (G,) int32 sum to R. jax's `megablox` Pallas kernels (`gmm`,
     and `tgmm` for the matrices' gradient), which won the race on the chip
-    (benchmark/moe_race.py; PERF.md section 6, PR 36)."""
-    return _megablox(lhs, rhs, group_sizes,
-                     _gmm_tiling(lhs.shape[0], *rhs.shape[1:]))
+    (benchmark/moe_race.py; PERF.md section 6), each call tiled from
+    its own shape (`_gmm_tiling`)."""
+    return _megablox(lhs, rhs, group_sizes)
 
 
 def moe_route(x, router, k, score="sigmoid", scaling=1.0, norm_topk=True,

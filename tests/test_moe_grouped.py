@@ -169,3 +169,88 @@ def test_a_score_the_router_does_not_know_is_refused():
     x, router, gate_in, out = _weights()
     with pytest.raises(ValueError, match="router score"):
         _routed(x, router, gate_in, out, "tanh", 1.0, True)
+
+
+# -- the megablox tiles: each call from its own shape ------------------------
+
+@pytest.mark.parametrize("size,tile", [
+    (1792, 896), (3584, 896), (2048, 1024), (3072, 1024), (1024, 1024),
+    (1280, 640), (640, 640), (96, 96), (200, 200)])
+def test_a_width_takes_the_largest_multiple_of_128_that_divides_it(size,
+                                                                   tile):
+    """1,792 and 3,584 in 896-wide tiles, not 256 and 512; a width that no
+    multiple of 128 divides is one tile."""
+    assert moe._gmm_tiling(73728, size, 2048, 8)[1] == tile
+    assert moe._gmm_tiling(73728, 2048, size, 8)[2] == tile
+
+
+@pytest.mark.parametrize("m,groups,rows", [
+    (73728, 8, 512), (12288, 8, 512), (8192, 8, 512), (8192 - 512, 8, 256),
+    (4096, 8, 256), (4096, 1, 512), (73728 + 256, 8, 256), (96, 4, 96)])
+def test_the_row_tile_follows_the_rows_a_group_averages(m, groups, rows):
+    assert moe._gmm_tiling(m, 2048, 1792, groups)[0] == rows
+
+
+def _two_products(f):
+    """The routed part's grouped products: gate and up as one, then down."""
+    def experts(x, w1, w2, group):
+        h = moe.grouped_matmul(x, w1, group)
+        h = (jax.nn.silu(h[:, :f]) * h[:, f:]).astype(x.dtype)
+        return moe.grouped_matmul(h, w2, group)
+    return experts
+
+
+@pytest.mark.parametrize("rows,d,f,tiles,steps", [
+    (73728, 2048, 1792, {(512, 1024, 896): 4, (512, 896, 1024): 4}, 48),
+    (12288, 3072, 1024, {(512, 1024, 1024): 8}, 36)],
+    ids=["lfm2", "laguna"])
+def test_grouped_stats_counts_a_remat_layers_grid_steps(rows, d, f, tiles,
+                                                        steps):
+    """Value and gradient of one expert layer under remat at a cell's
+    shapes, traced and not run: each product's forward twice, the rows'
+    gradient on its own axes (a 3,584-deep contraction in 896-wide steps,
+    1,792 columns in 896-wide tiles) and `tgmm` on the forward's. LFM2's
+    layer takes 48 grid steps a row tile, where 256-row tiles that fell to
+    256 and 512 wide took 116; every call of Laguna's takes 512 rows by
+    1,024 by 1,024."""
+    S = jax.ShapeDtypeStruct
+    G = 8
+    args = (S((rows, d), jnp.bfloat16), S((G, d, 2 * f), jnp.bfloat16),
+            S((G, f, d), jnp.bfloat16), S((G,), jnp.int32))
+    experts = _two_products(f)
+    loss = jax.checkpoint(
+        lambda *a: experts(*a).astype(jnp.float32).sum())
+    before = moe.grouped_stats()
+    jax.make_jaxpr(jax.value_and_grad(loss, argnums=(0, 1, 2)))(*args)
+    after = moe.grouped_stats()
+    got = {t: c - before["tiles"].get(t, 0) for t, c in after["tiles"].items()
+           if c > before["tiles"].get(t, 0)}
+    assert got == tiles
+    assert after["calls"] - before["calls"] == 8
+    assert after["row_tile_steps"] - before["row_tile_steps"] == steps
+
+
+def test_a_width_of_640_tiles_and_differentiates_as_lax_ragged_dot():
+    """Widths whose tile is not a power of two (1,280 and 640 -> 640), the
+    rows' gradient on its own axes: the layer's value and its three
+    gradients against the same layer over `lax.ragged_dot`."""
+    sizes = (100, 0, 156, 256)
+    rows, d, f = sum(sizes), 1280, 640
+    ks = jax.random.split(jax.random.PRNGKey(7), 3)
+    x = jax.random.normal(ks[0], (rows, d))
+    w1 = jax.random.normal(ks[1], (len(sizes), d, 2 * f)) / np.sqrt(d)
+    w2 = jax.random.normal(ks[2], (len(sizes), f, d)) / np.sqrt(f)
+    group = jnp.asarray(sizes, jnp.int32)
+    assert moe._gmm_tiling(rows, d, 2 * f, len(sizes)) == (256, 640, 640)
+
+    def ragged(x, w1, w2, group):
+        h = jax.lax.ragged_dot(x, w1, group)
+        h = jax.nn.silu(h[:, :f]) * h[:, f:]
+        return jax.lax.ragged_dot(h, w2, group)
+    loss = lambda fn: lambda *a: jnp.sum(jnp.sin(fn(*a, group)))
+    got = jax.value_and_grad(loss(_two_products(f)), (0, 1, 2))(x, w1, w2)
+    want = jax.value_and_grad(loss(ragged), (0, 1, 2))(x, w1, w2)
+    for g, w in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+        assert float(jnp.max(jnp.abs(w))) > 0
+        assert float(jnp.max(jnp.abs(g - w))) < 2e-5 * max(
+            1.0, float(jnp.max(jnp.abs(w))))

@@ -209,6 +209,44 @@ def test_a_step_leaves_no_operation_unnamed(which, request):
     assert bare == []
 
 
+@pytest.mark.parametrize("cell,family,layer,tiles", [
+    ("laguna-s-2.1.train-8k", "laguna", 1, {(512, 1024, 1024): 8}),
+    ("lfm2-8b-a1b.train-4x8k-moe", "lfm2_moe", 2,
+     {(512, 1024, 896): 4, (512, 896, 1024): 4})], ids=["laguna", "lfm2"])
+def test_an_expert_layers_grouped_products_take_their_own_tiles(
+        cell, family, layer, tiles):
+    """Value and gradient of one remat block with experts of the cell at its
+    published widths and batch, traced and not run: the eight megablox
+    calls (each product's forward twice, the rows' and the matrices'
+    gradients) and their tiles, by `grouped_stats()`. Laguna's widths are
+    multiples of 1,024, so every call keeps the 1,024 x 1,024 it had; LFM2's
+    1,792 and 3,584 go in 896-wide tiles. Both buffers hold 1,024 rows or
+    more a held expert (1,536 and 9,216), so 512 rows a tile."""
+    import importlib
+    from perfbench import cells
+    from incubator_mxnet_tpu.models.transformer import (TransformerLM,
+                                                        _remat_policy)
+    from incubator_mxnet_tpu.parallel import moe
+    cell = cells.resolve(cell)
+    fam = importlib.import_module(f"perfbench.families.{family}")
+    model = TransformerLM(fam.model_config(cell.config, cell.traffic))
+    prefix = f"layer{layer}_"
+    params = {k: jax.ShapeDtypeStruct(v.shape, v.dtype) for k, v in
+              jax.eval_shape(model.init_params, jax.random.PRNGKey(0)).items()
+              if k.startswith(prefix)}
+    x = jax.ShapeDtypeStruct((cell.traffic["batch_per_chip"],
+                              cell.traffic["seq_len"], model.cfg.d_model),
+                             jnp.bfloat16)
+    block = jax.checkpoint(lambda p, y: model._block(p, prefix, y, None),
+                           policy=_remat_policy(None))
+    loss = lambda p, y: block(p, y)[0].astype(jnp.float32).sum()
+    before = moe.grouped_stats()["tiles"]
+    jax.make_jaxpr(jax.value_and_grad(loss, argnums=(0, 1)))(params, x)
+    after = moe.grouped_stats()["tiles"]
+    assert {t: c - before.get(t, 0) for t, c in after.items()
+            if c > before.get(t, 0)} == tiles
+
+
 @pytest.mark.parametrize("traced", [False, True])
 def test_apply_op_enters_a_scope_only_inside_a_trace(monkeypatch, traced):
     """The eager branch is untouched: no scope, no cost per call."""
